@@ -22,6 +22,11 @@ Contract from ISSUE 20 / docs/PRECISION.md, on a >=4-way dp mesh:
    the CPU emulation backend has no meaningful MXU peak, so CI prints
    the measured value and skips the floor there.
 
+This is a CPU gate: the ``setdefault("JAX_PLATFORMS", "cpu")`` below puts
+it on the virtual CPU mesh unless the caller names another platform, so
+its timings are never device speed and item 5 has never executed
+(ROADMAP S9).
+
 Usage: python benchmark/fp8_train.py [--dp 4] [--steps 6]
            [--parity-tol 0.05] [--byte-cut 2.0] [--mfu 0.45] [--json]
 """
@@ -158,11 +163,9 @@ def main(argv=None):
     peak = None
     mfu = None
     if not on_cpu:
-        sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-        from bench import _chip_peak   # noqa: E402
-        peak = _chip_peak(jax.devices()[0])
-        if peak:
-            mfu = flops / sec / peak
+        from mxnet_tpu import insight
+        peak = insight.peaks()[0]      # unknown device kind: raises
+        mfu = flops / sec / peak
 
     report = {
         "dp": args.dp,

@@ -144,7 +144,7 @@ def gate_hardware(min_decode_speedup):
     import mxnet_tpu as mx
     from mxnet_tpu.gluon.model_zoo.gpt import GPTForCausalLM
 
-    peak = bench._peak_flops()
+    peak = mx.insight.peaks()[0]
     r_bf16 = bench.bench_resnet50_infer("bf16", False, peak)
     r_int8 = bench.bench_resnet50_infer("int8", False, peak)
     infer_speedup = r_int8["items_per_s"] / r_bf16["items_per_s"]

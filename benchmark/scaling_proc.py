@@ -18,7 +18,7 @@ regression:
     LOADER_GATE {"ok", "ratio", "threshold", ...}
 
 Reference anchor: tools/bandwidth/measure.py + tests/nightly/
-dist_sync_kvstore.py launch taxonomy.
+dist_sync_kvstore.py launch scheme.
 """
 import json
 import os

@@ -16,6 +16,10 @@ telemetry plane (PJRT allocator live/peak) is reported alongside when
 the backend provides it; the CPU backend used in CI has no allocator
 stats, so that section prints n/a there and lights up on real TPUs.
 
+This is a CPU gate: the ``setdefault("JAX_PLATFORMS", "cpu")`` below puts
+it on the virtual CPU mesh unless the caller names another platform; it
+counts bytes, it measures no device.
+
 Usage: python benchmark/zero_memory.py [--reduction 0.4] [--dp 4]
            [--steps 2] [--json]
 """

@@ -113,8 +113,8 @@
 #                (docs/STATIC_ANALYSIS.md)
 #   nightly    - the slow bucket (MXNET_TEST_SLOW=1), reference
 #                tests/nightly analog
-#   tpu        - hardware-only: Mosaic kernel checks + full bench grid
-#                (skipped with a notice when no TPU is attached)
+#   tpu        - on a machine with a chip: chip_smoke.py, then the Mosaic
+#                kernel checks (no chip = a red stage, never a skip)
 #
 # The stage x platform matrix (what the reference spreads across
 # Jenkinsfiles) is ci/matrix.yaml; 'all' runs the PR-blocking set.
@@ -126,7 +126,7 @@ stage="${1:-all}"
 
 sanity() {
     echo "== sanity: python compile-check =="
-    python -m compileall -q mxnet_tpu tools example tests bench.py __graft_entry__.py
+    python -m compileall -q mxnet_tpu tools example tests bench.py chip_smoke.py __graft_entry__.py
     echo "== sanity: onnx proto gencode =="
     # byte-diff only when the local protoc matches the version that
     # produced the checked-in gencode (recorded in .protoc-version);
@@ -685,20 +685,13 @@ nightly() {
 }
 
 tpu() {
-    echo "== tpu: hardware stage =="
-    python tools/_tpu_probe.py; probe=$?
-    if [ "$probe" -eq 2 ]; then
-        # a wedged tunnel on the dedicated TPU runner is a red build,
-        # not a skip — otherwise hardware regressions hide forever
-        echo "TPU probe TIMED OUT (wedged tunnel?); failing stage"; return 1
-    elif [ "$probe" -ne 0 ]; then
-        echo "no TPU attached; stage skipped"; return 0
-    fi
+    # runs on a machine with a chip; with none, chip_smoke.py exits
+    # non-zero and the stage is red — a missing chip is never a skip.
+    # One process per chip: the two commands run one after the other.
+    echo "== tpu: the main path starts on the chip =="
+    python chip_smoke.py
+    echo "== tpu: every Pallas kernel compiles under Mosaic and matches =="
     python tools/tpu_kernel_check.py
-    python bench.py
-    # hardware halves of the low-bit gates: int8 infer beats bf16,
-    # int4-weight decode >=1.3x fp32 tokens/s with greedy parity
-    python benchmark/quantized_inference.py --assert
 }
 
 case "$stage" in

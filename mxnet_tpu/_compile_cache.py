@@ -1,19 +1,26 @@
-"""Opt-in JAX persistent compilation cache (mx.config.compilation_cache_dir).
+"""JAX persistent compilation cache: where it lives and what it reports.
 
 Reference parity: the reference ships compiled-op caches keyed on op
 signatures in-process; on a compiler-backed stack the expensive artifact
 is the XLA executable, and JAX can persist those to disk so *repeated
 runs* — the CI re-run, the resumed preemptible job, the hyperparameter
-sweep over one model — skip compilation entirely.  This module arms that
-cache from the ``compilation_cache_dir`` knob (env alias
-``MXNET_COMPILE_CACHE``) and mirrors JAX's cache activity into
+sweep over one model — skip compilation entirely.
+
+Placement rule (:func:`cache_dir`): a cache placed from outside wins.
+When ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and this
+module sets no directory in code.  Otherwise the directory is the
+``compilation_cache_dir`` knob (env alias ``MXNET_COMPILE_CACHE``), or
+the path an entry script passes — ``chip_smoke.py`` and ``bench.py`` pass
+one fixed path inside the checkout, because the path is part of the
+cache key and a directory that moves never hits.  Importing the package
+arms nothing unless the knob is set.  Cache activity is mirrored into
 ``mx.telemetry``'s ``compile.*`` metrics, next to the in-process
 recompile detector (telemetry.note_compile).
 
 Threshold note: JAX by default only persists programs that took >1s to
-compile and are >minimal size; we zero both thresholds — an opted-in
-cache directory should cache everything, tiny test programs included,
-or the knob looks broken on small models.
+compile and are >minimal size; we zero both thresholds — an armed cache
+should cache everything, tiny test programs included, or it looks broken
+on small models.
 """
 from __future__ import annotations
 
@@ -22,7 +29,15 @@ import os
 from . import config as _config
 from . import telemetry as _telemetry
 
-__all__ = ["configure"]
+__all__ = ["CHECKOUT_CACHE", "cache_dir", "configure"]
+
+#: what the entry scripts of a checkout (chip_smoke.py, bench.py) pass to
+#: :func:`configure` when nobody placed a cache from outside: one fixed
+#: path beside the package — the path is part of the cache key, so it is
+#: never a temp, pid or time-derived name
+CHECKOUT_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
 
 _telemetry.declare_metric(
     "compile.persistent_cache_requests_total", "counter",
@@ -50,10 +65,7 @@ def _install_listeners():
     global _listener_installed
     if _listener_installed:
         return
-    try:
-        from jax import monitoring
-    except ImportError:
-        return
+    from jax import monitoring
 
     def on_event(event, *args, **kwargs):
         if not _telemetry._active:
@@ -74,24 +86,28 @@ def _install_listeners():
     _listener_installed = True
 
 
+def cache_dir(path=None):
+    """The directory the persistent cache lives in, or None when there
+    is none: ``JAX_COMPILATION_CACHE_DIR`` if set, else ``path``, else
+    the ``compilation_cache_dir`` knob."""
+    path = (os.environ.get("JAX_COMPILATION_CACHE_DIR") or path
+            or _config.get("compilation_cache_dir"))
+    return os.path.abspath(os.path.expanduser(path)) if path else None
+
+
 def configure(path=None):
-    """Point JAX's persistent compilation cache at ``path`` (default: the
-    ``compilation_cache_dir`` knob).  Returns the armed directory, or
-    None when the knob is empty.  Idempotent; safe to call after arrays
-    exist (only future compilations consult the cache)."""
-    if path is None:
-        path = _config.get("compilation_cache_dir")
-    if not path:
+    """Arm JAX's persistent compilation cache at :func:`cache_dir` and
+    return that directory (None, and nothing armed, when there is none).
+    Idempotent; safe to call after arrays exist (only future
+    compilations consult the cache)."""
+    directory = cache_dir(path)
+    if directory is None:
         return None
-    path = os.path.abspath(os.path.expanduser(path))
-    os.makedirs(path, exist_ok=True)
     import jax
-    jax.config.update("jax_compilation_cache_dir", path)
-    for knob, value in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                        ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(knob, value)
-        except (AttributeError, ValueError):  # older/newer jax: keep defaults
-            pass
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        os.makedirs(directory, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", directory)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     _install_listeners()
-    return path
+    return directory

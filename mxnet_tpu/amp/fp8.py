@@ -110,7 +110,7 @@ def _qcast(v, scale, fmt):
 
 def _dot(a, b, dims):
     """fp8 x fp8 dot with fp32 accumulation.  On fp8-capable devices the
-    operands stay fp8 (the MXU takes them natively); elsewhere they
+    operands stay fp8 (the compiler picks the MXU mode); elsewhere they
     upcast first — numerically identical (the information loss happened
     at the cast), and it keeps CPU CI on dtypes XLA:CPU always lowers."""
     if not fp8_capable():

@@ -20,8 +20,8 @@ import threading
 
 import jax
 import jax.numpy as jnp
+from jax import enable_x64 as _enable_x64
 
-from ._jax_compat import enable_x64 as _enable_x64
 from .base import MXNetError
 
 # ---------------------------------------------------------------------------

@@ -3,9 +3,10 @@ XLA compile cache they sit next to.
 
 Winners live in ONE JSON file (``winners.json``) under, in order of
 preference: the ``autotune.cache_dir`` knob, the persistent XLA compile
-cache directory (``compilation_cache_dir`` — "next to the XLA cache", so
-one cache volume carries both the compiled executables and the configs
-that produced them), or ``<mxnet home>/autotune``.
+cache directory (``_compile_cache.cache_dir()``: JAX_COMPILATION_CACHE_DIR,
+else the ``compilation_cache_dir`` knob — "next to the XLA cache", so one
+cache volume carries both the compiled executables and the configs that
+produced them), or ``<mxnet home>/autotune``.
 
 Keys are ``<model fingerprint>|<device_kind>|dp<N>[|mesh:<axes>]``: the
 fingerprint hashes the parameter inventory (structural name, shape,
@@ -48,11 +49,9 @@ _TRIALS_CAP = 512
 
 def cache_dir():
     """Resolve the winners directory (see module docstring)."""
-    path = _config.get("autotune.cache_dir")
-    if not path:
-        path = _config.get("compilation_cache_dir")
-    if not path:
-        path = os.path.join(_config.get("home"), "autotune")
+    from .._compile_cache import cache_dir as _xla_cache_dir
+    path = (_config.get("autotune.cache_dir") or _xla_cache_dir()
+            or os.path.join(_config.get("home"), "autotune"))
     return os.path.abspath(os.path.expanduser(path))
 
 

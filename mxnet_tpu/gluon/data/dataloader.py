@@ -16,6 +16,7 @@ from __future__ import annotations
 import collections
 import concurrent.futures as cf
 import os
+import pickle
 import time
 
 import numpy as onp
@@ -94,7 +95,15 @@ def default_mp_batchify_fn(data):
 _worker_state = {}
 
 
-def _mp_worker_init(dataset, batchify):
+def _mp_worker_init(payload):
+    # a chip belongs to one process and the parent holds it: pin the
+    # worker to the CPU platform before anything here can start a
+    # back-end.  ``payload`` arrives pickled so that this runs first —
+    # unpickling a dataset that holds mx arrays creates device buffers.
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    import jax
+    jax.config.update("jax_platforms", "cpu")
+    dataset, batchify = pickle.loads(payload)
     _worker_state["dataset"] = dataset
     _worker_state["batchify"] = batchify
     _worker_state["segs"] = {}  # name -> SharedMemory (attached handles)
@@ -444,11 +453,14 @@ class DataLoader:
         # holds live PJRT/XLA state that must not be forked
         if self._proc_pool is None:
             import multiprocessing as mp
+            from multiprocessing.reduction import ForkingPickler
+            payload = bytes(ForkingPickler.dumps(
+                (self._dataset, self._batchify(True))))
             self._proc_pool = cf.ProcessPoolExecutor(
                 self._num_workers,
                 mp_context=mp.get_context("spawn"),
                 initializer=_mp_worker_init,
-                initargs=(self._dataset, self._batchify(True)))
+                initargs=(payload,))
         return self._proc_pool
 
     def _kill_pool(self):

@@ -199,13 +199,13 @@ def _fused_ln_residual(x, h, ln, p):
 
     from ... import autograd, config
     from ... import random as _random
+    from ... import runtime as _runtime
     from ...numpy.multiarray import _invoke
 
     mode = config.get("fused_ln_residual")
     if mode == "off" or ln._axis not in (-1, x.ndim - 1):
         return None
-    on_tpu = jax.default_backend() == "tpu"
-    if mode == "auto" and not on_tpu:
+    if mode == "auto" and not _runtime.on_tpu():
         return None
     if mode == "auto" and not (autograd.is_training() and float(p) > 0):
         # Measured on TPU v5lite (round 5, tools/tpu_ab.py): with dropout
@@ -228,7 +228,7 @@ def _fused_ln_residual(x, h, ln, p):
     p_eff = float(p) if autograd.is_training() else 0.0
     key = _random._next_key() if p_eff > 0 else None
     eps = ln._epsilon
-    interpret = not on_tpu
+    interpret = _runtime.pallas_interpret()
 
     def fn(x_, h_, g_, b_):
         mask = (jax.random.bernoulli(key, 1.0 - p_eff, h_.shape)

@@ -41,10 +41,12 @@ from . import config as _config
 from . import fault as _fault
 from . import telemetry as _telemetry
 from . import trace as _trace
+from .base import MXNetError
 
 __all__ = [
     "enable", "disable", "configure", "active", "reset",
     "capture_cost", "capture_jit", "register_executable", "note_step",
+    "PEAKS", "peaks",
     "roofline_verdict", "input_stall_p50", "attribution", "last_summary",
     "healthz",
     "drift_events", "DriftDetector", "on_drift", "remove_drift_hook",
@@ -78,25 +80,25 @@ _telemetry.declare_metric(
     "Age of each host's merged fleet snapshot at scrape time, by "
     "host — the staleness signal for the fleet view.")
 
-#: peak FLOP/s by device_kind substring (public TPU bf16 specs; the
-#: bench.py PEAK_BF16 table) plus a nominal host-CPU entry so the CI
-#: virtual mesh still reports a defined — if approximate — MFU.
-PEAK_FLOPS = {
-    "v5 lite": 197e12, "v5e": 197e12,
-    "v4": 275e12,
-    "v5p": 459e12, "v5": 459e12,
-    "v6 lite": 918e12, "v6e": 918e12,
-    "cpu": 1e11,
-}
-
-#: memory bandwidth (bytes/s) by device_kind substring (public HBM
-#: specs) — the roofline's machine-balance denominator.
-PEAK_BYTES_PER_S = {
-    "v5 lite": 819e9, "v5e": 819e9,
-    "v4": 1228e9,
-    "v5p": 2765e9, "v5": 2765e9,
-    "v6 lite": 1640e9, "v6e": 1640e9,
-    "cpu": 5e10,
+#: Per-chip peaks keyed by the lower-cased ``device_kind`` JAX reports:
+#: (bf16 FLOP/s, int8 OP/s, HBM bytes/s).  The one peak table in the
+#: tree — bench.py and chip_smoke.py read it.  Source: Google Cloud TPU
+#: documentation, the per-version system-architecture pages (v4 275 /
+#: 275 / 1228; "TPU v5e" 197 TFLOP/s bf16, 393 TOP/s int8, 819 GB/s;
+#: v5p 459 / 918 / 2765; v6e 918 / 1836 / 1640) — the figures jax's own
+#: pallas/mosaic/tpu_info.py carries.  A v5e reports itself as
+#: "TPU v5 lite" (chip run, PR 21).  "cpu" is a nominal host entry so the
+#: CI virtual mesh still reports a defined — if approximate — MFU; any
+#: other kind that is missing here is an error, never a default.
+PEAKS = {
+    "tpu v4": (275e12, 275e12, 1228e9),
+    "tpu v5 lite": (197e12, 393e12, 819e9),
+    "tpu v5e": (197e12, 393e12, 819e9),
+    "tpu v5": (459e12, 918e12, 2765e9),
+    "tpu v5p": (459e12, 918e12, 2765e9),
+    "tpu v6 lite": (918e12, 1836e12, 1640e9),
+    "tpu v6e": (918e12, 1836e12, 1640e9),
+    "cpu": (1e11, 1e11, 5e10),
 }
 
 _lock = threading.Lock()
@@ -177,28 +179,31 @@ def reset():
 # -- device peaks & roofline -------------------------------------------------
 
 def _device_kind():
+    import jax
+    return str(jax.devices()[0].device_kind).lower()
+
+
+def peaks(kind=None):
+    """``(bf16 FLOP/s, int8 OP/s, HBM bytes/s)`` for ``kind`` (default:
+    this process's first device).  Raises for a kind :data:`PEAKS` does
+    not list: a utilisation against an assumed peak is not a
+    measurement."""
+    kind = _device_kind() if kind is None else str(kind).lower()
     try:
-        import jax
-        return str(getattr(jax.devices()[0], "device_kind", "cpu")).lower()
-    except Exception:   # noqa: BLE001 - attribution must not need a backend
-        return "cpu"
+        return PEAKS[kind]
+    except KeyError:
+        raise MXNetError(
+            f"no peak figures for device kind {kind!r}; add a sourced "
+            "row to mxnet_tpu.insight.PEAKS") from None
 
 
-def _lookup_peaks(kind):
-    for sub, peak in PEAK_FLOPS.items():
-        if sub != "cpu" and sub in kind:
-            return peak, PEAK_BYTES_PER_S[sub]
-    return PEAK_FLOPS["cpu"], PEAK_BYTES_PER_S["cpu"]
-
-
-def _peaks(kind=None):
-    """(peak FLOP/s, peak bytes/s) for ``kind`` (default: this process's
-    first device, cached)."""
+def _peaks():
+    """(peak FLOP/s, peak bytes/s) of this process's first device,
+    cached."""
     global _peak_cache
-    if kind is not None:
-        return _lookup_peaks(str(kind).lower())
     if _peak_cache is None:
-        _peak_cache = _lookup_peaks(_device_kind())
+        flops, _, bytes_per_s = peaks()
+        _peak_cache = (flops, bytes_per_s)
     return _peak_cache
 
 

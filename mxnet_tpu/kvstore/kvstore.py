@@ -98,7 +98,7 @@ class KVStore(KVStoreBase):
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
         import functools
 
-        from .._jax_compat import shard_map
+        from jax import shard_map
 
         n, shape = len(vals), tuple(vals[0].shape)
         mesh = Mesh(onp.array(devs), ("kv",))

@@ -481,8 +481,8 @@ def fused_conv_bn_relu(x, weight, gamma, beta, running_mean, running_var,
     """
     from ..ops.pallas_conv_bwd import fused_cbr_train
     if interpret is None:
-        import jax as _jax
-        interpret = _jax.default_backend() != "tpu"
+        from .. import runtime as _runtime
+        interpret = _runtime.pallas_interpret()
 
     def fn(x_, w, g, b):
         xh = jnp.transpose(x_, (0, 2, 3, 1))          # NCHW -> NHWC

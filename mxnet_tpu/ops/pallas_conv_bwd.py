@@ -1,6 +1,6 @@
 """Fused conv3x3 + BatchNorm + ReLU backward — Pallas TPU mega-kernel.
 
-Round-3 profiling (ROUND3_NOTES.md §1) localized the ResNet-50 training
+Round-3 profiling localized the ResNet-50 training
 wall: backward convs sit AT the HBM roofline because the standard
 decomposition reads the conv-output cotangent dy three times (BN-backward
 reductions, dgrad, wgrad) and materializes it once. This kernel changes the

@@ -88,8 +88,8 @@ def chunked_lm_xent(h, w, labels, chunk=8192):
 
     The tied LM head's logits tensor is the long-context memory wall:
     at seq 8192 x vocab 50257 it alone is ~823 MB bf16 and OOMs one v5e
-    even under whole-model remat (ROUND5_NOTES). This computes the loss
-    by streaming ``lax.scan`` over vocab chunks — per chunk one
+    even under whole-model remat (round 5, before PR 1). This computes the
+    loss by streaming ``lax.scan`` over vocab chunks — per chunk one
     (N, D) @ (D, chunk) matmul feeds a running online-logsumexp (the
     flash-attention trick applied to the classifier axis) and the picked
     label logits; the VJP re-streams the chunks, emitting dh and dw

@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .._jax_compat import shard_map
+from jax import shard_map
 
 
 def allreduce(x, mesh, axis="dp", op="sum"):

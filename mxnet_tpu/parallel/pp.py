@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from .._jax_compat import shard_map
+from jax import shard_map
 
 
 def gpipe(stage_fn, stage_params, xs, mesh, axis="pp"):

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .._jax_compat import shard_map
+from jax import shard_map
 
 
 def _block_attn(q, k, v, m_prev, l_prev, acc, scale, mask=None):

@@ -103,7 +103,7 @@ def _time_allreduce(mesh, net, iters=10):
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     n = mesh.devices.size
     nparams = sum(int(onp_prod(p.shape)) for p in
@@ -139,7 +139,7 @@ def multiprocess_overhead_table(ns=(2, 4), timeout=420):
 
     Separates process-collective overhead from the shared-core contention
     that dominates the virtual in-process mesh (reference anchor:
-    tests/nightly/dist_sync_kvstore.py launch taxonomy). Rows come from
+    tests/nightly/dist_sync_kvstore.py launch scheme). Rows come from
     rank 0 of each run; failures degrade to an {'n', 'error'} row.
     """
     import json

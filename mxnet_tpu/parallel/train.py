@@ -12,6 +12,7 @@ overlap re-created at compile time.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import re
 
@@ -30,6 +31,7 @@ from .. import telemetry as _telemetry
 from ..amp import fp8 as _fp8
 from ..base import MXNetError
 from ..numpy.multiarray import ndarray, _wrap
+from . import mesh as _pmesh
 from .mesh import MeshConfig, activation_sharding
 
 _telemetry.declare_metric(
@@ -807,7 +809,7 @@ class ShardedTrainStep:
         exactly the granularity XLA's latency-hiding scheduler overlaps
         with the remaining backward compute.
         """
-        from .._jax_compat import shard_map
+        from jax import shard_map
         dpx = self.dp_axis
         dp_n = int(self.mesh.shape[dpx])
         mode = self._compress
@@ -887,7 +889,7 @@ class ShardedTrainStep:
             x, NamedSharding(self.mesh, self.param_specs.get(n, P())))
 
     def _build_zero_update(self):
-        from .._jax_compat import shard_map
+        from jax import shard_map
         dpx = self.dp_axis
         fopt = self.fopt
         names = list(self._zero)
@@ -959,16 +961,40 @@ class ShardedTrainStep:
             new_st[n] = new_zs[n]
         return new_tr, new_st
 
-    def __call__(self, *batch):
-        """Run one step; returns the (replicated) scalar loss as ndarray."""
-        from .. import random as _random
+    def _trace_scope(self):
+        """The activation_sharding scope the step traces under.  It
+        carries the layout's own rules (sp) and — always — the mesh, which
+        is how ops that must know it (the flash kernel's shard_map) find
+        it.  A raw-Mesh step built inside a caller's scope keeps the
+        caller's rules."""
+        if not self._act_rules and _pmesh._act_rules is not None:
+            return contextlib.nullcontext()
+        return activation_sharding(self.mesh, **self._act_rules)
+
+    def _shard_batch(self, batch):
         raws = [b._data if isinstance(b, ndarray) else jnp.asarray(b)
                 for b in batch]
         # ensure_sharded skips the re-put when a DevicePrefetcher (see
         # .prefetch) already laid the batch out on the step's shardings —
         # the common case in an overlapped input pipeline
-        raws = [_pipeline.ensure_sharded(r, s)
+        return [_pipeline.ensure_sharded(r, s)
                 for r, s in zip(raws, self.batch_shardings)]
+
+    def lower(self, *batch):
+        """Trace the step for ``batch`` without compiling or running it
+        (no state advances): the ``jax.stages.Lowered`` whose text shows
+        what the compiled step will contain — Mosaic kernels,
+        collectives, shardings."""
+        args = (self.trainable, self.aux, self.states, self.extra,
+                jax.random.PRNGKey(0), jnp.zeros((), jnp.float32),
+                jnp.ones((), jnp.float32), *self._shard_batch(batch))
+        with self._trace_scope():
+            return self._step.lower(*args)
+
+    def __call__(self, *batch):
+        """Run one step; returns the (replicated) scalar loss as ndarray."""
+        from .. import random as _random
+        raws = self._shard_batch(batch)
         rng = _random._next_key()
         opt = self.fopt.opt
         # advance the update count on host (lr schedules / warmup / bias
@@ -993,21 +1019,12 @@ class ShardedTrainStep:
             label = getattr(self, "_insight_label", "parallel.train_step")
             cap = (self.trainable, self.aux, self.states, self.extra, rng,
                    lr, t, *raws)
-            if self._act_rules:
-                with activation_sharding(self.mesh, **self._act_rules):
-                    _insight.capture_jit(label, self._step, cap,
-                                         kind="train")
-            else:
+            with self._trace_scope():
                 _insight.capture_jit(label, self._step, cap, kind="train")
-        if self._act_rules:
-            # sp: install the activation rules around the call so the
-            # layers' constrain() hooks and the ring-attention routing see
-            # them while jit traces (first call) — no-op afterwards
-            with activation_sharding(self.mesh, **self._act_rules):
-                out = self._step(
-                    self.trainable, self.aux, self.states, self.extra,
-                    rng, lr, t, *raws)
-        else:
+        # the scope surrounds the call so the layers' constrain() hooks,
+        # the ring-attention routing and the flash kernel's shard_map see
+        # the mesh while jit traces (first call) — no-op afterwards
+        with self._trace_scope():
             out = self._step(
                 self.trainable, self.aux, self.states, self.extra, rng,
                 lr, t, *raws)

@@ -104,7 +104,7 @@ def int64_tensor_size(active=True):
     default 32-bit truncation applies (a startup-time choice in the
     reference, a scope here).
     """
-    from ._jax_compat import enable_x64
+    from jax import enable_x64
     with enable_x64(active):
         yield
 

@@ -191,7 +191,7 @@ def test_finite_difference_utility():
 
 
 # ---------------------------------------------------------------------------
-# higher-order (create_graph) — reference taxonomy:
+# higher-order (create_graph) — reference layout:
 # python/mxnet/autograd.py:303 grad(create_graph=True) over
 # src/imperative/imperative.cc:438; tests/python/unittest/test_higher_order_grad.py
 # ---------------------------------------------------------------------------

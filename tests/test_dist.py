@@ -1,4 +1,4 @@
-"""Multi-process distributed tests (reference taxonomy: tests/nightly/
+"""Multi-process distributed tests (reference layout: tests/nightly/
 dist_sync_kvstore.py launched via tools/launch.py local mode, SURVEY §4
 'distributed tests are real multi-process on one box') and the gradient-
 compression bitwise oracle (reference: src/kvstore/gradient_compression.h).
@@ -140,7 +140,7 @@ def test_dist_async_watchdog_times_out():
 @pytest.mark.slow
 def test_multiprocess_overhead_table_two_procs():
     """Real 2-process collective probe (reference:
-    tests/nightly/dist_sync_kvstore.py launch taxonomy)."""
+    tests/nightly/dist_sync_kvstore.py launch scheme)."""
     from mxnet_tpu.parallel.scaling import multiprocess_overhead_table
 
     rows = multiprocess_overhead_table(ns=(2,), timeout=240)

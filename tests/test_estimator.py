@@ -1,5 +1,5 @@
 """gluon.contrib.estimator (reference: tests/python/unittest/
-test_gluon_estimator.py + test_gluon_event_handler.py taxonomy)."""
+test_gluon_estimator.py + test_gluon_event_handler.py layout)."""
 import os
 
 import numpy as onp

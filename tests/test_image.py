@@ -1,5 +1,5 @@
 """Image pipeline: augmenters, ImageIter over RecordIO, im2rec, model_store
-(reference taxonomy: tests/python/unittest/test_image.py +
+(reference layout: tests/python/unittest/test_image.py +
 test_gluon_model_zoo.py)."""
 import os
 import sys

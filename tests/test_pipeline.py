@@ -393,9 +393,11 @@ def test_shm_ring_grant_return_protocol():
 # persistent compilation cache knob
 # ---------------------------------------------------------------------------
 
-def test_compile_cache_knob_configures_jax(tmp_path):
+def test_compile_cache_knob_configures_jax(tmp_path, monkeypatch):
     import jax
     from mxnet_tpu import _compile_cache
+    # a cache placed from outside would win (tests/test_bring_up.py)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     cache_dir = str(tmp_path / "xla-cache")
     prev = jax.config.jax_compilation_cache_dir
     try:
@@ -406,6 +408,7 @@ def test_compile_cache_knob_configures_jax(tmp_path):
         import os
         assert os.path.isdir(cache_dir)
     finally:
+        mx.config.set("compilation_cache_dir", "")
         jax.config.update("jax_compilation_cache_dir", prev)
 
 

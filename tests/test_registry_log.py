@@ -1,6 +1,6 @@
 """mx.registry generic factory + mx.log + contrib facade tail.
 
-Reference taxonomy: python/mxnet/registry.py is exercised in the
+Reference layout: python/mxnet/registry.py is exercised in the
 reference through initializer/optimizer create-from-json paths;
 contrib/io.py DataLoaderIter has doctest-style usage in its docstring.
 """

@@ -1,6 +1,6 @@
 """Sparse NDArray (row_sparse/CSR) tests.
 
-Reference taxonomy: tests/python/unittest/test_sparse_ndarray.py +
+Reference layout: tests/python/unittest/test_sparse_ndarray.py +
 test_sparse_operator.py — construction, tostype round-trips, retain,
 sparse dot vs dense oracle, kvstore row_sparse_pull.
 """

@@ -12,6 +12,12 @@ Each worker gets DMLC-style env vars that mxnet_tpu.kvstore.dist reads:
     MXTPU_DIST_DEVICE=cpu                  (local launcher) force the CPU
                                            platform + gloo collectives
 
+CPU-only by construction: the local launcher starts N processes on one
+host and a chip belongs to one process at a time, so every worker is
+pinned to the CPU platform (MXTPU_DIST_DEVICE=cpu).  One process drives
+all the chips of a host; this tool is for exercising the multi-process
+collective path, not for reaching an accelerator.
+
 Usage:  python tools/launch.py -n 4 [--launcher local] python3 train.py ...
 """
 from __future__ import annotations

@@ -43,7 +43,8 @@ def main():
     import jax
     dev = jax.devices()[0]
     assert dev.platform == "tpu", f"need TPU, got {dev.platform}"
-    peak = bench._chip_peak(dev)
+    from mxnet_tpu import insight
+    peak = insight.peaks(dev.device_kind)[0]
     res = {"device": getattr(dev, "device_kind", "?")}
     if which in ("bert", "all"):
         # both workloads: dropout off (XLA's fusion wins) and on (the
