@@ -30,6 +30,7 @@ from .. import engine
 from .. import fault as _fault
 from .. import pipeline as _pipeline
 from .. import telemetry as _telemetry
+from .. import trace as _trace
 from ..base import MXNetError, np_dtype
 from ..context import Context, current_context
 
@@ -475,7 +476,10 @@ class ndarray:
         """
         if _pipeline._guard_depth:
             _pipeline.note_host_sync("ndarray.asnumpy")
-        host = onp.asarray(jax.device_get(self._data))
+        # where the host waits for the device: it learns here, not when
+        # the call returned, that the work is done
+        with _trace.span("ndarray.asnumpy", category="ndarray"):
+            host = onp.asarray(jax.device_get(self._data))
         if not (host.flags["C_CONTIGUOUS"] and host.flags["WRITEABLE"]):
             host = host.copy(order="C")  # owned, dense, writable
         return host

@@ -450,6 +450,9 @@ def multi_head_attention(query, key, value, heads, mask=None, dropout_p=0.0,
     pure = mask is None and dropout_p == 0.0
     sp_mesh = _sp_mesh() if pure else None
 
+    # one scope a layer: split, pad to the kernel's lanes, kernel, slice,
+    # merge — the glue is this scope's time less its kernels'
+    @jax.named_scope("mx.attn")
     def fn(q, k, v):
         b, sq, hd = q.shape
         sk = k.shape[1]

@@ -108,6 +108,7 @@ def _fwd(q, k, v, causal, scale, block_q, block_k, interpret):
             jax.ShapeDtypeStruct((bh, sq_full, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="mx_flash_fwd",
     )(q, k, v)
     if sq_pad:
         out = out[:, :sq]
@@ -270,6 +271,7 @@ def _flash_bwd(causal, scale, block_q, block_k, bwd_block_q, bwd_block_k,
             jax.ShapeDtypeStruct((bh, sk_full, d), jnp.float32),
         ],
         interpret=interpret,
+        name="mx_flash_bwd_dkv",
     )(q, do, lse, delta, k, v)
 
     dq = pl.pallas_call(
@@ -280,6 +282,7 @@ def _flash_bwd(causal, scale, block_q, block_k, bwd_block_q, bwd_block_k,
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((bh, sq_full, d), jnp.float32),
         interpret=interpret,
+        name="mx_flash_bwd_dq",
     )(q, do, lse, delta, k, v)
 
     if sq_pad:

@@ -102,6 +102,7 @@ def _run_fwd(x, h, mask, gamma, beta, p, eps, block_rows, interpret):
                    jax.ShapeDtypeStruct((rows_p, 1), jnp.float32),
                    jax.ShapeDtypeStruct((rows_p, 1), jnp.float32)],
         interpret=interpret,
+        name="mx_ln_residual_fwd",
     )(xp, hp, mp, gamma[None], beta[None])
     return out[:n], mean, rstd
 
@@ -135,6 +136,7 @@ def _ln_residual_bwd(p, eps, block_rows, interpret, res, do):
                    jax.ShapeDtypeStruct((8, dim), jnp.float32),
                    jax.ShapeDtypeStruct((8, dim), jnp.float32)],
         interpret=interpret,
+        name="mx_ln_residual_bwd",
     )(xp, hp, mp, gamma[None], mean, rstd, dop)
     dgamma = dgp.sum(0).astype(gamma.dtype)
     dbeta = dbp.sum(0).astype(beta.dtype)

@@ -146,6 +146,7 @@ def quantized_matmul(x, w_q, w_scale, x_scale, bias=None, act=None,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
         interpret=interpret,
+        name="mx_int8_matmul",
     )(xs, xp, wp, wsp, bp)
     return out[:m, :n]
 
@@ -204,5 +205,6 @@ def fp8_matmul(x, w_q, w_scale, x_scale, bias=None, act=None, fmt="e4m3",
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
         interpret=interpret,
+        name="mx_fp8_matmul",
     )(xs, xp, wp, wsp, bp)
     return out[:m, :n]

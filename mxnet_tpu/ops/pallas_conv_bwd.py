@@ -158,6 +158,7 @@ def fused_conv3x3_bn_relu_bwd(da, x, y, w, gamma, beta, mean, var,
             pltpu.VMEM((NB, H + 2, W + 2, C), x.dtype),
         ],
         interpret=interpret,
+        name="mx_conv3x3_bwd",
     )(vec, da, y, x, wf)
 
     dw = dw9.reshape(3, 3, C, O).astype(w.dtype)

@@ -28,6 +28,7 @@ from .. import functional
 from .. import insight as _insight
 from .. import pipeline as _pipeline
 from .. import telemetry as _telemetry
+from .. import trace as _trace
 from ..amp import fp8 as _fp8
 from ..base import MXNetError
 from ..numpy.multiarray import ndarray, _wrap
@@ -736,10 +737,14 @@ class ShardedTrainStep:
 
     def _loss_and_grad(self, trainable, aux, rng, inputs, labels):
         def lossf(tr):
-            out, mutated = functional.functional_call(
-                self.block, self._expand_pp({**tr, **aux}), *inputs,
-                train=True, rng_key=rng)
-            return self.loss_fn(out, *labels), self._collapse_pp(mutated)
+            # the one scope of the forward; JAX names its backward
+            # transpose(jvp(mx.fwd)) by itself
+            with jax.named_scope("mx.fwd"):
+                out, mutated = functional.functional_call(
+                    self.block, self._expand_pp({**tr, **aux}), *inputs,
+                    train=True, rng_key=rng)
+                return (self.loss_fn(out, *labels),
+                        self._collapse_pp(mutated))
 
         if self._remat_on:
             lossf = jax.checkpoint(lossf, policy=self._remat_policy)
@@ -756,7 +761,7 @@ class ShardedTrainStep:
 
         def lossf(tr, g):
             sc = {s: (scales[s][0], scales[s][1], g[s]) for s in g}
-            with _fp8.scope(sc) as ctx:
+            with jax.named_scope("mx.fwd"), _fp8.scope(sc) as ctx:
                 out, mutated = functional.functional_call(
                     self.block, self._expand_pp({**tr, **aux}), *inputs,
                     train=True, rng_key=rng)
@@ -912,6 +917,7 @@ class ShardedTrainStep:
 
         self._zero_update = _zupd
 
+    @jax.named_scope("mx.optimizer")
     def _apply_updates(self, trainable, grads, states, lr, t,
                        zero_flat_grads=None):
         """Optimizer update dispatch: flat-ZeRO params go through the
@@ -992,10 +998,21 @@ class ShardedTrainStep:
             return self._step.lower(*args)
 
     def __call__(self, *batch):
-        """Run one step; returns the (replicated) scalar loss as ndarray."""
+        """Run one step; returns the (replicated) scalar loss as ndarray.
+
+        The host's part of a step is one span, ``mx/train.call`` in any
+        profiler session, holding ``mx/train.shard_batch`` (the batch
+        handed over to the step's shardings), ``mx/train.scalars`` (the
+        key, ``lr`` and ``t`` helper programs) and ``mx/train.dispatch``
+        (trace + lower + load on the first call, the enqueue afterwards).
+        """
+        with _trace.span("train.call", category="train"):
+            return self._call(batch)
+
+    def _call(self, batch):
         from .. import random as _random
-        raws = self._shard_batch(batch)
-        rng = _random._next_key()
+        with _trace.span("train.shard_batch", category="train"):
+            raws = self._shard_batch(batch)
         opt = self.fopt.opt
         # advance the update count on host (lr schedules / warmup / bias
         # correction used to be frozen at step 0 in the compiled path); the
@@ -1007,9 +1024,12 @@ class ShardedTrainStep:
             # keep the flight recorder's step current so a crash bundle
             # is named for (and attributes evidence to) the right step
             _blackbox.set_context(step=int(base) + self.steps_per_call)
-        lr_val = opt.lr_scheduler(base + 1) if opt.lr_scheduler else opt.lr
-        lr = jnp.asarray(lr_val, jnp.float32)
-        t = jnp.asarray(base + 1, jnp.float32)
+        with _trace.span("train.scalars", category="train"):
+            rng = _random._next_key()
+            lr_val = (opt.lr_scheduler(base + 1) if opt.lr_scheduler
+                      else opt.lr)
+            lr = jnp.asarray(lr_val, jnp.float32)
+            t = jnp.asarray(base + 1, jnp.float32)
         if _insight._active and not getattr(self, "_insight_done", False):
             # one-time attribution capture BEFORE dispatch (donation
             # deletes the input buffers): trace-only .lower(), no
@@ -1024,7 +1044,8 @@ class ShardedTrainStep:
         # the scope surrounds the call so the layers' constrain() hooks,
         # the ring-attention routing and the flash kernel's shard_map see
         # the mesh while jit traces (first call) — no-op afterwards
-        with self._trace_scope():
+        with self._trace_scope(), \
+                _trace.span("train.dispatch", category="train"):
             out = self._step(
                 self.trainable, self.aux, self.states, self.extra, rng,
                 lr, t, *raws)

@@ -27,11 +27,21 @@ Design points, mirroring the rest of the observability plane:
   dicts (``ph: "X"``); ``export(path)`` wraps them in ``traceEvents`` —
   load in ``ui.perfetto.dev`` or ``chrome://tracing`` as-is.  While the
   profiler is running, finished spans also mirror into its aggregate
-  table (``profiler.dumps()``) under ``trace:<category>``, and when a
-  device trace is armed (``set_config(tensorboard_dir=...)`` +
-  ``set_state('run')``) ``span()`` brackets itself with
-  ``jax.profiler.TraceAnnotation`` so host spans align with the XLA
-  device timeline in Xprof.
+  table (``profiler.dumps()``) under ``trace:<category>``.
+- **One trace with the device.** Every ``span()`` is also a
+  ``jax.profiler.TraceAnnotation`` named ``mx/<name>``, recorder on or
+  off (about a microsecond while no profiler session is on).  So to see
+  the program's host spans on the device's own timeline, start *any*
+  ``jax.profiler`` session — ``jax.profiler.start_trace(dir)``, Xprof's
+  capture, ``mx.profiler.set_state('run')`` with a ``tensorboard_dir``:
+  host spans are ``mx/…`` (``mx/train.call`` holds ``mx/train.
+  shard_batch``, ``mx/train.scalars`` and ``mx/train.dispatch``;
+  ``mx/ndarray.asnumpy`` is where the host waits for the device),
+  Pallas kernels are ``mx_…`` (``mx_flash_fwd``, ``mx_flash_bwd_dkv``,
+  ``mx_flash_bwd_dq``) and the step's scopes ``mx.…`` (``mx.fwd``, its
+  backward ``transpose(jvp(mx.fwd))``, ``mx.optimizer``, ``mx.attn``).
+  Spans nest as the profiler nests them: on one thread, a span's parent
+  is the span whose interval contains it.
 """
 from __future__ import annotations
 
@@ -41,6 +51,8 @@ import itertools
 import json
 import os
 import threading
+
+import jax
 
 from . import config as _config
 from . import profiler as _profiler
@@ -143,20 +155,14 @@ def _finish(name, category, start_us, dur_us, trace_id, span_id,
                                dur_us, dict(attrs) if attrs else None)
 
 
-class _NoopSpan:
+class _Annotation(jax.profiler.TraceAnnotation):
+    """What ``span()`` returns while the recorder is off: the span on the
+    profiler's timeline alone, chainable like a recorded one."""
+
     __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
 
     def set(self, **attrs):
         return self
-
-
-_NOOP = _NoopSpan()
 
 
 class _Span:
@@ -172,7 +178,6 @@ class _Span:
         self.span_id = _new_id()
         self.trace_id = None
         self.parent_id = None
-        self._jax = None
         self._onstack = False
 
     def set(self, **attrs):
@@ -187,18 +192,14 @@ class _Span:
             self.trace_id = self.span_id
         s.append((self.trace_id, self.span_id))
         self._onstack = True
-        if _profiler._state.get("device_trace_dir"):
-            import jax
-            self._jax = jax.profiler.TraceAnnotation(self.name)
-            self._jax.__enter__()
+        self._jax = jax.profiler.TraceAnnotation("mx/" + self.name)
+        self._jax.__enter__()
         self._t0 = _profiler.now_us()
         return self
 
     def __exit__(self, *exc):
         t1 = _profiler.now_us()
-        if self._jax is not None:
-            self._jax.__exit__(*exc)
-            self._jax = None
+        self._jax.__exit__(*exc)
         if self._onstack:
             st = getattr(_tls, "stack", None)
             if st:
@@ -212,10 +213,11 @@ class _Span:
 
 def span(name, category="app", **attrs):
     """``with trace.span("train.step", step=n): ...`` — nested spans
-    parent automatically through the thread-local context stack.  A
-    cheap no-op object is returned while tracing is disabled."""
+    parent automatically through the thread-local context stack.  In any
+    ``jax.profiler`` session the span is ``mx/<name>`` on the profiler's
+    timeline; while the recorder is disabled that is all it is."""
     if not _active:
-        return _NOOP
+        return _Annotation("mx/" + name)
     return _Span(name, category, attrs)
 
 
