@@ -257,12 +257,16 @@ def kernel_tile_bytes(kernel, bucket, blocks):
         d = max(128, int(d))  # head_dim zero-pads to the lane width
         bq = min(int(b["block_q"]), int(sq))
         bk = min(int(b["block_k"]), int(sk))
-        # q/o/acc tiles + k/v tiles + the (bq, bk) score block, fp32;
-        # the bwd kernels additionally hold do/dq (q-shaped) and dk/dv
-        # (k-shaped) accumulators
-        tiles = 3 * bq * d + 2 * bk * d + bq * bk
-        if kernel == "flash_attention_bwd":
-            tiles += 2 * bq * d + 2 * bk * d
+        if kernel == "flash_attention":
+            # q and o blocks, the (d, bq) accumulator, K and V whole (the
+            # forward loops over k-blocks inside one grid step), the
+            # (bk, bq) scores and their exponentials
+            tiles = 3 * bq * d + 2 * int(sk) * d + 2 * bq * bk
+        else:
+            # the larger of the two kernels on each term: q, do and the
+            # dq block with its fp32 scratch; k, v and the dk, dv blocks
+            # with theirs; sT, pT, dpT, dsT; the lse and delta rows
+            tiles = 4 * bq * d + 6 * bk * d + 4 * bq * bk + 2 * bq
         return 4 * tiles
     if kernel in ("quantized_matmul", "fp8_matmul"):
         m, n, k = bucket
@@ -278,6 +282,34 @@ def kernel_tile_bytes(kernel, bucket, blocks):
         # x/h/mask/out tiles plus fp32 row stats
         return 4 * (4 * br * dim + 2 * br)
     raise MXNetError(f"kernel_tile_bytes: unknown kernel {kernel!r}")
+
+
+def _flash_cost(kernel, bucket, b, launch):
+    """The flash kernels as they tile a causal call (what the trials
+    run): a tile above the diagonal is skipped — a backward grid step
+    that skips still pays its launch, the forward's k-loop never visits
+    it — and a tile the diagonal crosses costs a whole tile (its mask
+    did not show on the chip).  A tile's work is one pass of
+    ``bq x bk x d`` per matrix product (2 forward; 4 in dK/dV + 3 in dQ
+    backward); a forward k-loop trip costs what a grid step does.  The
+    weights are the v5e's block sweep at (1024, 1024, 64) (PERF.md,
+    PR 26): ~0.27 us a step against ~0.6 us a pass of a 512 x 512 x 128
+    tile; rank correlation with that sweep 0.85 forward, 0.98 backward.
+    No other chip was at hand: every device family ranks by the v5e's
+    ratio of a step to a pass, unmeasured there, until its own sweep
+    says otherwise (the search times what this ranking lets through).
+    The bytes a step streams ride under its compute: with dense
+    statistics and bf16 gradients the kernels are bound by their tiles,
+    not by HBM."""
+    from ..ops.pallas.flash_attention import tile_counts
+    sq, sk, d = (int(x) for x in bucket)
+    bq, bk = min(int(b["block_q"]), sq), min(int(b["block_k"]), sk)
+    c = tile_counts(sq, sk, bq, bk, True)
+    work = c["computed"] + c["masked"]
+    per_pass = bq * bk * max(128, d) / 2 ** 25
+    if kernel == "flash_attention":
+        return (-(-sq // bq) + work) * launch + 2 * per_pass * work
+    return 2 * (work + c["skipped"]) * launch + 7 * per_pass * work
 
 
 def kernel_cost(kernel, bucket, blocks):
@@ -300,13 +332,8 @@ def kernel_cost(kernel, bucket, blocks):
         return steps, util
 
     if kernel in ("flash_attention", "flash_attention_bwd"):
-        sq, sk, d = bucket
-        steps, util = _grid_and_util((sq, sk), (b["block_q"], b["block_k"]),
-                                     (256, 256))
-        work = (min(b["block_q"], sq) * min(b["block_k"], sk)
-                * max(128, d)) / 2 ** 20
-        passes = 3.0 if kernel == "flash_attention_bwd" else 1.0
-    elif kernel in ("quantized_matmul", "fp8_matmul"):
+        return _flash_cost(kernel, bucket, b, launch)
+    if kernel in ("quantized_matmul", "fp8_matmul"):
         m, n, k = bucket
         steps, util = _grid_and_util((m, n), (b["block_m"], b["block_n"]),
                                      (256, 256))

@@ -84,7 +84,7 @@ _STATIC_DEFAULTS = {
            "fp8_matmul": {"block_m": 256, "block_n": 256},
            "ln_residual": {"block_rows": 256}},
     "v5e": {"flash_attention": {"block_q": 512, "block_k": 512},
-            "flash_attention_bwd": {"block_q": 512, "block_k": 256},
+            "flash_attention_bwd": {"block_q": 512, "block_k": 512},
             "quantized_matmul": {"block_m": 256, "block_n": 512},
             "fp8_matmul": {"block_m": 256, "block_n": 512},
             "ln_residual": {"block_rows": 512}},
@@ -329,9 +329,11 @@ def _make_trial_fn(kernel, bucket, interpret):
     if kernel in ("flash_attention", "flash_attention_bwd"):
         from ..ops.pallas.flash_attention import flash_attention
         sq, sk, d = bucket
-        q = jnp.asarray(rs.randn(1, 2, sq, d), jnp.float32)
-        k = jnp.asarray(rs.randn(1, 2, sk, d), jnp.float32)
-        v = jnp.asarray(rs.randn(1, 2, sk, d), jnp.float32)
+        # timed on what the chip runs: bf16 operands, and heads enough
+        # that the grid's steady state outweighs its first steps
+        q = jnp.asarray(rs.randn(4, 8, sq, d), jnp.bfloat16)
+        k = jnp.asarray(rs.randn(4, 8, sk, d), jnp.bfloat16)
+        v = jnp.asarray(rs.randn(4, 8, sk, d), jnp.bfloat16)
 
         def build(blocks):
             if kernel == "flash_attention":
@@ -340,12 +342,15 @@ def _make_trial_fn(kernel, bucket, interpret):
                                            interpret=interpret, **blocks)
             else:
                 def f(q_, k_, v_):
-                    def loss(qq):
+                    # all three gradients: one asked for alone lets XLA
+                    # drop the other backward kernel from the trial
+                    def loss(qq, kk, vv):
                         return flash_attention(
-                            qq, k_, v_, causal=True, interpret=interpret,
+                            qq, kk, vv, causal=True, interpret=interpret,
                             bwd_block_q=blocks["block_q"],
-                            bwd_block_k=blocks["block_k"]).sum()
-                    return jax.grad(loss)(q_)
+                            bwd_block_k=blocks["block_k"]
+                        ).astype(jnp.float32).sum()
+                    return jax.grad(loss, argnums=(0, 1, 2))(q_, k_, v_)
             return jax.jit(f), (q, k, v)
     elif kernel in ("quantized_matmul", "fp8_matmul"):
         m, n, kk = bucket

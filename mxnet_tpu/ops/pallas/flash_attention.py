@@ -7,6 +7,28 @@ Pallas kernel per (batch*head, q-block) grid cell streams K/V blocks through
 VMEM with an online-softmax accumulator, so scores never hit HBM and the
 matmuls stay on the MXU. Backward is a recompute VJP (flash-style: saves
 only out + logsumexp residuals, rebuilds P per block).
+
+What the three kernels move and visit (``mx_flash_fwd``,
+``mx_flash_bwd_dkv``, ``mx_flash_bwd_dq``):
+
+* The softmax statistics (``lse``, ``delta``) are ``(bh, 1, seq)`` with the
+  sequence on the lanes.  A minor dimension of 1 is stored as one live lane
+  in 128, so nothing here has one.  The backward tiles are computed
+  transposed (``sT = k @ q.T``, shape ``(block_k, block_q)``): the
+  statistics then broadcast along sublanes, ``dv = pT @ do`` and
+  ``dk = dsT @ q`` are plain products, and only ``dq = dsT.T @ k`` takes
+  the transposed-LHS form.
+* Gradients accumulate in fp32 VMEM scratch across the sequential grid
+  axis and leave the kernel once, in the operands' dtype.
+* Causal tiles (``tile_counts``): a tile above the diagonal is *skipped* —
+  not computed, and its blocks not fetched, because the index maps clamp
+  to the nearest tile that has work, so a skipped step names the block
+  already resident.  Only a tile the diagonal (or the ragged end of K)
+  crosses is *masked* in the sense that its mask changes something.  The
+  kernels build the mask in every tile they run wherever the shapes have
+  one at all: a second, unmasked copy of the tile body for the tiles
+  wholly under the diagonal gave no time on the v5e and cost set-up
+  (PERF.md section 6, PR 26), so there is one body.
 """
 from __future__ import annotations
 
@@ -14,60 +36,154 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_NEG_INF = -1e30
+from ... import telemetry as _telemetry
 
+_NEG_INF = -1e30
+_LANES = 128
+
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T
+_NN = (((1,), (0,)), ((), ()))      # a @ b
+_TN = (((0,), (0,)), ((), ()))      # a.T @ b
+
+
+# ---------------------------------------------------------------------------
+# the tile schedule: which (q-block a, k-block b) tiles run, and in which
+# of those the mask changes something.  ``_tile_runs`` serves python ints
+# (the counter, the tests) and traced grid indices (the kernels) alike.
+# ---------------------------------------------------------------------------
+
+def _tile_runs(a, b, block_q, block_k, causal):
+    """Some query of q-block ``a`` attends some key of k-block ``b``."""
+    if not causal:
+        return True
+    return a * block_q + (block_q - 1) >= b * block_k
+
+
+def _tile_masked(a, b, block_q, block_k, seq_k, causal):
+    """Some (query, key) pair of a running tile is not attended: the
+    diagonal crosses it, or K's zero padding starts inside it."""
+    edge = (b + 1) * block_k > seq_k
+    if not causal:
+        return edge
+    return edge or a * block_q < (b + 1) * block_k - 1
+
+
+def _when_runs(a, b, block_q, block_k, causal):
+    """Decorator of a backward tile body: run it where the tile has work,
+    which without a diagonal is everywhere (no branch is traced)."""
+    if not causal:
+        return lambda body: body()
+    return pl.when(_tile_runs(a, b, block_q, block_k, causal))
+
+
+def _div(x, n):
+    """``x // n`` for ``x >= 0``.  On a traced grid index ``//`` is a
+    floor division (a division, two signs, a select), traced and lowered
+    at every use; the truncating division is one operation."""
+    return x // n if isinstance(x, int) else jax.lax.div(x, np.int32(n))
+
+
+def _fwd_visits(qi, nk, block_q, block_k, causal, minimum=min):
+    """K blocks the forward visits for q-block ``qi``: those that hold a
+    key some query of the block attends, ``ceil((qi+1)*block_q/block_k)``
+    under the diagonal."""
+    if not causal:
+        return nk
+    return minimum(nk, _div((qi + 1) * block_q + block_k - 1, block_k))
+
+
+def tile_counts(seq_q, seq_k, block_q, block_k, causal):
+    """Tiles one head's kernels compute unmasked (``computed``), compute
+    where the mask changes something (``masked``) and skip (``skipped``).
+    A tile is one grid step of either backward kernel and one trip of the
+    forward's k-loop (``_fwd_visits`` counts the same tiles a q-block).
+    Blocks clamp to the sequence as in the kernels."""
+    bq, bk = min(block_q, seq_q), min(block_k, seq_k)
+    counts = {"computed": 0, "masked": 0, "skipped": 0}
+    for a in range(-(-seq_q // bq)):
+        for b in range(-(-seq_k // bk)):
+            if not _tile_runs(a, b, bq, bk, causal):
+                counts["skipped"] += 1
+            elif _tile_masked(a, b, bq, bk, seq_k, causal):
+                counts["masked"] += 1
+            else:
+                counts["computed"] += 1
+    return counts
+
+
+def _count_tiles(kernels, bh, seq_q, seq_k, block_q, block_k, causal):
+    """``kernel.flash_tiles_total``: once a traced call, tiles x ``bh``."""
+    counts = tile_counts(seq_q, seq_k, block_q, block_k, causal)
+    for kernel in kernels:
+        for kind, n in counts.items():
+            _telemetry.inc("kernel.flash_tiles_total", n * bh,
+                           kernel=kernel, kind=kind)
+
+
+def _attended(q0, k0, shape, q_axis, seq_k, causal):
+    """The mask of one tile whose queries start at ``q0`` along
+    ``q_axis`` and whose keys start at ``k0`` along the other axis.
+    In-kernel loads of a zero-padded final K block see zeros, not
+    nothing: keys at or beyond ``seq_k`` are masked explicitly."""
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+    valid = k_pos < seq_k
+    if causal:
+        q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+        valid &= q_pos >= k_pos
+    return valid
+
+
+# ---------------------------------------------------------------------------
+# forward
+# ---------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, seq_k,
                 causal, scale, block_q):
+    """One q-block against its k-blocks, tiles transposed as in the
+    backward (``sT = k @ q.T``): the running max and sum are ``(1, bq)``
+    rows, reduced over sublanes and broadcast along them, and ``lse``
+    leaves as it is kept.  The accumulator is ``(d, bq)`` and is turned
+    once, at the end."""
     qi = pl.program_id(1)
     # keep MXU operands in the input dtype (bf16 on TPU): fp32 matmul
     # costs ~8x the MXU passes; accumulation is fp32 regardless via
     # preferred_element_type. Softmax math stays fp32.
     q = q_ref[0]                                      # (bq, d)
     bq, d = q.shape
-    nk = pl.cdiv(seq_k, block_k)
+    nk = k_ref.shape[1] // block_k
+
+    masked = causal or seq_k % block_k != 0
 
     def body(j, carry):
         m_prev, l_prev, acc = carry
-        k = k_ref[0, pl.ds(j * block_k, block_k), :]
-        v = v_ref[0, pl.ds(j * block_k, block_k), :]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        k_pos = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (bq, block_k), 1)
-        # dynamic-slice loads clamp at the array end, so a partial final
-        # block would re-read earlier keys — mask beyond seq_k explicitly
-        s = jnp.where(k_pos < seq_k, s, _NEG_INF)
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, block_k), 0)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)
+        k0 = pl.multiple_of(j * block_k, block_k)
+        k = k_ref[0, pl.ds(k0, block_k), :]
+        v = v_ref[0, pl.ds(k0, block_k), :]
+        sT = jax.lax.dot_general(k, q, _NT,
+                                 preferred_element_type=jnp.float32) * scale
+        if masked:
+            sT = jnp.where(_attended(qi * block_q, k0, sT.shape, 1, seq_k,
+                                     causal), sT, _NEG_INF)
+        m_new = jnp.maximum(m_prev, jnp.max(sT, axis=0, keepdims=True))
+        pT = jnp.exp(sT - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_new = corr * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+        l_new = corr * l_prev + jnp.sum(pT, axis=0, keepdims=True)
         acc = corr * acc + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            v, pT.astype(v.dtype), _TN, preferred_element_type=jnp.float32)
         return m_new, l_new, acc
 
-    m0 = jnp.full((bq, 1), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((bq, 1), jnp.float32)
-    acc0 = jnp.zeros((bq, d), jnp.float32)
-    if causal:
-        # only blocks with k_start <= q_end contribute
-        nk_eff = jnp.minimum(nk, (qi + 1) * block_q // block_k
-                             + (1 if block_q % block_k else 0) + 1)
-        nk_eff = jnp.minimum(nk_eff, nk)
-    else:
-        nk_eff = nk
-    m, l, acc = jax.lax.fori_loop(0, nk_eff, body, (m0, l0, acc0))
+    carry = (jnp.full((1, bq), _NEG_INF, jnp.float32),
+             jnp.zeros((1, bq), jnp.float32),
+             jnp.zeros((d, bq), jnp.float32))
+    n_visit = _fwd_visits(qi, nk, block_q, block_k, causal, jnp.minimum)
+    carry = jax.lax.fori_loop(0, n_visit, body, carry)
+    m, l, acc = carry
     l_safe = jnp.maximum(l, 1e-30)
-    o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
+    o_ref[0] = (acc / l_safe).T.astype(o_ref.dtype)
     lse_ref[0] = m + jnp.log(l_safe)
 
 
@@ -87,7 +203,7 @@ def _fwd(q, k, v, causal, scale, block_q, block_k, interpret):
         k = jnp.pad(k, ((0, 0), (0, sk_pad), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, sk_pad), (0, 0)))
     sq_full, sk_full = sq + sq_pad, sk + sk_pad
-    grid = (bh, pl.cdiv(sq_full, block_q))
+    grid = (bh, sq_full // block_q)
     kernel = functools.partial(
         _fwd_kernel, block_k=block_k, seq_k=sk, causal=causal, scale=scale,
         block_q=block_q)
@@ -101,18 +217,18 @@ def _fwd(q, k, v, causal, scale, block_q, block_k, interpret):
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, 1, block_q), lambda i, j: (i, 0, j)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, sq_full, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, sq_full, 1), jnp.float32),
+            jax.ShapeDtypeStruct((bh, 1, sq_full), jnp.float32),
         ],
         interpret=interpret,
         name="mx_flash_fwd",
     )(q, k, v)
     if sq_pad:
         out = out[:, :sq]
-        lse = lse[:, :sq]
+        lse = lse[:, :, :sq]
     return out, lse
 
 
@@ -129,90 +245,89 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, bwd_block_q,
     return out, (q, k, v, out, lse)
 
 
-def _bwd_block(q, do, lse, delta, kb, vb, q0, k0, seq_q, seq_k, causal,
-               scale):
-    """Shared recompute for one (q-block, k-block) tile: returns (p, ds).
+# ---------------------------------------------------------------------------
+# backward
+# ---------------------------------------------------------------------------
 
-    p = exp(s - lse) rebuilt from saved logsumexp; ds = p*(dp - delta)*scale
-    (standard flash-attention backward tile math). MXU operands stay in
+def _bwd_tile(q, do, lse, delta, kb, vb, q0, k0, seq_k, causal, scale):
+    """Shared recompute for one tile, transposed: returns (pT, dsT), both
+    ``(block_k, block_q)``.
+
+    pT = exp(sT - lse) rebuilt from the saved logsumexp; dsT =
+    pT*(dpT - delta)*scale (standard flash-attention backward tile math).
+    ``lse`` and ``delta`` are ``(1, block_q)`` rows.  MXU operands stay in
     the input dtype with fp32 accumulation; only the softmax algebra is
-    fp32.
+    fp32.  Zero-padded queries need no mask: their ``do`` and ``q`` rows
+    are zero, so they add nothing to dv and dk.
     """
-    bq, bk = q.shape[0], kb.shape[0]
-    s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    valid = (q_pos < seq_q) & (k_pos < seq_k)
-    if causal:
-        valid &= q_pos >= k_pos
-    s = jnp.where(valid, s, _NEG_INF)
-    p = jnp.where(valid, jnp.exp(s - lse), 0.0)
-    dp = jax.lax.dot_general(do, vb, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    ds = p * (dp - delta) * scale
-    return p, ds
+    sT = jax.lax.dot_general(kb, q, _NT,
+                             preferred_element_type=jnp.float32) * scale
+    pT = jnp.exp(sT - lse)
+    if causal or seq_k % kb.shape[0] != 0:
+        pT = jnp.where(_attended(q0, k0, sT.shape, 1, seq_k, causal),
+                       pT, 0.0)
+    dpT = jax.lax.dot_general(vb, do, _NT,
+                              preferred_element_type=jnp.float32)
+    dsT = pT * (dpT - delta) * scale
+    return pT, dsT
 
 
 def _bwd_dkv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
-                    dk_ref, dv_ref, *, block_q, block_k, seq_q, seq_k,
-                    causal, scale):
-    """dK/dV for one k-block, accumulated over sequential q-block steps
-    (grid (bh, nk, nq): last axis revisits the same output block)."""
+                    dk_ref, dv_ref, dk_acc, dv_acc, *, block_q, block_k,
+                    seq_k, causal, scale):
+    """dK/dV for one k-block, accumulated in VMEM over sequential q-block
+    steps (grid (bh, nk, nq): the last axis revisits the same output
+    block, written once on its last step)."""
     j, qi = pl.program_id(1), pl.program_id(2)
 
     @pl.when(qi == 0)
     def _init():
-        dk_ref[...] = jnp.zeros_like(dk_ref)
-        dv_ref[...] = jnp.zeros_like(dv_ref)
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
 
+    @_when_runs(qi, j, block_q, block_k, causal)
     def _compute():
         q = q_ref[0]
         do = do_ref[0]
-        lse, delta = lse_ref[0], delta_ref[0]
-        kb = k_ref[0]
-        vb = v_ref[0]
-        p, ds = _bwd_block(q, do, lse, delta, kb, vb, qi * block_q,
-                           j * block_k, seq_q, seq_k, causal, scale)
-        dv_ref[0] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+        pT, dsT = _bwd_tile(q, do, lse_ref[0], delta_ref[0], k_ref[0],
+                            v_ref[0], qi * block_q, j * block_k, seq_k,
+                            causal, scale)
+        dv_acc[...] += jax.lax.dot_general(
+            pT.astype(do.dtype), do, _NN,
             preferred_element_type=jnp.float32)
-        dk_ref[0] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+        dk_acc[...] += jax.lax.dot_general(
+            dsT.astype(q.dtype), q, _NN,
             preferred_element_type=jnp.float32)
 
-    if causal:
-        # the tile is all-masked when every q_pos < the k block start
-        pl.when((qi + 1) * block_q - 1 >= j * block_k)(_compute)
-    else:
-        _compute()
+    @pl.when(qi == pl.num_programs(2) - 1)
+    def _store():
+        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def _bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, dq_ref,
-                   *, block_q, block_k, seq_q, seq_k, causal, scale):
-    """dQ for one q-block, accumulated over sequential k-block steps."""
+                   dq_acc, *, block_q, block_k, seq_k, causal, scale):
+    """dQ for one q-block, accumulated in VMEM over sequential k-block
+    steps."""
     qi, j = pl.program_id(1), pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
-        dq_ref[...] = jnp.zeros_like(dq_ref)
+        dq_acc[...] = jnp.zeros_like(dq_acc)
 
+    @_when_runs(qi, j, block_q, block_k, causal)
     def _compute():
-        q = q_ref[0]
-        do = do_ref[0]
-        lse, delta = lse_ref[0], delta_ref[0]
         kb = k_ref[0]
-        vb = v_ref[0]
-        _, ds = _bwd_block(q, do, lse, delta, kb, vb, qi * block_q,
-                           j * block_k, seq_q, seq_k, causal, scale)
-        dq_ref[0] += jax.lax.dot_general(
-            ds.astype(kb.dtype), kb, (((1,), (0,)), ((), ())),
+        _, dsT = _bwd_tile(q_ref[0], do_ref[0], lse_ref[0], delta_ref[0],
+                           kb, v_ref[0], qi * block_q, j * block_k, seq_k,
+                           causal, scale)
+        dq_acc[...] += jax.lax.dot_general(
+            dsT.astype(kb.dtype), kb, _TN,
             preferred_element_type=jnp.float32)
 
-    if causal:
-        pl.when((qi + 1) * block_q - 1 >= j * block_k)(_compute)
-    else:
-        _compute()
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _store():
+        dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
 
 
 def _flash_bwd(causal, scale, block_q, block_k, bwd_block_q, bwd_block_k,
@@ -231,34 +346,52 @@ def _flash_bwd(causal, scale, block_q, block_k, bwd_block_q, bwd_block_k,
     sk = k.shape[1]
     bq = min(bwd_block_q, sq)
     bk = min(bwd_block_k, sk)
+    if _telemetry._active:
+        _count_tiles(("bwd_dkv", "bwd_dq"), bh, sq, sk, bq, bk, causal)
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1, keepdims=True)
+                    axis=-1)[:, None, :]
 
     sq_pad, sk_pad = -sq % bq, -sk % bk
     if sq_pad:
         pad = ((0, 0), (0, sq_pad), (0, 0))
         q, do = jnp.pad(q, pad), jnp.pad(do, pad)
-        lse, delta = (jnp.pad(lse, ((0, 0), (0, sq_pad), (0, 0))),
-                      jnp.pad(delta, ((0, 0), (0, sq_pad), (0, 0))))
+        pad = ((0, 0), (0, 0), (0, sq_pad))
+        lse, delta = jnp.pad(lse, pad), jnp.pad(delta, pad)
     if sk_pad:
         pad = ((0, 0), (0, sk_pad), (0, 0))
         k, v = jnp.pad(k, pad), jnp.pad(v, pad)
     sq_full, sk_full = sq + sq_pad, sk + sk_pad
     nq, nk = sq_full // bq, sk_full // bk
 
-    q_spec = pl.BlockSpec((1, bq, d), lambda i, a, b: (i, a, 0))
-    r_spec = pl.BlockSpec((1, bq, 1), lambda i, a, b: (i, a, 0))
-    k_spec = pl.BlockSpec((1, bk, d), lambda i, a, b: (i, b, 0))
+    # a step that has no work names the block of the nearest step that
+    # has, so the pipeline finds it resident and fetches nothing.  An
+    # index map is traced once a BlockSpec a layer: lax primitives on the
+    # int32 grid indices, not jnp's operators (a jit dispatch each)
+    if causal:
+        def q_of(a, b):     # dK/dV: first q-block that reaches k-block b
+            first = _div(jax.lax.mul(b, np.int32(bk)), bq)
+            return jax.lax.min(jax.lax.max(a, first), np.int32(nq - 1))
 
+        def k_of(a, b):     # dQ: last k-block that q-block a reaches
+            last = _div(jax.lax.add(jax.lax.mul(a, np.int32(bq)),
+                                    np.int32(bq - 1)), bk)
+            return jax.lax.min(b, last)
+    else:
+        def q_of(a, b):
+            return a
+
+        def k_of(a, b):
+            return b
+
+    kw = dict(block_q=bq, block_k=bk, seq_k=sk, causal=causal, scale=scale)
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, block_q=bq, block_k=bk,
-                          seq_q=sq, seq_k=sk, causal=causal, scale=scale),
+        functools.partial(_bwd_dkv_kernel, **kw),
         grid=(bh, nk, nq),
         in_specs=[
-            pl.BlockSpec((1, bq, d), lambda i, b, a: (i, a, 0)),
-            pl.BlockSpec((1, bq, d), lambda i, b, a: (i, a, 0)),
-            pl.BlockSpec((1, bq, 1), lambda i, b, a: (i, a, 0)),
-            pl.BlockSpec((1, bq, 1), lambda i, b, a: (i, a, 0)),
+            pl.BlockSpec((1, bq, d), lambda i, b, a: (i, q_of(a, b), 0)),
+            pl.BlockSpec((1, bq, d), lambda i, b, a: (i, q_of(a, b), 0)),
+            pl.BlockSpec((1, 1, bq), lambda i, b, a: (i, 0, q_of(a, b))),
+            pl.BlockSpec((1, 1, bq), lambda i, b, a: (i, 0, q_of(a, b))),
             pl.BlockSpec((1, bk, d), lambda i, b, a: (i, b, 0)),
             pl.BlockSpec((1, bk, d), lambda i, b, a: (i, b, 0)),
         ],
@@ -267,20 +400,25 @@ def _flash_bwd(causal, scale, block_q, block_k, bwd_block_q, bwd_block_k,
             pl.BlockSpec((1, bk, d), lambda i, b, a: (i, b, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, sk_full, d), jnp.float32),
-            jax.ShapeDtypeStruct((bh, sk_full, d), jnp.float32),
+            jax.ShapeDtypeStruct((bh, sk_full, d), k.dtype),
+            jax.ShapeDtypeStruct((bh, sk_full, d), v.dtype),
         ],
+        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
+                        pltpu.VMEM((bk, d), jnp.float32)],
         interpret=interpret,
         name="mx_flash_bwd_dkv",
     )(q, do, lse, delta, k, v)
 
+    q_spec = pl.BlockSpec((1, bq, d), lambda i, a, b: (i, a, 0))
+    r_spec = pl.BlockSpec((1, 1, bq), lambda i, a, b: (i, 0, a))
+    k_spec = pl.BlockSpec((1, bk, d), lambda i, a, b: (i, k_of(a, b), 0))
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, block_q=bq, block_k=bk,
-                          seq_q=sq, seq_k=sk, causal=causal, scale=scale),
+        functools.partial(_bwd_dq_kernel, **kw),
         grid=(bh, nq, nk),
         in_specs=[q_spec, q_spec, r_spec, r_spec, k_spec, k_spec],
         out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((bh, sq_full, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((bh, sq_full, d), q.dtype),
+        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
         name="mx_flash_bwd_dq",
     )(q, do, lse, delta, k, v)
@@ -289,7 +427,7 @@ def _flash_bwd(causal, scale, block_q, block_k, bwd_block_q, bwd_block_k,
         dq = dq[:, :sq]
     if sk_pad:
         dk, dv = dk[:, :sk], dv[:, :sk]
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+    return dq, dk, dv
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -307,7 +445,9 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     winner for this (seq_q, seq_k, head_dim) bucket when one is loaded,
     else the per-device static table (CPU row keeps the historical
     1024/512).  The backward tiles independently via bwd_block_q /
-    bwd_block_k.  Explicit values always win.
+    bwd_block_k.  Explicit values always win.  Compiled for a TPU, a block
+    shorter than its sequence is a multiple of the 128 lanes (the
+    statistics carry the sequence there); the interpreter takes any.
     """
     b, h, sq, d = q.shape
     sk = k.shape[2]
@@ -323,13 +463,17 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
         bb = resolve_blocks("flash_attention_bwd", (sq, sk, d))
         bwd_block_q = bb["block_q"] if bwd_block_q is None else bwd_block_q
         bwd_block_k = bb["block_k"] if bwd_block_k is None else bwd_block_k
+    # counted here, where a call is traced once: under jax.grad the
+    # forward kernel's python runs for the primal and again for the VJP
+    if _telemetry._active:
+        _count_tiles(("fwd",), b * h, sq, sk, block_q, block_k, causal)
     qr = q.reshape(b * h, sq, d)
     kr = k.reshape(b * h, sk, d)
     vr = v.reshape(b * h, sk, d)
     # TPU lanes are 128 wide: a 64-dim head halves every load/store and
     # forces relayouts. Zero-pad head_dim to the lane width — zeros add
     # nothing to q·k^T and the padded tail of out is exactly zero.
-    d_pad = -d % 128 if d < 128 else 0
+    d_pad = -d % _LANES if d < _LANES else 0
     if d_pad:
         pad = ((0, 0), (0, 0), (0, d_pad))
         qr, kr, vr = (jnp.pad(qr, pad), jnp.pad(kr, pad), jnp.pad(vr, pad))

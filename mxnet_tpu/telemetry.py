@@ -227,6 +227,10 @@ declare_metric("autotune.retunes_total", "counter",
 declare_metric("autotune.learned_rank_corr", "gauge",
                "Spearman rank correlation of the learned kernel cost "
                "model against measured trials at the last rank gate")
+declare_metric("kernel.flash_tiles_total", "counter",
+               "(q-block, k-block) tiles of the flash attention kernels "
+               "per traced call, times batch*heads, by kernel "
+               "(fwd|bwd_dkv|bwd_dq) and kind (computed|masked|skipped)")
 
 
 # -- switches ---------------------------------------------------------------

@@ -222,6 +222,47 @@ def _vmem_kept(kernel, bucket):
             if kernel_tile_bytes(kernel, bucket, b) <= budget]
 
 
+def test_flash_cost_model_follows_the_kernels_tiles():
+    """The analytic entries rank by what the kernels now do: a causal
+    tile above the diagonal costs the backward a launch and the forward
+    nothing, the forward holds K and V whole, the backward's fp32
+    scratch and four score tiles count against VMEM."""
+    from mxnet_tpu.autotune.cost import kernel_cost, kernel_tile_bytes
+    from mxnet_tpu.ops.pallas.flash_attention import tile_counts
+    bucket = (1024, 1024, 64)
+
+    def blocks(bq, bk):
+        return {"block_q": bq, "block_k": bk}
+    # backward at 512/512: 4 grid steps a kernel (1 skipped), 3 tiles of
+    # 7 passes, a pass of 512 x 512 x 128 weighing 1.0
+    assert tile_counts(1024, 1024, 512, 512, True)["skipped"] == 1
+    assert kernel_cost("flash_attention_bwd", bucket,
+                       blocks(512, 512)) == pytest.approx(2 * 4 + 7 * 3)
+    # forward: 2 grid steps + 3 loop trips, 3 tiles of 2 passes
+    assert kernel_cost("flash_attention", bucket,
+                       blocks(512, 512)) == pytest.approx(5 + 2 * 3)
+    # the order the v5e read (PERF.md, PR 26): larger tiles first
+    order = [blocks(512, 512), blocks(512, 256), blocks(256, 256),
+             blocks(256, 128)]
+    costs = [kernel_cost("flash_attention_bwd", bucket, b) for b in order]
+    assert costs == sorted(costs) and len(set(costs)) == len(costs)
+    # VMEM: the forward's K and V grow with the sequence at fixed blocks
+    assert (kernel_tile_bytes("flash_attention", (4096, 4096, 64),
+                              blocks(512, 512))
+            - kernel_tile_bytes("flash_attention", bucket, blocks(512, 512))
+            == 4 * 2 * (4096 - 1024) * 128)
+    assert kernel_tile_bytes("flash_attention_bwd", bucket,
+                             blocks(512, 512)) == 4 * (
+        4 * 512 * 128 + 6 * 512 * 128 + 4 * 512 * 512 + 2 * 512)
+    # the v5e's row is the chip's sweep, inside the searched space
+    v5e = K._STATIC_DEFAULTS["v5e"]
+    assert v5e["flash_attention_bwd"] == blocks(512, 512)
+    assert v5e["flash_attention"] == blocks(512, 512)
+    for kern in ("flash_attention", "flash_attention_bwd"):
+        for axis, val in v5e[kern].items():
+            assert val in K._SPACE[kern][axis]
+
+
 def test_search_converges_to_planted_optimum():
     best = {"block_q": 512, "block_k": 256}
     bucket = (2048, 2048, 128)
