@@ -195,6 +195,7 @@ def dense_fp8(x, w, b, site, flatten=False):
     ctx = current()
     xs, ws, gs = ctx.scales[site]
     h = x.reshape(x.shape[0], -1) if flatten and x.ndim > 2 else x
+    h = h.astype(jnp.float32)       # the grid's arithmetic is fp32
     record(site, jnp.max(jnp.abs(h)).astype(jnp.float32),
            jnp.max(jnp.abs(w)).astype(jnp.float32))
     return fp8_linear(h, w, b, xs, ws, gs)

@@ -21,7 +21,8 @@ TARGET_DTYPE_OPS = [
 # transcendentals, cumulative reductions)
 FP32_OPS = [
     "softmax", "log_softmax", "masked_softmax", "masked_log_softmax",
-    "softmin", "batch_norm", "layer_norm", "group_norm", "instance_norm",
+    "softmin", "batch_norm", "layer_norm", "rms_norm", "group_norm",
+    "instance_norm",
     "l2_normalization", "lrn",
     "sum", "mean", "var", "std", "norm", "cumsum", "prod", "nansum",
     "exp", "expm1", "log", "log1p", "log2", "log10", "erf", "erfinv",

@@ -1,8 +1,8 @@
 """gluon.nn (reference: python/mxnet/gluon/nn/__init__.py)."""
 from .basic_layers import (  # noqa: F401
     Sequential, HybridSequential, Dense, Dropout, BatchNorm, BatchNormReLU,
-    SyncBatchNorm, LayerNorm, GroupNorm, InstanceNorm, Embedding, Flatten,
-    Identity, Lambda, HybridLambda, Concatenate, HybridConcatenate,
+    SyncBatchNorm, LayerNorm, RMSNorm, GroupNorm, InstanceNorm, Embedding,
+    Flatten, Identity, Lambda, HybridLambda, Concatenate, HybridConcatenate,
 )
 from .conv_layers import (  # noqa: F401
     Conv1D, Conv2D, Conv3D, Conv1DTranspose, Conv2DTranspose, Conv3DTranspose,
@@ -16,9 +16,10 @@ from .activations import (  # noqa: F401
     Activation, LeakyReLU, PReLU, ELU, SELU, GELU, SiLU, Swish,
 )
 from .transformer import (  # noqa: F401
-    MultiHeadAttention, PositionwiseFFN, TransformerEncoder,
+    MultiHeadAttention, GroupedQueryAttention, PositionwiseFFN, GatedFFN,
+    TransformerEncoder,
     TransformerEncoderCell, TransformerDecoderCell,
 )
-from .moe import MoEDense  # noqa: F401
+from .moe import MoEDense, RoutedExperts  # noqa: F401
 from .fuse import FusableSequential  # noqa: F401
 from ..block import Block, HybridBlock, SymbolBlock  # noqa: F401

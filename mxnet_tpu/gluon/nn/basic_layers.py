@@ -123,6 +123,13 @@ class Dense(HybridBlock):
                     x._data, self.weight.data()._data,
                     self.bias.data()._data if self.bias is not None
                     else None, site, flatten=self._flatten)
+                # the kernel accumulates and returns fp32; under mx.amp
+                # the layer returns AMP's type, as the dense path it
+                # replaces does (what the next op saves for its backward
+                # pass is then as wide as without fp8, not twice)
+                from ... import amp as _amp
+                if _amp.is_active():
+                    raw = raw.astype(_amp.target_dtype())
                 out = _wrap(raw)
                 return self.act(out) if self.act is not None else out
         out = npx.fully_connected(
@@ -286,6 +293,27 @@ class LayerNorm(HybridBlock):
                 p._finish_deferred_init()
         return npx.layer_norm(x, self.gamma.data(), self.beta.data(),
                               axis=self._axis, eps=self._epsilon)
+
+
+class RMSNorm(HybridBlock):
+    """Root-mean-square norm over the last axis with a learned scale
+    (npx.rms_norm): ``x / sqrt(mean(x^2) + eps) * gamma``, float32
+    inside."""
+
+    def __init__(self, epsilon=1e-5, gamma_initializer="ones",
+                 in_channels=0):
+        super().__init__()
+        self._epsilon = epsilon
+        self.gamma = Parameter("gamma", shape=(in_channels,),
+                               init=gamma_initializer,
+                               allow_deferred_init=True)
+
+    def forward(self, x):
+        if not self.gamma._shape_known():
+            self.gamma._finish_deferred_init((x.shape[-1],))
+        elif self.gamma._data is None:
+            self.gamma._finish_deferred_init()
+        return npx.rms_norm(x, self.gamma.data(), eps=self._epsilon)
 
 
 class GroupNorm(HybridBlock):

@@ -1,18 +1,29 @@
-"""Mixture-of-Experts layers (expert parallelism).
+"""Mixture-of-Experts layers.
 
 Reference parity: none (the reference has no MoE — SURVEY §2.3 marks EP
 out of its scope; first-class here per the long-context/distributed brief).
-Design: Switch/Top-k router + experts stored as stacked weight tensors with
-a leading expert dim. Dispatch/combine are einsums over a one-hot dispatch
-mask — the GSPMD-friendly formulation: shard the expert dim over an 'ep'
-mesh axis (megatron_specs analog: P('ep', ...)) and XLA inserts the
-all-to-alls. Capacity-factor truncation keeps shapes static for jit.
+
+Two layers.  ``MoEDense`` is the Switch/top-k formulation: experts as
+stacked weights with a leading expert dim, dispatch and combine as einsums
+over a one-hot ``(tokens, experts, capacity)`` mask, tokens past an
+expert's capacity dropped.  Its expert dim can be sharded over any mesh
+axis a caller names (``moe_expert_specs``); ``MeshConfig`` itself has no
+'ep' axis, so that means a raw ``jax.sharding.Mesh``.
+
+``RoutedExperts`` is one chip's share of a drop-free expert layer: it is
+told the published expert count and which experts it holds, routes every
+token over all of them, and computes its own experts' part by grouped
+matrix products over ragged groups (``lax.ragged_dot``) — no capacity a
+expert, no one-hot tensor.  What the absent experts would add is left
+out: the sum over the shares of a layer is the whole layer.  There is no
+exchange between shares here (no 'ep' axis, no all-to-all).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from ... import telemetry as _telemetry
 from ...numpy.multiarray import _invoke
 from ..block import HybridBlock
 from ..parameter import Parameter
@@ -120,9 +131,161 @@ class MoEDense(HybridBlock):
                             self.w_out.data()), name="moe_dense")
 
 
+class RoutedExperts(HybridBlock):
+    """One share of a sigmoid-routed, drop-free expert layer on
+    (batch, seq, units), plus the shared expert every share computes.
+
+    ``num_experts`` is the published count the router scores;
+    ``held = (lo, hi)`` the experts whose weights live here.  Per token:
+    ``s = sigmoid(u Wr)`` (float32), the ``num_experts_per_tok`` largest
+    of ``s + expert_bias`` are selected, their weights are
+    ``route_scale * s_e / (sum of the selected s + 1e-20)`` — normalised
+    over all selected experts, held or not — and the layer returns
+    ``Shared(u) + sum over selected held e of w_e Expert_e(u)``, each
+    expert a SwiGLU of width ``hidden_size``.
+
+    The assignments that name a held expert are sorted by expert and
+    computed as ragged groups of at most ``rows_bound`` rows in all (a
+    static bound: shapes stay fixed for jit).  Assignments past it are
+    left out and counted.  State that is no trained parameter rides the
+    aux channel like BatchNorm's statistics: ``expert_bias`` (selection
+    bias; nothing here changes it), ``expert_load`` (assignments per
+    published expert, accumulated over training calls) and ``rows_over``
+    (assignments past the bound, accumulated).
+    """
+
+    def __init__(self, units, hidden_size, num_experts, num_experts_per_tok,
+                 held, rows_bound, shared_hidden_size=0, route_scale=1.0,
+                 dtype="float32"):
+        super().__init__()
+        lo, hi = held
+        if not 0 <= lo < hi <= num_experts:
+            raise ValueError(f"held experts {held} outside [0, "
+                             f"{num_experts})")
+        self._n_exp, self._topk = num_experts, num_experts_per_tok
+        self._lo, self._n_held = lo, hi - lo
+        self._rows, self._scale = int(rows_bound), float(route_scale)
+        n = self._n_held
+        self.router = Parameter("router", shape=(num_experts, units),
+                                dtype=dtype)
+        self.w_gate = Parameter("w_gate", shape=(n, units, hidden_size),
+                                dtype=dtype)
+        self.w_up = Parameter("w_up", shape=(n, units, hidden_size),
+                              dtype=dtype)
+        self.w_down = Parameter("w_down", shape=(n, hidden_size, units),
+                                dtype=dtype)
+        self.expert_bias = Parameter("expert_bias", grad_req="null",
+                                     shape=(num_experts,), init="zeros")
+        self.expert_load = Parameter("expert_load", grad_req="null",
+                                     shape=(num_experts,), dtype="int32",
+                                     init="zeros")
+        self.rows_over = Parameter("rows_over", grad_req="null",
+                                   shape=(1,), dtype="int32", init="zeros")
+        # the shared expert's three matrices, laid out as Dense keeps
+        # them (out, in); computed inside the layer's own scope
+        self._shared = bool(shared_hidden_size)
+        if self._shared:
+            f = shared_hidden_size
+            self.shared_gate = Parameter("shared_gate", shape=(f, units),
+                                         dtype=dtype)
+            self.shared_up = Parameter("shared_up", shape=(f, units),
+                                       dtype=dtype)
+            self.shared_down = Parameter("shared_down", shape=(units, f),
+                                         dtype=dtype)
+
+    def forward(self, x):
+        from ... import amp, autograd
+        shared = (self.shared_gate, self.shared_up, self.shared_down) \
+            if self._shared else ()
+        for p in (self.router, self.w_gate, self.w_up, self.w_down,
+                  self.expert_bias, self.expert_load, self.rows_over) \
+                + shared:
+            if p._data is None:
+                p._finish_deferred_init()
+        n_exp, topk, lo, n_held = (self._n_exp, self._topk, self._lo,
+                                   self._n_held)
+        bound, scale = self._rows, self._scale
+        compute = amp.target_dtype() if amp.is_active() else None
+        if _telemetry._active:
+            _telemetry.inc("moe.rows_bound_total", bound)
+
+        def fn(x_, router, w_gate, w_up, w_down, bias, *sh):
+            u = x_.reshape(-1, x_.shape[-1])
+            dt = u.dtype if compute is None else compute
+            with jax.named_scope("mx.moe"):
+                with jax.named_scope("mx.moe.route"):
+                    s = jax.nn.sigmoid(jnp.dot(
+                        u.astype(jnp.float32), router.astype(jnp.float32).T,
+                        precision=jax.lax.Precision.HIGHEST))
+                    _, idx = jax.lax.top_k(s + bias, topk)      # (T, k)
+                    # the selected scores and the counts from one
+                    # (T, k, n) comparison that fuses into its
+                    # reductions: a gather of k scores a token took
+                    # 0.5 ms a layer on the v5e
+                    hot = idx[..., None] == jnp.arange(n_exp)
+                    chosen = jnp.sum(jnp.where(hot, s[:, None, :], 0.0), -1)
+                    w = scale * chosen / (
+                        jnp.sum(chosen, -1, keepdims=True) + 1e-20)
+                    load = jnp.sum(hot, axis=(0, 1), dtype=jnp.int32)
+                    # held assignments first, grouped by expert; the
+                    # sort is stable, so a group keeps its tokens in order
+                    local = idx.reshape(-1) - lo
+                    key = jnp.where((local >= 0) & (local < n_held), local,
+                                    n_held)
+                    n_rows = min(bound, key.shape[0])   # a short call
+                    rows = jnp.argsort(key, stable=True)[:n_rows]
+                    held = load[lo:lo + n_held]
+                    ends = jnp.minimum(jnp.cumsum(held), n_rows)
+                    sizes = jnp.diff(ends, prepend=0)
+                    over = jnp.sum(held) - ends[-1]
+                    token = rows // topk
+                    live = (jnp.arange(n_rows) < ends[-1])[:, None]
+                    w_rows = w.reshape(-1)[rows][:, None]
+                    x_rows = jnp.where(live, u.astype(dt)[token], 0)
+
+                def grouped(rows, w):
+                    # rows past the groups belong to no expert, and the
+                    # grouped product leaves them unwritten on the chip
+                    # (whatever the buffer held, NaN too), forward and
+                    # transposed.  They are selected away, never
+                    # multiplied away, after every product: the select's
+                    # transpose does the same to the cotangents
+                    return jnp.where(live, jax.lax.ragged_dot(
+                        rows, w.astype(dt), sizes), 0)
+
+                with jax.named_scope("mx.moe.experts"):
+                    y = grouped(jax.nn.silu(grouped(x_rows, w_gate))
+                                * grouped(x_rows, w_up), w_down)
+                    out = 0.0
+                    if sh:
+                        g, up, down = (m.astype(dt) for m in sh)
+                        ud = u.astype(dt)
+                        out = ((jax.nn.silu(ud @ g.T) * (ud @ up.T))
+                               @ down.T).astype(jnp.float32)
+                out = out + jnp.zeros(u.shape, jnp.float32).at[token].add(
+                    y.astype(jnp.float32) * w_rows)
+                # the counts leave as floats (exact: at most tokens x k):
+                # an eager recording has no cotangent for an integer
+                return (out.astype(x_.dtype).reshape(x_.shape),
+                        load.astype(jnp.float32),
+                        over.astype(jnp.float32).reshape(1))
+
+        out, load, over = _invoke(
+            fn, (x, self.router.data(), self.w_gate.data(),
+                 self.w_up.data(), self.w_down.data(),
+                 self.expert_bias.data()) + tuple(p.data() for p in shared),
+            name="routed_experts")
+        if autograd.is_training():
+            counted, past = self.expert_load.data(), self.rows_over.data()
+            counted._rebind(counted._data + load._data.astype(jnp.int32))
+            past._rebind(past._data + over._data.astype(jnp.int32))
+        return out
+
+
 def moe_expert_specs(ep_axis="ep"):
-    """PartitionSpecs for MoEDense params: experts sharded over `ep_axis`
-    (the parallel.train.megatron_specs analog for EP)."""
+    """PartitionSpecs for MoEDense params: experts sharded over the mesh
+    axis named ``ep_axis`` (the parallel.train.megatron_specs analog).
+    ``MeshConfig`` builds no such axis: pass a raw Mesh that has one."""
     from jax.sharding import PartitionSpec as P
     return {
         "gate": P(),

@@ -14,7 +14,7 @@ from __future__ import annotations
 from ... import numpy as np
 from ... import numpy_extension as npx
 from ..block import HybridBlock
-from .basic_layers import Dense, Dropout, LayerNorm
+from .basic_layers import Dense, Dropout, LayerNorm, RMSNorm
 
 
 class MultiHeadAttention(HybridBlock):
@@ -182,6 +182,76 @@ class PositionwiseFFN(HybridBlock):
         if self.dropout is not None:
             h = self.dropout(h)
         return h
+
+
+class GatedFFN(HybridBlock):
+    """Gated feed-forward (SwiGLU, Shazeer 2020):
+    ``down(silu(gate(x)) * up(x))``, no biases."""
+
+    def __init__(self, units, hidden_size):
+        super().__init__()
+        self.gate_proj = Dense(hidden_size, use_bias=False, flatten=False)
+        self.up_proj = Dense(hidden_size, use_bias=False, flatten=False)
+        self.down_proj = Dense(units, use_bias=False, flatten=False)
+
+    def forward(self, x):
+        return self.down_proj(
+            npx.activation(self.gate_proj(x), act_type="silu")
+            * self.up_proj(x))
+
+
+class GroupedQueryAttention(HybridBlock):
+    """Gated causal self-attention on (batch, seq, units) with
+    ``num_heads`` query heads over ``num_kv_heads`` key/value heads, no
+    biases: RMSNorm over the head dimension on q and k (one scale vector
+    each, shared by the heads), and a sigmoid gate on the attention
+    output from a fourth projection of the input,
+    ``(o * sigmoid(g)) Wo``.  What differs by layer is optional: rotary
+    embedding on q and k, and a causal ``window``.  The core is
+    ``ops.attention.multi_head_attention``: the flash kernels on a TPU,
+    the XLA composition elsewhere.
+    """
+
+    def __init__(self, units, num_heads, num_kv_heads, head_dim=None,
+                 window=None, rotary=False, rope_theta=10000.0,
+                 epsilon=1e-5):
+        super().__init__()
+        head_dim = units // num_heads if head_dim is None else head_dim
+        if num_heads % num_kv_heads:
+            raise ValueError(f"{num_heads} query heads do not group over "
+                             f"{num_kv_heads} KV heads")
+        self._heads, self._kv_heads, self._dim = (num_heads, num_kv_heads,
+                                                  head_dim)
+        self._window, self._rotary, self._theta = window, rotary, rope_theta
+
+        def proj(n):
+            return Dense(n, use_bias=False, flatten=False)
+
+        self.query_proj = proj(num_heads * head_dim)
+        self.key_proj = proj(num_kv_heads * head_dim)
+        self.value_proj = proj(num_kv_heads * head_dim)
+        self.gate_proj = proj(num_heads * head_dim)
+        self.out_proj = proj(units)
+        self.q_norm = RMSNorm(epsilon, in_channels=head_dim)
+        self.k_norm = RMSNorm(epsilon, in_channels=head_dim)
+
+    def _heads_normed(self, t, norm, heads):
+        b, s, _ = t.shape
+        return norm(t.reshape(b, s, heads, self._dim)).reshape(b, s, -1)
+
+    def forward(self, x):
+        from ...ops.attention import multi_head_attention
+        q = self._heads_normed(self.query_proj(x), self.q_norm, self._heads)
+        k = self._heads_normed(self.key_proj(x), self.k_norm,
+                               self._kv_heads)
+        v = self.value_proj(x)
+        if self._rotary:
+            q = npx.rotary_embedding(q, self._heads, self._theta)
+            k = npx.rotary_embedding(k, self._kv_heads, self._theta)
+        out = multi_head_attention(q, k, v, self._heads, causal=True,
+                                   kv_heads=self._kv_heads,
+                                   window=self._window)
+        return self.out_proj(out * npx.sigmoid(self.gate_proj(x)))
 
 
 def _fused_ln_residual(x, h, ln, p):

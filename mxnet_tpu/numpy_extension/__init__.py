@@ -514,6 +514,37 @@ def layer_norm(data, gamma=None, beta=None, axis=-1, eps=1e-5):
     return _invoke(fn, (data, gamma, beta), name="layer_norm")
 
 
+def rms_norm(data, gamma, eps=1e-5):
+    """Root-mean-square norm over the last axis with a learned scale
+    (Zhang & Sennrich 2019; no mean, no bias), computed in float32 and
+    returned in the type it arrived in (under AMP it is an fp32 op and
+    arrives in float32)."""
+    def fn(x, g):
+        xf = x.astype(jnp.float32)
+        out = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+        return (out * g.astype(jnp.float32)).astype(x.dtype)
+    return _invoke(fn, (data, gamma), name="rms_norm")
+
+
+def rotary_embedding(data, heads, theta=10000.0):
+    """Rotary position embedding (Su et al. 2021) on (batch, seq,
+    heads*dim), positions 0..seq-1, the whole head rotated, rotate-half
+    convention: ``x * cos + concat(-x2, x1) * sin`` with ``x1, x2`` the
+    halves of a head and angle ``pos * theta**(-2i/dim)`` for the pair
+    ``i``.  Angles and products in float32."""
+    def fn(x):
+        b, s, hd = x.shape
+        d = hd // heads
+        inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+        ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
+        cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
+        xf = x.astype(jnp.float32).reshape(b, s, heads, d)
+        x1, x2 = xf[..., :d // 2], xf[..., d // 2:]
+        out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+        return out.reshape(b, s, hd).astype(x.dtype)
+    return _invoke(fn, (data,), name="rotary_embedding")
+
+
 def group_norm(data, gamma=None, beta=None, num_groups=1, eps=1e-5):
     """Reference: src/operator/nn/group_norm.cc (N, C, ...) layout."""
     def fn(x, g, b):

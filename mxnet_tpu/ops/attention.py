@@ -19,18 +19,25 @@ from ..numpy.multiarray import _invoke
 
 
 def _reference_attention(q, k, v, heads, mask=None, causal=False, scale=None,
-                         dropout_p=0.0):
-    """(batch, seq, heads*dim) XLA composition."""
+                         dropout_p=0.0, kv_heads=None, window=None):
+    """(batch, seq, heads*dim) XLA composition; K and V carry
+    ``kv_heads * dim`` (each KV head repeated over its query heads)."""
     b, sq, hd = q.shape
     sk = k.shape[1]
     d = hd // heads
+    kv_heads = heads if kv_heads is None else kv_heads
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
     qh = q.reshape(b, sq, heads, d).transpose(0, 2, 1, 3)
-    kh = k.reshape(b, sk, heads, d).transpose(0, 2, 1, 3)
-    vh = v.reshape(b, sk, heads, d).transpose(0, 2, 1, 3)
+    kh = k.reshape(b, sk, kv_heads, d).transpose(0, 2, 1, 3)
+    vh = v.reshape(b, sk, kv_heads, d).transpose(0, 2, 1, 3)
+    if kv_heads != heads:
+        kh = jnp.repeat(kh, heads // kv_heads, axis=1)
+        vh = jnp.repeat(vh, heads // kv_heads, axis=1)
     scores = jnp.einsum("bhqd,bhkd->bhqk", qh, kh) * scale
     if causal:
         cm = jnp.tril(jnp.ones((sq, sk), dtype=bool))
+        if window is not None:
+            cm &= ~jnp.tril(jnp.ones((sq, sk), dtype=bool), -window)
         scores = jnp.where(cm, scores, -1e30)
     if mask is not None:
         scores = jnp.where(mask.astype(bool), scores, -1e30)
@@ -71,8 +78,9 @@ def _sp_mesh():
     return None
 
 
-def _flash(qh, kh, vh, causal):
-    """The Pallas flash kernel on (batch, heads, seq, dim) operands.
+def _flash(qh, kh, vh, causal, window=None):
+    """The Pallas flash kernel on (batch, heads, seq, dim) queries and
+    (batch, kv_heads, seq, dim) keys and values.
 
     GSPMD cannot partition a Mosaic kernel — on a multi-device mesh the
     lowering stops with "Mosaic kernels cannot be automatically
@@ -82,6 +90,10 @@ def _flash(qh, kh, vh, causal):
     shard_map over every mesh axis: batch over 'dp', heads over 'tp'
     (where the axis exists and divides), each device running the kernel
     on its own block; axes that do not divide leave the dim replicated.
+    Heads shard by the KV head count, for all three operands alike: a
+    'tp' that divides the KV heads divides the query heads too and keeps
+    every group of query heads beside its KV head, and one that does not
+    would split a group, so the heads then stay replicated.
     Already inside a shard_map over the whole mesh (the compressed-
     gradient path) the operands are per-device and the kernel is called
     as is.
@@ -91,7 +103,7 @@ def _flash(qh, kh, vh, causal):
     if (mesh is None or mesh.size == 1
             or set(jax.sharding.get_abstract_mesh().manual_axes)
             >= set(mesh.axis_names)):
-        return flash_attention(qh, kh, vh, causal=causal)
+        return flash_attention(qh, kh, vh, causal=causal, window=window)
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
@@ -99,9 +111,10 @@ def _flash(qh, kh, vh, causal):
         n = int(mesh.shape.get(name, 1))
         return name if n > 1 and dim % n == 0 else None
 
-    spec = P(axis("dp", qh.shape[0]), axis("tp", qh.shape[1]), None, None)
+    spec = P(axis("dp", qh.shape[0]), axis("tp", kh.shape[1]), None, None)
     return shard_map(
-        lambda q, k, v: flash_attention(q, k, v, causal=causal),
+        lambda q, k, v: flash_attention(q, k, v, causal=causal,
+                                        window=window),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False)(qh, kh, vh)
 
@@ -436,19 +449,32 @@ def decode_attention(query, key, value, k_cache, v_cache, positions, heads):
 
 
 def multi_head_attention(query, key, value, heads, mask=None, dropout_p=0.0,
-                         causal=False):
-    """Fused MHA on (batch, seq, heads*dim) ndarrays.
+                         causal=False, kv_heads=None, window=None):
+    """Fused MHA on (batch, seq, heads*dim) queries and (batch, seq,
+    kv_heads*dim) keys and values.
+
+    ``kv_heads`` (default ``heads``) divides ``heads``: query head ``i``
+    reads KV head ``i // (heads // kv_heads)``.  ``window`` (causal only)
+    keeps query ``i`` to the keys ``0 <= i - j < window``.
 
     Routing: sp-sharded scope -> ring attention (sequence parallelism over
-    ICI); long unmasked sequences on TPU -> Pallas flash kernel; otherwise
-    the XLA dot_general composition. Attention-prob dropout (training only,
-    reference: transformer attention cells) forces the XLA path.
+    ICI; ungrouped, unwindowed calls only); long unmasked sequences on
+    TPU -> Pallas flash kernel; otherwise the XLA dot_general
+    composition. Attention-prob dropout (training only, reference:
+    transformer attention cells) forces the XLA path.
     """
+    kv_heads = heads if kv_heads is None else kv_heads
+    if heads % kv_heads:
+        raise ValueError(f"{heads} query heads do not group over "
+                         f"{kv_heads} KV heads")
+    if window is not None and not causal:
+        raise ValueError("a window is a causal band: pass causal=True")
     from .. import autograd
     if not autograd.is_training():
         dropout_p = 0.0
     pure = mask is None and dropout_p == 0.0
-    sp_mesh = _sp_mesh() if pure else None
+    plain = kv_heads == heads and window is None
+    sp_mesh = _sp_mesh() if pure and plain else None
 
     # one scope a layer: split, pad to the kernel's lanes, kernel, slice,
     # merge — the glue is this scope's time less its kernels'
@@ -458,8 +484,8 @@ def multi_head_attention(query, key, value, heads, mask=None, dropout_p=0.0,
         sk = k.shape[1]
         d = hd // heads
 
-        def split(t):       # (b, s, heads*d) -> (b, heads, s, d)
-            return t.reshape(b, -1, heads, d).transpose(0, 2, 1, 3)
+        def split(t, n=heads):      # (b, s, n*d) -> (b, n, s, d)
+            return t.reshape(b, -1, n, d).transpose(0, 2, 1, 3)
 
         def merge(t):
             return t.transpose(0, 2, 1, 3).reshape(b, sq, hd)
@@ -473,9 +499,10 @@ def multi_head_attention(query, key, value, heads, mask=None, dropout_p=0.0,
         if _runtime.on_tpu() and pure and sk >= min_seq:
             # no fallback here: a kernel Mosaic refuses must surface as
             # the compiler's error, not as a slower step
-            return merge(_flash(split(q), split(k), split(v), causal))
+            return merge(_flash(split(q), split(k, kv_heads),
+                                split(v, kv_heads), causal, window))
         m = mask._data if hasattr(mask, "_data") else mask
         return _reference_attention(q, k, v, heads, m, causal, None,
-                                    dropout_p)
+                                    dropout_p, kv_heads, window)
 
     return _invoke(fn, (query, key, value), name="multi_head_attention")

@@ -20,6 +20,13 @@ What the three kernels move and visit (``mx_flash_fwd``,
   the transposed-LHS form.
 * Gradients accumulate in fp32 VMEM scratch across the sequential grid
   axis and leave the kernel once, in the operands' dtype.
+* Grouped KV heads: K and V keep their own head count.  The forward and
+  dQ read the KV head of their query head through the index map (eight
+  consecutive query heads name the same block, so it is fetched once);
+  dK/dV walk the group's query heads into one accumulator.  A causal
+  ``window`` skips the tiles left of the band as the diagonal skips
+  those above it.  With one KV head a query head and no window every
+  branch below is taken in Python and the kernels trace as they did.
 * Causal tiles (``tile_counts``): a tile above the diagonal is *skipped* —
   not computed, and its blocks not fetched, because the index maps clamp
   to the nearest tile that has work, so a skipped step names the block
@@ -44,6 +51,7 @@ from ... import telemetry as _telemetry
 
 _NEG_INF = -1e30
 _LANES = 128
+_VMEM_DEFAULT = 16 << 20    # Mosaic's scoped VMEM limit a kernel
 
 _NT = (((1,), (1,)), ((), ()))      # a @ b.T
 _NN = (((1,), (0,)), ((), ()))      # a @ b
@@ -56,28 +64,38 @@ _TN = (((0,), (0,)), ((), ()))      # a.T @ b
 # (the counter, the tests) and traced grid indices (the kernels) alike.
 # ---------------------------------------------------------------------------
 
-def _tile_runs(a, b, block_q, block_k, causal):
-    """Some query of q-block ``a`` attends some key of k-block ``b``."""
+def _tile_runs(a, b, block_q, block_k, causal, window=None):
+    """Some query of q-block ``a`` attends some key of k-block ``b``:
+    the tile is not wholly above the diagonal and, with a window, not
+    wholly left of the band (query ``i`` attends ``0 <= i - j <
+    window``)."""
     if not causal:
         return True
-    return a * block_q + (block_q - 1) >= b * block_k
+    runs = a * block_q + (block_q - 1) >= b * block_k
+    if window is None:
+        return runs
+    return runs & (a * block_q < (b + 1) * block_k - 1 + window)
 
 
-def _tile_masked(a, b, block_q, block_k, seq_k, causal):
+def _tile_masked(a, b, block_q, block_k, seq_k, causal, window=None):
     """Some (query, key) pair of a running tile is not attended: the
-    diagonal crosses it, or K's zero padding starts inside it."""
+    diagonal or the band's left edge crosses it, or K's zero padding
+    starts inside it."""
     edge = (b + 1) * block_k > seq_k
     if not causal:
         return edge
-    return edge or a * block_q < (b + 1) * block_k - 1
+    masked = edge or a * block_q < (b + 1) * block_k - 1
+    if window is None:
+        return masked
+    return masked or a * block_q + (block_q - 1) - b * block_k >= window
 
 
-def _when_runs(a, b, block_q, block_k, causal):
+def _when_runs(a, b, block_q, block_k, causal, window=None):
     """Decorator of a backward tile body: run it where the tile has work,
     which without a diagonal is everywhere (no branch is traced)."""
     if not causal:
         return lambda body: body()
-    return pl.when(_tile_runs(a, b, block_q, block_k, causal))
+    return pl.when(_tile_runs(a, b, block_q, block_k, causal, window))
 
 
 def _div(x, n):
@@ -96,35 +114,44 @@ def _fwd_visits(qi, nk, block_q, block_k, causal, minimum=min):
     return minimum(nk, _div((qi + 1) * block_q + block_k - 1, block_k))
 
 
-def tile_counts(seq_q, seq_k, block_q, block_k, causal):
+def _first_k_block(q0, block_k, window, maximum=max):
+    """First K block the band reaches for queries starting at ``q0``:
+    the block of key ``q0 - (window - 1)``."""
+    return _div(maximum(q0 - (window - 1), 0), block_k)
+
+
+def tile_counts(seq_q, seq_k, block_q, block_k, causal, window=None):
     """Tiles one head's kernels compute unmasked (``computed``), compute
     where the mask changes something (``masked``) and skip (``skipped``).
     A tile is one grid step of either backward kernel and one trip of the
-    forward's k-loop (``_fwd_visits`` counts the same tiles a q-block).
-    Blocks clamp to the sequence as in the kernels."""
+    forward's k-loop (``_fwd_visits`` and ``_first_k_block`` bound the
+    same tiles a q-block).  Blocks clamp to the sequence as in the
+    kernels."""
     bq, bk = min(block_q, seq_q), min(block_k, seq_k)
     counts = {"computed": 0, "masked": 0, "skipped": 0}
     for a in range(-(-seq_q // bq)):
         for b in range(-(-seq_k // bk)):
-            if not _tile_runs(a, b, bq, bk, causal):
+            if not _tile_runs(a, b, bq, bk, causal, window):
                 counts["skipped"] += 1
-            elif _tile_masked(a, b, bq, bk, seq_k, causal):
+            elif _tile_masked(a, b, bq, bk, seq_k, causal, window):
                 counts["masked"] += 1
             else:
                 counts["computed"] += 1
     return counts
 
 
-def _count_tiles(kernels, bh, seq_q, seq_k, block_q, block_k, causal):
-    """``kernel.flash_tiles_total``: once a traced call, tiles x ``bh``."""
-    counts = tile_counts(seq_q, seq_k, block_q, block_k, causal)
+def _count_tiles(kernels, bh, seq_q, seq_k, block_q, block_k, causal,
+                 window=None):
+    """``kernel.flash_tiles_total``: once a traced call, tiles x ``bh``
+    (query heads: each runs its own tiles, grouped or not)."""
+    counts = tile_counts(seq_q, seq_k, block_q, block_k, causal, window)
     for kernel in kernels:
         for kind, n in counts.items():
             _telemetry.inc("kernel.flash_tiles_total", n * bh,
                            kernel=kernel, kind=kind)
 
 
-def _attended(q0, k0, shape, q_axis, seq_k, causal):
+def _attended(q0, k0, shape, q_axis, seq_k, causal, window=None):
     """The mask of one tile whose queries start at ``q0`` along
     ``q_axis`` and whose keys start at ``k0`` along the other axis.
     In-kernel loads of a zero-padded final K block see zeros, not
@@ -134,6 +161,8 @@ def _attended(q0, k0, shape, q_axis, seq_k, causal):
     if causal:
         q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
         valid &= q_pos >= k_pos
+        if window is not None:
+            valid &= q_pos - k_pos < window
     return valid
 
 
@@ -142,7 +171,7 @@ def _attended(q0, k0, shape, q_axis, seq_k, causal):
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, seq_k,
-                causal, scale, block_q):
+                causal, scale, block_q, window=None):
     """One q-block against its k-blocks, tiles transposed as in the
     backward (``sT = k @ q.T``): the running max and sum are ``(1, bq)``
     rows, reduced over sublanes and broadcast along them, and ``lse``
@@ -167,7 +196,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, seq_k,
                                  preferred_element_type=jnp.float32) * scale
         if masked:
             sT = jnp.where(_attended(qi * block_q, k0, sT.shape, 1, seq_k,
-                                     causal), sT, _NEG_INF)
+                                     causal, window), sT, _NEG_INF)
         m_new = jnp.maximum(m_prev, jnp.max(sT, axis=0, keepdims=True))
         pT = jnp.exp(sT - m_new)
         corr = jnp.exp(m_prev - m_new)
@@ -180,16 +209,27 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, seq_k,
              jnp.zeros((1, bq), jnp.float32),
              jnp.zeros((d, bq), jnp.float32))
     n_visit = _fwd_visits(qi, nk, block_q, block_k, causal, jnp.minimum)
-    carry = jax.lax.fori_loop(0, n_visit, body, carry)
+    first = 0 if window is None else _first_k_block(
+        qi * block_q, block_k, window, jnp.maximum)
+    carry = jax.lax.fori_loop(first, n_visit, body, carry)
     m, l, acc = carry
     l_safe = jnp.maximum(l, 1e-30)
     o_ref[0] = (acc / l_safe).T.astype(o_ref.dtype)
     lse_ref[0] = m + jnp.log(l_safe)
 
 
-def _fwd(q, k, v, causal, scale, block_q, block_k, interpret):
+def _kv_row(group):
+    """The K/V row of query row ``i`` (rows are batch-major, heads
+    minor, so consecutive ``group`` query heads share one KV head)."""
+    if group == 1:
+        return lambda i: i
+    return lambda i: _div(i, group)
+
+
+def _fwd(q, k, v, causal, scale, block_q, block_k, interpret, window=None):
     bh, sq, d = q.shape
     sk = k.shape[1]
+    kv = _kv_row(bh // k.shape[0])
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
     # pad to block multiples: in-kernel pl.ds loads clamp at the array end,
@@ -204,16 +244,23 @@ def _fwd(q, k, v, causal, scale, block_q, block_k, interpret):
         v = jnp.pad(v, ((0, 0), (0, sk_pad), (0, 0)))
     sq_full, sk_full = sq + sq_pad, sk + sk_pad
     grid = (bh, sq_full // block_q)
+    # whole K and V of a head stay resident, double-buffered: past the
+    # default scoped limit (16 MiB: fp32 at seq 8192) the call asks for
+    # what it holds; shorter calls are compiled as they always were
+    resident = 4 * sk_full * d * k.dtype.itemsize
+    extra = {} if resident <= _VMEM_DEFAULT - (4 << 20) else {
+        "compiler_params": pltpu.CompilerParams(
+            vmem_limit_bytes=resident + (16 << 20))}
     kernel = functools.partial(
         _fwd_kernel, block_k=block_k, seq_k=sk, causal=causal, scale=scale,
-        block_q=block_q)
+        block_q=block_q, window=window)
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, sk_full, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, sk_full, d), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((1, sk_full, d), lambda i, j: (kv(i), 0, 0)),
+            pl.BlockSpec((1, sk_full, d), lambda i, j: (kv(i), 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
@@ -225,6 +272,7 @@ def _fwd(q, k, v, causal, scale, block_q, block_k, interpret):
         ],
         interpret=interpret,
         name="mx_flash_fwd",
+        **extra,
     )(q, k, v)
     if sq_pad:
         out = out[:, :sq]
@@ -232,16 +280,19 @@ def _fwd(q, k, v, causal, scale, block_q, block_k, interpret):
     return out, lse
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
 def _flash(q, k, v, causal, scale, block_q, block_k, bwd_block_q,
-           bwd_block_k, interpret):
-    out, _ = _fwd(q, k, v, causal, scale, block_q, block_k, interpret)
+           bwd_block_k, interpret, window=None):
+    out, _ = _fwd(q, k, v, causal, scale, block_q, block_k, interpret,
+                  window)
     return out
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, bwd_block_q,
-               bwd_block_k, interpret):
-    out, lse = _fwd(q, k, v, causal, scale, block_q, block_k, interpret)
+               bwd_block_k, interpret, window=None):
+    out, lse = _fwd(q, k, v, causal, scale, block_q, block_k, interpret,
+                    window)
     return out, (q, k, v, out, lse)
 
 
@@ -249,7 +300,8 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, bwd_block_q,
 # backward
 # ---------------------------------------------------------------------------
 
-def _bwd_tile(q, do, lse, delta, kb, vb, q0, k0, seq_k, causal, scale):
+def _bwd_tile(q, do, lse, delta, kb, vb, q0, k0, seq_k, causal, scale,
+              window=None):
     """Shared recompute for one tile, transposed: returns (pT, dsT), both
     ``(block_k, block_q)``.
 
@@ -264,8 +316,8 @@ def _bwd_tile(q, do, lse, delta, kb, vb, q0, k0, seq_k, causal, scale):
                              preferred_element_type=jnp.float32) * scale
     pT = jnp.exp(sT - lse)
     if causal or seq_k % kb.shape[0] != 0:
-        pT = jnp.where(_attended(q0, k0, sT.shape, 1, seq_k, causal),
-                       pT, 0.0)
+        pT = jnp.where(_attended(q0, k0, sT.shape, 1, seq_k, causal,
+                                 window), pT, 0.0)
     dpT = jax.lax.dot_general(vb, do, _NT,
                               preferred_element_type=jnp.float32)
     dsT = pT * (dpT - delta) * scale
@@ -274,24 +326,38 @@ def _bwd_tile(q, do, lse, delta, kb, vb, q0, k0, seq_k, causal, scale):
 
 def _bwd_dkv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc, *, block_q, block_k,
-                    seq_k, causal, scale):
+                    seq_k, causal, scale, window=None, group=1):
     """dK/dV for one k-block, accumulated in VMEM over sequential q-block
     steps (grid (bh, nk, nq): the last axis revisits the same output
-    block, written once on its last step)."""
-    j, qi = pl.program_id(1), pl.program_id(2)
+    block, written once on its last step).  With grouped KV heads the
+    grid is (b * kv_heads, nk, group, nq): the last two axes walk the
+    q-blocks of every query head of the group into the same
+    accumulator."""
+    j = pl.program_id(1)
+    q_axis = 2 if group == 1 else 3
+    if group == 1:
+        qi = pl.program_id(2)
 
-    @pl.when(qi == 0)
+        def is_step(g_at, q_at):    # traced where it is used, as before
+            return qi == q_at
+    else:
+        g, qi = pl.program_id(2), pl.program_id(3)
+
+        def is_step(g_at, q_at):
+            return (g == g_at) & (qi == q_at)
+
+    @pl.when(is_step(0, 0))
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    @_when_runs(qi, j, block_q, block_k, causal)
+    @_when_runs(qi, j, block_q, block_k, causal, window)
     def _compute():
         q = q_ref[0]
         do = do_ref[0]
         pT, dsT = _bwd_tile(q, do, lse_ref[0], delta_ref[0], k_ref[0],
                             v_ref[0], qi * block_q, j * block_k, seq_k,
-                            causal, scale)
+                            causal, scale, window)
         dv_acc[...] += jax.lax.dot_general(
             pT.astype(do.dtype), do, _NN,
             preferred_element_type=jnp.float32)
@@ -299,14 +365,15 @@ def _bwd_dkv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
             dsT.astype(q.dtype), q, _NN,
             preferred_element_type=jnp.float32)
 
-    @pl.when(qi == pl.num_programs(2) - 1)
+    @pl.when(is_step(group - 1, pl.num_programs(q_axis) - 1))
     def _store():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def _bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, dq_ref,
-                   dq_acc, *, block_q, block_k, seq_k, causal, scale):
+                   dq_acc, *, block_q, block_k, seq_k, causal, scale,
+                   window=None):
     """dQ for one q-block, accumulated in VMEM over sequential k-block
     steps."""
     qi, j = pl.program_id(1), pl.program_id(2)
@@ -315,12 +382,12 @@ def _bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, dq_ref,
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    @_when_runs(qi, j, block_q, block_k, causal)
+    @_when_runs(qi, j, block_q, block_k, causal, window)
     def _compute():
         kb = k_ref[0]
         _, dsT = _bwd_tile(q_ref[0], do_ref[0], lse_ref[0], delta_ref[0],
                            kb, v_ref[0], qi * block_q, j * block_k, seq_k,
-                           causal, scale)
+                           causal, scale, window)
         dq_acc[...] += jax.lax.dot_general(
             dsT.astype(kb.dtype), kb, _TN,
             preferred_element_type=jnp.float32)
@@ -331,7 +398,7 @@ def _bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, dq_ref,
 
 
 def _flash_bwd(causal, scale, block_q, block_k, bwd_block_q, bwd_block_k,
-               interpret, res, do):
+               interpret, window, res, do):
     """Blocked Pallas backward (flash-style residuals: out + logsumexp).
 
     Memory is O(seq): P is rebuilt per (q-block, k-block) tile in VMEM from
@@ -343,11 +410,13 @@ def _flash_bwd(causal, scale, block_q, block_k, bwd_block_q, bwd_block_k,
     """
     q, k, v, out, lse = res
     bh, sq, d = q.shape
-    sk = k.shape[1]
+    bkv, sk = k.shape[:2]
+    group = bh // bkv
     bq = min(bwd_block_q, sq)
     bk = min(bwd_block_k, sk)
     if _telemetry._active:
-        _count_tiles(("bwd_dkv", "bwd_dq"), bh, sq, sk, bq, bk, causal)
+        _count_tiles(("bwd_dkv", "bwd_dq"), bh, sq, sk, bq, bk, causal,
+                     window)
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)[:, None, :]
 
@@ -367,7 +436,23 @@ def _flash_bwd(causal, scale, block_q, block_k, bwd_block_q, bwd_block_k,
     # has, so the pipeline finds it resident and fetches nothing.  An
     # index map is traced once a BlockSpec a layer: lax primitives on the
     # int32 grid indices, not jnp's operators (a jit dispatch each)
-    if causal:
+    if causal and window is not None:
+        # the band ends on both sides: clamp to the first and the last
+        # tile of the row (dQ) or column (dK/dV) that has work
+        def q_of(a, b):
+            k0 = jax.lax.mul(b, np.int32(bk))
+            first = _div(k0, bq)
+            last = _div(jax.lax.add(k0, np.int32(bk - 2 + window)), bq)
+            return jax.lax.min(jax.lax.min(jax.lax.max(a, first), last),
+                               np.int32(nq - 1))
+
+        def k_of(a, b):
+            q0 = jax.lax.mul(a, np.int32(bq))
+            first = _div(jax.lax.max(jax.lax.sub(q0, np.int32(window - 1)),
+                                     np.int32(0)), bk)
+            last = _div(jax.lax.add(q0, np.int32(bq - 1)), bk)
+            return jax.lax.min(jax.lax.max(b, first), last)
+    elif causal:
         def q_of(a, b):     # dK/dV: first q-block that reaches k-block b
             first = _div(jax.lax.mul(b, np.int32(bk)), bq)
             return jax.lax.min(jax.lax.max(a, first), np.int32(nq - 1))
@@ -383,25 +468,32 @@ def _flash_bwd(causal, scale, block_q, block_k, bwd_block_q, bwd_block_k,
         def k_of(a, b):
             return b
 
-    kw = dict(block_q=bq, block_k=bk, seq_k=sk, causal=causal, scale=scale)
+    kw = dict(block_q=bq, block_k=bk, seq_k=sk, causal=causal, scale=scale,
+              window=window)
+    if group == 1:
+        dkv_grid = (bh, nk, nq)
+        dkv_q = pl.BlockSpec((1, bq, d), lambda i, b, a: (i, q_of(a, b), 0))
+        dkv_r = pl.BlockSpec((1, 1, bq), lambda i, b, a: (i, 0, q_of(a, b)))
+        dkv_k = pl.BlockSpec((1, bk, d), lambda i, b, a: (i, b, 0))
+    else:
+        # one KV head a grid row; its query heads are rows i*group + g
+        def q_row(i, g):
+            return jax.lax.add(jax.lax.mul(i, np.int32(group)), g)
+
+        dkv_grid = (bkv, nk, group, nq)
+        dkv_q = pl.BlockSpec(
+            (1, bq, d), lambda i, b, g, a: (q_row(i, g), q_of(a, b), 0))
+        dkv_r = pl.BlockSpec(
+            (1, 1, bq), lambda i, b, g, a: (q_row(i, g), 0, q_of(a, b)))
+        dkv_k = pl.BlockSpec((1, bk, d), lambda i, b, g, a: (i, b, 0))
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, **kw),
-        grid=(bh, nk, nq),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda i, b, a: (i, q_of(a, b), 0)),
-            pl.BlockSpec((1, bq, d), lambda i, b, a: (i, q_of(a, b), 0)),
-            pl.BlockSpec((1, 1, bq), lambda i, b, a: (i, 0, q_of(a, b))),
-            pl.BlockSpec((1, 1, bq), lambda i, b, a: (i, 0, q_of(a, b))),
-            pl.BlockSpec((1, bk, d), lambda i, b, a: (i, b, 0)),
-            pl.BlockSpec((1, bk, d), lambda i, b, a: (i, b, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bk, d), lambda i, b, a: (i, b, 0)),
-            pl.BlockSpec((1, bk, d), lambda i, b, a: (i, b, 0)),
-        ],
+        functools.partial(_bwd_dkv_kernel, group=group, **kw),
+        grid=dkv_grid,
+        in_specs=[dkv_q, dkv_q, dkv_r, dkv_r, dkv_k, dkv_k],
+        out_specs=[dkv_k, dkv_k],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, sk_full, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, sk_full, d), v.dtype),
+            jax.ShapeDtypeStruct((bkv, sk_full, d), k.dtype),
+            jax.ShapeDtypeStruct((bkv, sk_full, d), v.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
@@ -411,7 +503,9 @@ def _flash_bwd(causal, scale, block_q, block_k, bwd_block_q, bwd_block_k,
 
     q_spec = pl.BlockSpec((1, bq, d), lambda i, a, b: (i, a, 0))
     r_spec = pl.BlockSpec((1, 1, bq), lambda i, a, b: (i, 0, a))
-    k_spec = pl.BlockSpec((1, bk, d), lambda i, a, b: (i, k_of(a, b), 0))
+    kv = _kv_row(group)
+    k_spec = pl.BlockSpec((1, bk, d),
+                          lambda i, a, b: (kv(i), k_of(a, b), 0))
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **kw),
         grid=(bh, nq, nk),
@@ -435,10 +529,16 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                     block_k=None, bwd_block_q=None, bwd_block_k=None,
-                    interpret=False):
+                    interpret=False, window=None):
     """Multi-head attention, scores never materialized in HBM.
 
-    q: (batch, heads, seq_q, head_dim); k/v: (batch, heads, seq_k, head_dim).
+    q: (batch, heads, seq_q, head_dim); k/v: (batch, kv_heads, seq_k,
+    head_dim) with ``heads`` a multiple of ``kv_heads``: query head ``i``
+    reads KV head ``i // (heads // kv_heads)``, the K/V index maps name
+    that head (nothing is repeated in HBM) and dK/dV sum over the group
+    inside the kernel.  ``window`` (with ``causal``) keeps query ``i`` to
+    the keys ``0 <= i - j < window``; tiles left of that band are skipped
+    like tiles above the diagonal.
     Returns (batch, heads, seq_q, head_dim).
 
     Block shapes default to ``mx.autotune.resolve_blocks`` — the tuned
@@ -450,7 +550,11 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     statistics carry the sequence there); the interpreter takes any.
     """
     b, h, sq, d = q.shape
-    sk = k.shape[2]
+    hk, sk = k.shape[1:3]
+    if h % hk:
+        raise ValueError(f"{h} query heads do not group over {hk} KV heads")
+    if window is not None and not causal:
+        raise ValueError("a window is a causal band: pass causal=True")
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     if block_q is None or block_k is None:
@@ -466,10 +570,11 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     # counted here, where a call is traced once: under jax.grad the
     # forward kernel's python runs for the primal and again for the VJP
     if _telemetry._active:
-        _count_tiles(("fwd",), b * h, sq, sk, block_q, block_k, causal)
+        _count_tiles(("fwd",), b * h, sq, sk, block_q, block_k, causal,
+                     window)
     qr = q.reshape(b * h, sq, d)
-    kr = k.reshape(b * h, sk, d)
-    vr = v.reshape(b * h, sk, d)
+    kr = k.reshape(b * hk, sk, d)
+    vr = v.reshape(b * hk, sk, d)
     # TPU lanes are 128 wide: a 64-dim head halves every load/store and
     # forces relayouts. Zero-pad head_dim to the lane width — zeros add
     # nothing to q·k^T and the padded tail of out is exactly zero.
@@ -478,7 +583,7 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
         pad = ((0, 0), (0, 0), (0, d_pad))
         qr, kr, vr = (jnp.pad(qr, pad), jnp.pad(kr, pad), jnp.pad(vr, pad))
     out = _flash(qr, kr, vr, causal, scale, block_q, block_k, bwd_block_q,
-                 bwd_block_k, interpret)
+                 bwd_block_k, interpret, window)
     if d_pad:
         out = out[..., :d]
     return out.reshape(b, h, sq, d)

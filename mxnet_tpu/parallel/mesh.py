@@ -5,8 +5,12 @@ Reference parity: none — the reference scales via KVStore/ps-lite (SURVEY
 unlocks TP/PP/SP the reference lacks.
 
 Axis convention (scaling-book style): 'dp' (data, across ICI or DCN), 'tp'
-(tensor/model), 'pp' (pipeline stages), 'sp' (sequence/context), 'ep'
-(experts). Helpers build meshes over any subset.
+(tensor/model), 'pp' (pipeline stages), 'sp' (sequence/context): these four
+are ``MeshConfig.AXES``, and ``make_mesh`` builds a mesh over any subset.
+There is no expert axis: ``make_mesh`` accepts any axis name, so a raw mesh
+can carry an 'ep' for ``nn.moe.moe_expert_specs``, but ``MeshConfig`` and
+``ShardedTrainStep`` know none, and ``nn.RoutedExperts`` is one share of an
+expert layer with no exchange between shares.
 """
 from __future__ import annotations
 

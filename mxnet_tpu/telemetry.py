@@ -231,6 +231,10 @@ declare_metric("kernel.flash_tiles_total", "counter",
                "(q-block, k-block) tiles of the flash attention kernels "
                "per traced call, times batch*heads, by kernel "
                "(fwd|bwd_dkv|bwd_dq) and kind (computed|masked|skipped)")
+declare_metric("moe.rows_bound_total", "counter",
+               "static bound on the rows a RoutedExperts layer hands its "
+               "held experts, once per traced call (what the data really "
+               "sent is the layer's expert_load / rows_over aux state)")
 
 
 # -- switches ---------------------------------------------------------------

@@ -97,7 +97,7 @@ def test_flash_kernel_is_shard_mapped_under_a_mesh(monkeypatch):
     from mxnet_tpu.parallel.mesh import activation_sharding
     seen = []
 
-    def per_device(q, k, v, causal=False):
+    def per_device(q, k, v, causal=False, window=None):
         seen.append(q.shape)
         b, h, s, d = q.shape
 

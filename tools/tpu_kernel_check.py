@@ -8,7 +8,8 @@ shapes the model zoo uses and the static blocks the device gets
 (``autotune.kernels._STATIC_DEFAULTS``), and checks numerics against a
 plain-jnp reference:
 
-    flash_attention        fwd + bwd, bf16 and fp32, seq 512..8192
+    flash_attention        fwd + bwd, bf16 and fp32, seq 512..8192, with
+                           grouped KV heads and a causal window
     ln_residual            fwd + bwd, bf16 and fp32
     quantized_matmul       int8 x int8 -> int32
     fp8_matmul             e4m3 and e5m2
@@ -51,20 +52,31 @@ def _relerr(grads, refs):
     return max(rel)
 
 
-def _flash_case(B, H, S, D, dtype, causal, bwd=True):
+def _flash_case(B, H, S, D, dtype, causal, bwd=True, kv_heads=None,
+                window=None):
+    KV = H if kv_heads is None else kv_heads
+
     def check():
-        from mxnet_tpu.ops.pallas.flash_attention import flash_attention
+        from mxnet_tpu.ops.pallas import flash_attention as fa
         ks = jax.random.split(jax.random.PRNGKey(0), 3)
-        q, k, v = (jax.random.normal(kk, (B, H, S, D), dtype) for kk in ks)
+        q = jax.random.normal(ks[0], (B, H, S, D), dtype)
+        k, v = (jax.random.normal(kk, (B, KV, S, D), dtype) for kk in ks[1:])
+
+        def flash_attention(q, k, v, causal):
+            return fa.flash_attention(q, k, v, causal=causal, window=window)
 
         def ref(q, k, v):
-            s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
-                           k.astype(jnp.float32), precision=_HI) / np.sqrt(D)
+            k, v = (jnp.repeat(t.astype(jnp.float32), H // KV, axis=1)
+                    for t in (k, v))
+            s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32), k,
+                           precision=_HI) / np.sqrt(D)
             if causal:
-                s = jnp.where(jnp.tril(jnp.ones((S, S), bool)), s, -1e30)
+                band = jnp.tril(jnp.ones((S, S), bool))
+                if window is not None:
+                    band &= ~jnp.tril(jnp.ones((S, S), bool), -window)
+                s = jnp.where(band, s, -1e30)
             p = jax.nn.softmax(s, axis=-1)
-            return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32),
-                              precision=_HI)
+            return jnp.einsum("bhqk,bhkd->bhqd", p, v, precision=_HI)
 
         out = jax.jit(lambda q, k, v: flash_attention(
             q, k, v, causal=causal))(q, k, v)
@@ -83,7 +95,10 @@ def _flash_case(B, H, S, D, dtype, causal, bwd=True):
             assert res["bwd_relerr"] < 0.05, res
         return res
     name = (f"flash_attention b{B}h{H}s{S}d{D} {jnp.dtype(dtype).name} "
-            f"{'causal' if causal else 'full'}{'' if bwd else ' fwd-only'}")
+            f"{'causal' if causal else 'full'}"
+            f"{'' if KV == H else f' kv{KV}'}"
+            f"{'' if window is None else f' window{window}'}"
+            f"{'' if bwd else ' fwd-only'}")
     return name, check
 
 
@@ -214,6 +229,16 @@ def cases():
                                bwd=False))
         # long context: the forward holds whole K and V per grid cell
         out.append(_flash_case(1, 2, 8192, 64, dtype, causal=True))
+        # grouped KV heads (8 query heads a KV head, 128-wide: the afmoe
+        # zoo family's shape) with and without its 2048 window
+        out.append(_flash_case(2, 8, 2048, 128, dtype, causal=True,
+                               kv_heads=2))
+        out.append(_flash_case(1, 16, 2048, 128, dtype, causal=True,
+                               kv_heads=4, window=512))
+        out.append(_flash_case(1, 8, 4096, 128, dtype, causal=True,
+                               kv_heads=1, window=2048))
+        out.append(_flash_case(1, 8, 8192, 128, dtype, causal=True,
+                               kv_heads=1, window=2048, bwd=False))
     for dtype in (bf16, f32):
         out.append(_ln_case(32 * 128, 768, dtype))       # BERT-base bs32
     out.append(_int8_case(1024, 3072, 768, "gelu"))      # GPT-2 FFN up
