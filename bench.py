@@ -304,7 +304,7 @@ def bench_resnet50_infer(precision: str, on_cpu: bool, peak, k_steps=16,
     row["steps_per_call"] = k_steps
     row["config"] = _config_dict(bs, k_steps)
     # every inference row names its peak basis so cross-precision MFU
-    # comparisons in BENCH_rN are self-describing
+    # comparisons of the grid are self-describing
     if int8:
         row["peak_basis"] = f"int8 ({int8_factor:g}x bf16)"
         from mxnet_tpu import config as _cfg
@@ -451,187 +451,6 @@ def bench_gpt_train(precision: str, on_cpu: bool, peak, bs=8, seq=1024,
     row["params_m"] = round(n_params / 1e6, 1)
     # read off the executable that was timed, not inferred from seq
     row["flash_attention"] = "tpu_custom_call" in step.as_text()
-    return row
-
-
-def bench_gpt_train_mesh(precision, on_cpu, peak, mesh=None, zero=0,
-                         k_iters=5):
-    """Composed-parallelism GPT training rows (`MeshConfig` tentpole):
-    the same model trained dp-only vs dp x tp vs dp x tp x pp, through
-    the full `ShardedTrainStep` (grads, ZeRO state partitioning,
-    optimizer update in one jitted program).  Each row reports the
-    per-axis collective bytes the layout moved (the zero.* / mesh.*
-    telemetry counters, per step) so the grid reads as throughput vs
-    communication trade-offs.  Rows whose mesh exceeds the device count
-    report "skipped" — run under
-    XLA_FLAGS=--xla_force_host_platform_device_count=8 on CPU."""
-    import time as _t
-
-    import jax
-    import jax.numpy as jnp
-    import numpy as onp
-
-    import mxnet_tpu as mx
-    from mxnet_tpu import telemetry
-    from mxnet_tpu.gluon.model_zoo.gpt import GPTForCausalLM
-    from mxnet_tpu.parallel import MeshConfig, ShardedTrainStep
-
-    cfg = MeshConfig(**(mesh or {"dp": 1}))
-    tag = "x".join(f"{a}{s}" for a, s in cfg.shape.items() if s > 1) \
-        or "single"
-    name = f"gpt2_train_mesh_{tag}" + (f"_zero{zero}" if zero else "")
-    if cfg.size() > len(jax.devices()):
-        return {"name": name, "precision": precision,
-                "skipped": f"needs {cfg.size()} devices, "
-                           f"have {len(jax.devices())}"}
-
-    if on_cpu:
-        vocab, units, layers, heads, seq, bs = 1000, 64, 2, 4, 32, 8
-        k_iters = 3
-    else:  # GPT-2 small
-        vocab, units, layers, heads, seq, bs = 50257, 768, 12, 12, 1024, 8
-
-    mx.random.seed(0)
-    net = GPTForCausalLM(vocab_size=vocab, units=units,
-                         hidden_size=units * 4, num_layers=layers,
-                         num_heads=heads, max_length=seq,
-                         dropout=0.0, embed_dropout=0.0)
-    net.initialize()
-    net(mx.np.zeros((2, seq), dtype="int32"))
-    n_params = sum(int(v.data().size)
-                   for v in net.collect_params().values())
-
-    def loss_fn(logits, labels):
-        from mxnet_tpu.ops.xent import sparse_softmax_xent
-        return jnp.mean(sparse_softmax_xent(logits, labels))
-
-    train = ShardedTrainStep(
-        net, loss_fn, mx.optimizer.create("adam", learning_rate=1e-3),
-        cfg, batch_specs=cfg.batch_specs(2, 2), n_labels=1, zero=zero)
-    rs = onp.random.RandomState(0)
-    x = rs.randint(0, vocab, (bs, seq)).astype("int32")
-    y = rs.randint(0, vocab, (bs, seq)).astype("int32")
-    float(train(x, y).asnumpy())  # compile outside the timed window
-
-    telemetry.enable()
-    telemetry.reset()
-    t0 = _t.perf_counter()
-    for _ in range(k_iters):
-        loss = train(x, y)
-    float(loss.asnumpy())  # one host sync closes the chain
-    sec = (_t.perf_counter() - t0) / k_iters
-    bytes_per_step = {
-        k: int(v / k_iters)
-        for prefix in ("zero.", "mesh.")
-        for k, v in telemetry.counters(prefix=prefix, aggregate=True).items()}
-    telemetry.disable()
-
-    flops = 6.0 * n_params * bs * seq
-    row = _row(name, sec, bs, flops, precision, peak)
-    row["mesh"] = cfg.shape
-    row["config"] = _config_dict(bs, 1, zero=zero)
-    row["collective_bytes_per_step"] = bytes_per_step
-    return row
-
-
-def bench_gpt_train_fp8(precision, on_cpu, peak, bs=8, seq=1024, k_iters=5):
-    """fp8 training grid rows (`precision="fp8"` tentpole): gpt2-124m
-    class through the full ShardedTrainStep with e4m3/e5m2 delayed-
-    scaling matmuls AND int8 error-feedback gradient compression on the
-    dp all-reduce.  Each row reports MFU, the loss-parity delta vs an
-    identically-seeded higher-precision reference step (bf16-class on
-    hardware; the fp32 path on CPU, where bf16 compute is emulated
-    anyway), and the per-axis collective bytes/step — the dp sample
-    counts wire bytes at the int8 width, so the >=2x cut reads straight
-    off the row."""
-    import time as _t
-
-    import jax
-    import jax.numpy as jnp
-    import numpy as onp
-
-    import mxnet_tpu as mx
-    from mxnet_tpu import telemetry
-    from mxnet_tpu.gluon.model_zoo.gpt import GPTForCausalLM
-    from mxnet_tpu.parallel import MeshConfig, ShardedTrainStep
-
-    name = f"gpt2_train_bs{bs}_seq{seq}_fp8"
-    n_dev = len(jax.devices())
-    dp = min(4, n_dev)
-    if dp < 2:
-        return {"name": name, "precision": precision,
-                "skipped": f"needs >=2 devices for the dp mesh, have "
-                           f"{n_dev}"}
-    cfg = MeshConfig(dp=dp)
-
-    if on_cpu:
-        vocab, units, layers, heads = 1000, 64, 2, 4
-        seq, bs, k_iters = 32, 8, 3
-    else:  # GPT-2 small
-        vocab, units, layers, heads = 50257, 768, 12, 12
-
-    def build(precision_arg, compress):
-        mx.random.seed(0)
-        net = GPTForCausalLM(vocab_size=vocab, units=units,
-                             hidden_size=units * 4, num_layers=layers,
-                             num_heads=heads, max_length=seq,
-                             dropout=0.0, embed_dropout=0.0)
-        net.initialize()
-        net(mx.np.zeros((2, seq), dtype="int32"))
-        return net, ShardedTrainStep(
-            net, loss_fn, mx.optimizer.create("adam", learning_rate=1e-3),
-            cfg, batch_specs=cfg.batch_specs(2, 2), n_labels=1,
-            precision=precision_arg, grad_compress=compress)
-
-    def loss_fn(logits, labels):
-        from mxnet_tpu.ops.xent import sparse_softmax_xent
-        return jnp.mean(sparse_softmax_xent(logits, labels))
-
-    net8, train8 = build("fp8", "int8")
-    netref, trainref = build("fp32", "none")
-    n_params = sum(int(v.size) for v in train8.trainable.values())
-
-    rs = onp.random.RandomState(0)
-    x = rs.randint(0, vocab, (bs, seq)).astype("int32")
-    y = rs.randint(0, vocab, (bs, seq)).astype("int32")
-    # parity window: both steps walk the same batch from the same init;
-    # the delta after the window is the loss-curve gap fp8 introduces
-    l8 = lref = None
-    for _ in range(4):
-        l8 = train8(x, y)
-        lref = trainref(x, y)
-    l8, lref = float(l8.asnumpy()), float(lref.asnumpy())
-    parity_delta = abs(l8 - lref) / max(abs(lref), 1e-8)
-
-    telemetry.enable()
-    telemetry.reset()
-    t0 = _t.perf_counter()
-    for _ in range(k_iters):
-        loss = train8(x, y)
-    float(loss.asnumpy())  # one host sync closes the chain
-    sec = (_t.perf_counter() - t0) / k_iters
-    # aggregate=False keeps the {axis="dp"} labels — the per-axis
-    # breakdown IS the row's point
-    bytes_per_step = {
-        k: int(v / k_iters)
-        for prefix in ("zero.", "mesh.", "comm.")
-        for k, v in telemetry.counters(prefix=prefix).items()}
-    telemetry.disable()
-
-    flops = 6.0 * n_params * bs * seq
-    row = _row(name, sec, bs, flops, "fp8", peak)
-    row["mesh"] = cfg.shape
-    row["params_m"] = round(n_params / 1e6, 1)
-    row["loss_parity_delta"] = round(parity_delta, 5)
-    row["loss_fp8"] = round(l8, 5)
-    row["loss_ref"] = round(lref, 5)
-    row["grad_compress"] = "int8"
-    row["collective_bytes_per_step"] = bytes_per_step
-    dp_wire = bytes_per_step.get(
-        'mesh.collective_bytes_total{axis="dp"}', 0)
-    dp_full = bytes_per_step.get("mesh.dp_gradient_bytes_total", 0)
-    if dp_wire:
-        row["dp_bytes_cut"] = round(dp_full / dp_wire, 2)
     return row
 
 
@@ -869,15 +688,6 @@ _GRID = [
     (bench_bert_train, dict(precision="bf16", bs=64)),
     (bench_gpt_train, dict(precision="bf16", bs=8, seq=1024)),
     (bench_gpt_train, dict(precision="bf16", bs=4, seq=2048)),
-    (bench_gpt_train_fp8, dict(precision="fp8", bs=8, seq=1024)),
-    (bench_gpt_train_fp8, dict(precision="fp8", bs=4, seq=2048)),
-    (bench_gpt_train_mesh, dict(precision="fp32", mesh={"dp": 8},
-                                zero=1)),
-    (bench_gpt_train_mesh, dict(precision="fp32",
-                                mesh={"dp": 4, "tp": 2}, zero=1)),
-    (bench_gpt_train_mesh, dict(precision="fp32",
-                                mesh={"dp": 2, "tp": 2, "pp": 2},
-                                zero=1)),
     (bench_gpt_decode_serve, dict(precision="fp32")),
     (bench_gpt_decode_serve, dict(precision="fp32", mode="prefix")),
     (bench_gpt_decode_serve, dict(precision="fp32", mode="spec")),
@@ -929,8 +739,7 @@ def main(argv=None):
                 # the requested CPU smoke shrinks every CNN row to one
                 # tiny config — the batch-size rows would be duplicates
                 continue
-            if tuned is None and on_cpu \
-                    and fn in (bench_gpt_train, bench_gpt_train_fp8) \
+            if tuned is None and on_cpu and fn is bench_gpt_train \
                     and kwargs.get("seq") != 1024:
                 continue  # same dedup for the shrunken GPT rows
             if _goodput._active:

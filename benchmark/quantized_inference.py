@@ -19,7 +19,8 @@ CPU CI gates (always run):
 Hardware gates (TPU attached; skipped with a notice on CPU):
 
 - int8 resnet50 inference beats bf16 (items/s — the fused path's reason
-  to exist; BENCH_r05 measured the unfused chain *losing* to bf16).
+  to exist: the unfused chain pays an HBM round-trip for the int8
+  activations between its ops).
 - gpt2-class decode with ``int4_weights`` >= --min-decode-speedup
   (default 1.3x) tokens/s over fp32 with greedy parity on the workload.
 

@@ -136,7 +136,6 @@ def main(argv=None):
                 "losses": losses,
             }
     mem = telemetry.record_memory()
-    counters = telemetry.counters(prefix="zero.", aggregate=True)
     telemetry.disable()
 
     repl = results[0]["state_bytes_per_device"]
@@ -152,7 +151,6 @@ def main(argv=None):
         "zero1_state_bytes_per_device": shard,
         "reduction": reduction,
         "required_reduction": args.reduction,
-        "zero_collective_bytes": counters,
         "memory_stats": mem or None,
     }
     if results_tp:
@@ -178,7 +176,6 @@ def main(argv=None):
             print(f"dp={args.dp} tp={tp} (ZeRO x TP)  state bytes/device: "
                   f"zero=0 {repl_tp:,}  zero=1 {shard_tp:,}  "
                   f"(-{reduction_tp:.1%}, bar {args.reduction:.0%})")
-        print(f"zero collective bytes: {counters}")
         print("memory.* (PJRT): "
               + (json.dumps(mem) if mem else "n/a on this backend"))
 

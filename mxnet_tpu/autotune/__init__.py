@@ -12,7 +12,7 @@ next run starts tuned with zero trials.
 Three surfaces::
 
     # training-step API
-    tuned_step, result = step.autotune(loader)
+    tuned_step, result = mx.autotune.tune_step(step, loader)
 
     # estimator API
     est.fit(train_data, epochs=2, autotune=True)
@@ -52,14 +52,15 @@ from .persist import (cache_dir, kernel_key, load_trials, load_winner,
                       winners_path)
 from .search import (SearchResult, TrialOOM, TrialParity, TrialResult,
                      last_summary, search, trial_compile_scope,
-                     tune_estimator)
+                     tune_estimator, tune_step)
 from .space import Candidate, SearchSpace
 
 __all__ = [
     "Candidate", "SearchSpace", "CostModel", "ModelStats",
     "REMAT_MEM_FRACTION", "REMAT_FLOPS_FACTOR",
     "SearchResult", "TrialResult", "TrialOOM", "TrialParity",
-    "search", "tune_estimator", "trial_compile_scope", "last_summary",
+    "search", "tune_estimator", "tune_step", "trial_compile_scope",
+    "last_summary",
     "cache_dir", "winners_path", "model_fingerprint", "winner_key",
     "load_winner", "save_winner",
     "KERNELS", "KernelSearchResult", "Retuner", "kernel_candidates",

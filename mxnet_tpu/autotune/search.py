@@ -489,6 +489,73 @@ def search(block, loss_fn, optimizer, mesh, batch_specs, sample_batch,
     return result
 
 
+def _host_batch(batches, batch=None):
+    """One batch as host numpy: ``batch`` itself, or ONE borrowed from the
+    loader ``batches`` and released via ``pipeline.take``."""
+    if batch is None:
+        from .. import pipeline as _pipeline
+        batch = next(iter(_pipeline.take(batches, 1)), None)
+        if batch is None:
+            raise MXNetError("autotune: batches yielded no batch")
+    return tuple(onp.asarray(getattr(b, "_data", b)) for b in batch)
+
+
+def tune_step(step, batches=None, sample_batch=None, space=None, **kw):
+    """Search the step-config grid around a ``ShardedTrainStep``'s model,
+    loss, optimizer and mesh (:func:`search`) and return
+    ``(tuned_step, result)``.
+
+    ``batches`` lends ONE sample batch (shaped like the step's per-update
+    batch, no lead axes) and is released via ``pipeline.take``; pass
+    ``sample_batch=`` to skip the loader.  Current weights sync to the
+    block first so trials — and the returned tuned step — start from the
+    step's training state.  The tuned step reuses the caller's optimizer
+    (schedule position included); trials only ever run on hermetic
+    clones.  Keyword args flow to :func:`search` (hbm_budget=, force=,
+    ...).
+    """
+    from ..parallel.mesh import MeshConfig
+    from ..parallel.train import ShardedTrainStep
+    if sample_batch is None and batches is None:
+        raise MXNetError(
+            "autotune needs `batches` (a loader to borrow one batch from) "
+            "or an explicit `sample_batch`")
+    sample = _host_batch(batches, sample_batch)
+    step.sync_to_block()
+    result = search(
+        step.block, step.loss_fn, step.fopt.opt, step.mesh,
+        step.batch_specs, sample, n_labels=step.n_labels,
+        param_specs=step.param_specs, dp_axis=step.dp_axis,
+        space=space, **kw)
+    cfg = result.config
+    if cfg is None:  # every trial failed: keep the caller's config
+        return step, result
+    mesh = step.mesh_config or step.mesh
+    batch_specs, param_specs, dp_axis = (
+        step.batch_specs, step.param_specs, step.dp_axis)
+    if cfg.get("mesh"):
+        # a mesh-axis search won on a different layout: rebuild the
+        # step around the winning MeshConfig (specs re-derive)
+        mesh = MeshConfig(**cfg["mesh"])
+        batch_specs = mesh.batch_specs(
+            *[len(s) if s is not None else 2 for s in step.batch_specs])
+        param_specs = None
+        dp_axis = "dp"
+    precision = cfg.get("precision", "fp32")
+    tuned = ShardedTrainStep(
+        step.block, step.loss_fn, step.fopt.opt, mesh,
+        batch_specs, n_labels=step.n_labels,
+        param_specs=param_specs,
+        steps_per_call=cfg["steps_per_call"], zero=cfg["zero"],
+        grad_accum=cfg["grad_accum"], remat=cfg["remat"],
+        dp_axis=dp_axis,
+        precision=precision if precision in ("fp32", "fp8")
+        else step.precision,
+        grad_compress=step._compress)
+    tuned._n_step = step._n_step
+    return tuned, result
+
+
 def tune_estimator(estimator, train_data, space=None, apply=True, **kw):
     """`estimator.fit(autotune=True)` backend: search around the
     estimator's net/loss/optimizer using one batch drawn from the loader
@@ -499,13 +566,9 @@ def tune_estimator(estimator, train_data, space=None, apply=True, **kw):
     can lift the rest (zero/grad_accum/steps_per_call)."""
     import jax
     from jax.sharding import PartitionSpec as P
-    from .. import pipeline as _pipeline
     from ..parallel.mesh import make_mesh
 
-    batch = next(iter(_pipeline.take(train_data, 1)), None)
-    if batch is None:
-        raise MXNetError("autotune: train_data yielded no batch")
-    arrs = tuple(onp.asarray(getattr(b, "_data", b)) for b in batch)
+    arrs = _host_batch(train_data)
     b0 = int(arrs[0].shape[0])
     ndev = len(jax.devices())
     dp = ndev if b0 % ndev == 0 else 1

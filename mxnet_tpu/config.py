@@ -169,8 +169,8 @@ declare("telemetry.step_interval", int, 1, "MXNET_TELEMETRY_STEP_INTERVAL",
 declare("dataloader.worker_mode", str, "auto", "MXNET_DATALOADER_WORKER_MODE",
         "num_workers>0 execution mode: 'threads', 'processes', or 'auto' "
         "(first-batch cost probe picks processes only for GIL-bound "
-        "python transforms — BENCH_r05 shows IPC makes processes 4x "
-        "slower for everything else).")
+        "python transforms — IPC makes processes slower for everything "
+        "else).")
 declare("dataloader.mp_threshold_ms", float, 2.0,
         "MXNET_DATALOADER_MP_THRESHOLD_MS",
         "auto worker mode: per-sample python cost (ms) above which the "
@@ -184,8 +184,8 @@ declare("dataloader.respawn_backoff", float, 0.1,
         "(doubles per retry).")
 declare("dataloader.shm_ring", bool, True, "MXNET_DATALOADER_SHM_RING",
         "Process-worker loaders reuse a pool of SharedMemory segments "
-        "across batches instead of create/unlink per leaf (BENCH_r05: the "
-        "churn made process workers 0.25x thread throughput); off restores "
+        "across batches instead of create/unlink per leaf (the churn made "
+        "process workers slower than threads); off restores "
         "the historical one-shot segments.")
 declare("dataloader.shm_ring_max", int, 32, "MXNET_DATALOADER_SHM_RING_MAX",
         "Max idle SharedMemory segments the reuse pool keeps per loader; "

@@ -171,7 +171,7 @@ class QuantizedDense(HybridBlock):
     Forward is ONE fused op (npx.quantized_dense_fused): activation
     quantize, int8 MXU dot, dequant + bias + activation epilogue — the
     separate quantize_v2/quantized_fully_connected pair this replaced
-    paid an HBM round-trip per layer (BENCH_r05)."""
+    paid an HBM round-trip per layer."""
 
     def __init__(self, dense: nn.Dense, threshold: float):
         super().__init__()

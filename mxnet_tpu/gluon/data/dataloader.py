@@ -86,7 +86,7 @@ def default_mp_batchify_fn(data):
 # (shape, dtype, offset); the main process copies each leaf out into a
 # device array.  One grant/attach/give_back per BATCH instead of per
 # leaf — the per-leaf segment churn (and its per-leaf pool round trips)
-# made process workers 0.25x thread throughput in BENCH_r05.  With the
+# is what made process workers slower than threads.  With the
 # dataloader.shm_ring knob (default on) segments are pooled and reused
 # across batches; otherwise each segment is unlinked after its one batch
 # (the historical protocol).
@@ -404,9 +404,8 @@ class DataLoader:
     def _resolve_worker_mode(self):
         """'threads' or 'processes' for num_workers>0.
 
-        BENCH_r05 Weak #4: the shm transport makes process workers ~4x
-        slower per batch than threads for anything that releases the GIL
-        (numpy decode), while GIL-bound pure-python transforms only scale
+        The shm transport makes process workers slower per batch than
+        threads for anything that releases the GIL (numpy decode), while GIL-bound pure-python transforms only scale
         in processes.  'auto' (the default) probes the cost of one sample
         eagerly and picks processes only above
         mx.config dataloader.mp_threshold_ms; MXNET_DATALOADER_WORKER_MODE

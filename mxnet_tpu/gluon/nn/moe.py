@@ -284,7 +284,7 @@ class RoutedExperts(HybridBlock):
 
 def moe_expert_specs(ep_axis="ep"):
     """PartitionSpecs for MoEDense params: experts sharded over the mesh
-    axis named ``ep_axis`` (the parallel.train.megatron_specs analog).
+    axis named ``ep_axis`` (the parallel.layout.megatron_specs analog).
     ``MeshConfig`` builds no such axis: pass a raw Mesh that has one."""
     from jax.sharding import PartitionSpec as P
     return {

@@ -32,7 +32,6 @@ from __future__ import annotations
 
 import json
 import os
-import re
 import statistics
 import threading
 import time
@@ -725,30 +724,6 @@ def merge_snapshots(lease_dir=None):
         for ev in (ins.get("drift_events") or []):
             merged["drift_events"].append({**ev, "host": rank})
     merged["drift_events"].sort(key=lambda e: e.get("time", 0.0))
-    # per-axis collective traffic rollup: parse the labeled
-    # mesh.collective_bytes_total{axis="dp"} / zero.collective_bytes_total
-    # {op=...} samples out of the summed counters so the fleet view
-    # answers "how many bytes moved per mesh axis" (and makes the
-    # compression cut directly observable: the dp sample counts wire
-    # bytes at the compressed width vs mesh.dp_gradient_bytes_total's
-    # uncompressed payload)
-    coll = {"by_axis": {}, "zero_by_op": {}}
-    for k, v in merged["counters"].items():
-        m = re.match(r'mesh\.collective_bytes_total\{axis="([^"]+)"\}$', k)
-        if m:
-            ax = m.group(1)
-            coll["by_axis"][ax] = coll["by_axis"].get(ax, 0) + v
-            continue
-        m = re.match(r'zero\.collective_bytes_total\{op="([^"]+)"\}$', k)
-        if m:
-            op = m.group(1)
-            coll["zero_by_op"][op] = coll["zero_by_op"].get(op, 0) + v
-    comp = merged["counters"].get("comm.compressed_bytes_total", 0)
-    uncomp = merged["counters"].get("comm.uncompressed_bytes_total", 0)
-    if uncomp and comp:
-        coll["compression_ratio"] = round(uncomp / comp, 3)
-    if coll["by_axis"] or coll["zero_by_op"]:
-        merged["collectives"] = coll
     return merged
 
 

@@ -3,10 +3,10 @@
 Reference parity: the role of src/operator/quantization/'s cuDNN int8
 kernels (quantized_fully_connected.cc, quantized_conv.cc) — the hand-
 written path the reference keeps because compiler fusion alone does not
-reach the int8 peak. BENCH_r05 showed the same thing here: the composed
-quantize_v2 → dot_general(int32) → dequantize chain loses to bf16
-(12,012 vs 12,790 img/s) because XLA materializes the int8 activations
-and the fp32 epilogue in HBM between ops. This kernel streams one
+reach the int8 peak. The same holds here: in the composed
+quantize_v2 → dot_general(int32) → dequantize chain XLA materializes the
+int8 activations and the fp32 epilogue in HBM between ops, which spends
+the bandwidth the int8 matmul saved. This kernel streams one
 (block_m, K) activation tile through VMEM ONCE: quantize in registers,
 int8×int8 dot on the MXU with int32 accumulation, dequant + bias +
 activation in the epilogue, write the finished fp tile.

@@ -175,8 +175,7 @@ def quantized_dense_fused(data, weight, x_scale, w_scale, bias=None,
 
     One traced op end to end: the separate quantize_v2 /
     quantized_fully_connected pair costs an HBM round-trip for the int8
-    activations between the two ops (BENCH_r05: int8 resnet50 *slower*
-    than bf16).  Routing per ``quantize.fused_matmul``: the Pallas kernel
+    activations between the two ops.  Routing per ``quantize.fused_matmul``: the Pallas kernel
     (ops/pallas/quant_matmul.py) on TPU / when forced 'on' (interpret
     mode off-TPU), else the same ``lax.dot_general(preferred=int32)``
     expression as :func:`quantized_fully_connected` inside one jit so XLA

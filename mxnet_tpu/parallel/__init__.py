@@ -18,5 +18,6 @@ from .ring_attention import ring_attention  # noqa: F401
 from . import tp  # noqa: F401
 from . import pp  # noqa: F401
 from .pp import gpipe, stack_stage_params, shard_stages  # noqa: F401
-from .train import ShardedTrainStep, megatron_specs, scan_steps  # noqa: F401
+from .layout import StateLayout, megatron_specs  # noqa: F401
+from .train import ShardedTrainStep, scan_steps  # noqa: F401
 from .scaling import weak_scaling_table  # noqa: F401

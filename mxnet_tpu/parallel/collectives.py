@@ -12,9 +12,8 @@ keeps its reduce-scatter/all-gather INSIDE the jitted step (an in_spec
 ``P(dp)`` on logically-reduced grads is the reduce-scatter under GSPMD;
 ``jax.lax.all_gather(..., tiled=True)`` with ``check_vma=False``
 re-assembles params, exactly as :func:`allgather` below) so XLA can
-overlap them with compute; traffic is counted by the
-``zero.reduce_scatter_bytes_total`` / ``zero.all_gather_bytes_total``
-telemetry counters.
+overlap them with compute; what they move is in a profiler trace's
+``all-reduce`` / ``all-gather`` rows.
 """
 from __future__ import annotations
 
@@ -57,15 +56,33 @@ def reduce_scatter(x, mesh, axis="dp"):
     return _rs(x)
 
 
+def quantized_mean(c, axis, n, mode):
+    """Inside a shard_map over ``axis`` (``n`` ranks): quantize this rank's
+    f32 ``c`` against a SHARED scale (pmax of the absmax over ranks, so
+    dequantization after the reduce is exact w.r.t. what was sent), psum,
+    and return ``(mean, sent)`` — ``sent`` is what this rank's ``c``
+    became, so ``c - sent`` is its error-feedback residual."""
+    if mode == "int8":
+        s = jax.lax.pmax(jnp.max(jnp.abs(c)), axis) / 127.0
+        s = jnp.where(s > 0.0, s, jnp.float32(1.0))
+        q = jnp.clip(jnp.round(c / s), -127.0, 127.0)
+        # the payload is int8-VALUED but reduced as f32 operands: the f32
+        # psum of integer values is exact below 2^24, so dequant-after-
+        # reduce equals the mean of per-rank dequants bitwise
+        sent = q * s
+        return jax.lax.psum(q, axis) * s / n, sent
+    # bf16: value-snap through bf16, reduce in f32
+    sent = c.astype(jnp.bfloat16).astype(jnp.float32)
+    return jax.lax.psum(sent, axis) / n, sent
+
+
 def compressed_allreduce(x, mesh, axis="dp", mode="int8", residual=None):
     """Error-feedback compressed mean-allreduce of per-rank values.
 
     ``x`` is a per-rank stack (leading dim = mesh axis size, as in
     :func:`allreduce`): each rank's contribution plus its carried
-    ``residual`` quantizes against a SHARED scale (pmax of the absmax
-    over ranks, so dequantization after the reduce is exact w.r.t. what
-    was sent) and psums at the wire width — int8 payload (4x narrower
-    than fp32) or bf16 (2x).  Returns ``(mean, new_residual)`` where
+    ``residual`` goes through :func:`quantized_mean` (int8- or
+    bf16-valued payload, reduced as f32 operands).  Returns ``(mean, new_residual)`` where
     ``new_residual`` (same per-rank stack layout) carries the
     quantization error into the next call — EF-SGD: the error
     telescopes across steps instead of biasing the trajectory.
@@ -85,15 +102,7 @@ def compressed_allreduce(x, mesh, axis="dp", mode="int8", residual=None):
                        out_specs=(P(), P(axis)), check_vma=False)
     def _car(v, res):
         c = v[0].astype(jnp.float32) + res[0]
-        if mode == "int8":
-            s = jax.lax.pmax(jnp.max(jnp.abs(c)), axis) / 127.0
-            s = jnp.where(s > 0.0, s, jnp.float32(1.0))
-            q = jnp.clip(jnp.round(c / s), -127.0, 127.0)
-            sent = q * s
-            red = jax.lax.psum(q, axis) * s / n
-        else:
-            sent = c.astype(jnp.bfloat16).astype(jnp.float32)
-            red = jax.lax.psum(sent, axis) / n
+        red, sent = quantized_mean(c, axis, n, mode)
         return red, (c - sent)[None]
 
     return _car(x, residual)

@@ -398,8 +398,8 @@ def test_step_autotune_returns_tuned_step_that_trains():
     first = float(step(x, y))
     space = SearchSpace(batch_size=16, steps_per_call=(1, 2),
                         grad_accum=(1,), zero=(0,), remat=(False,))
-    tuned, res = step.autotune(sample_batch=(x, y), space=space,
-                               trial_seconds=0.03, force=True)
+    tuned, res = autotune.tune_step(step, sample_batch=(x, y), space=space,
+                                    trial_seconds=0.03, force=True)
     assert res.best is not None
     cfg = res.config
     assert tuned.steps_per_call == cfg["steps_per_call"]

@@ -78,7 +78,7 @@ def test_mp_loader_shm_cleanup():
 
 def test_mp_loader_shm_ring_reuse():
     """Epoch 2+ serves most batches from pooled segments: bounded creates,
-    growing reuse counter (BENCH_r05 proc-vs-thread gap driver).  All
+    growing reuse counter (segment churn is what slows process workers).  All
     leaves of a batch ride ONE packed segment, so the counters tick once
     per batch, not once per leaf."""
     from mxnet_tpu import telemetry

@@ -161,7 +161,7 @@ def test_worker_hang_caught_by_heartbeat_deadline(monkeypatch):
 
 def test_worker_mode_auto_and_override(monkeypatch):
     ds = _SynthDataset(32)
-    # cheap samples -> threads (BENCH_r05: shm transport ~4x slower)
+    # cheap samples -> threads (the shm transport costs more than it buys)
     assert DataLoader(ds, batch_size=8,
                       num_workers=2)._resolve_worker_mode() == "threads"
     # a zero threshold makes any sample "expensive" -> processes

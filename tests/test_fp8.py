@@ -4,7 +4,8 @@
 Oracles: the fp8 step against the fp32 reference on the same seed and
 batches (loss-curve parity, not bitwise — the format genuinely rounds),
 the EF-compressed dp reduction against the uncompressed step (error
-feedback telescopes, wire bytes provably cut), checkpoint round-trips
+feedback telescopes; one collective a bucket in the lowered program),
+checkpoint round-trips
 bitwise through an elastic dp resize, and the serve/autotune guards
 that keep fp8 from shipping where it is unproven.
 
@@ -12,10 +13,6 @@ Note: seed BEFORE ``initialize()`` — Dense with ``in_units`` known
 materializes weights immediately, so a seed set after construction
 never reaches the initializer.
 """
-import json
-import os
-import time
-
 import numpy as onp
 import pytest
 
@@ -150,7 +147,7 @@ def test_fp8_step_tracks_fp32_loss_curve():
     ref = _step("fp32")
     mx.random.seed(3)
     s8 = _step("fp8")
-    assert s8._fp8_sites, "Dense weight must be an eligible fp8 site"
+    assert s8.layout.fp8_sites, "Dense weight must be an eligible fp8 site"
     for _ in range(4):
         l0 = float(ref(x, y).asnumpy())
         l8 = float(s8(x, y).asnumpy())
@@ -161,7 +158,7 @@ def test_fp8_step_tracks_fp32_loss_curve():
 def test_fp8_amax_history_rolls_per_update():
     s8 = _step("fp8")
     x, y = _data()
-    site = s8._fp8_sites[0]
+    site = s8.layout.fp8_sites[0]
     h0 = {k: onp.asarray(v) for k, v in s8.extra["fp8"][site].items()}
     assert all((v == 0).all() for v in h0.values())
     s8(x, y)
@@ -181,7 +178,7 @@ def test_fp8_with_grad_accum_and_steps_per_call():
     s8(x.reshape(2, 2, 8, IN_UNITS), y.reshape(2, 2, 8))
     assert s8._n_step == 2
     assert opt.num_update == 2
-    site = s8._fp8_sites[0]
+    site = s8.layout.fp8_sites[0]
     h = onp.asarray(s8.extra["fp8"][site]["x"])
     assert h[0] > 0 and h[1] > 0 and (h[2:] == 0).all()
 
@@ -204,23 +201,35 @@ def test_compressed_step_tracks_uncompressed(mode):
         assert abs(lc - l0) / max(abs(l0), 1e-8) < 0.05, (mode, lc, l0)
 
 
-def test_int8_compression_cuts_dp_wire_bytes():
-    telemetry.enable()
+@pytest.mark.parametrize("bucket_mb,n_buckets", [(25.0, 1), (0.001, 2)])
+def test_compressed_step_lowers_one_dp_reduce_per_bucket(bucket_mb,
+                                                         n_buckets):
+    """What crosses the dp axis is in the lowered program, not in an
+    estimate: every bucket's quantized payload is ONE all-reduce over the
+    four dp ranks (the independent collectives XLA overlaps with the
+    backward), fed by the clip to +-127.  The payload is int8-VALUED but
+    its operands are f32 — the sum of four ranks does not fit 8 bits — so
+    the program never had an 8-bit wire (PERF.md section 6, PR 30)."""
+    import re
+    prev = mxconfig.get("comm.bucket_mb")
+    mxconfig.set("comm.bucket_mb", bucket_mb)
     try:
-        telemetry.reset()
         comp = _step("fp32", "int8")
-        x, y = _data()
-        for _ in range(2):
-            comp(x, y)
-        c = telemetry.counters()   # aggregate=False keeps {axis="dp"}
-        wire = c.get('mesh.collective_bytes_total{axis="dp"}', 0)
-        full = c.get("mesh.dp_gradient_bytes_total", 0)
-        assert full > 0 and wire > 0
-        assert full / wire >= 2.0, (wire, full)
-        assert c.get("comm.compressed_bytes_total", 0) == wire
-        assert c.get("comm.uncompressed_bytes_total", 0) == full
     finally:
-        telemetry.disable()
+        mxconfig.set("comm.bucket_mb", prev)
+    sizes = [sum(s for _, _, s in b) for b in comp.layout.buckets]
+    assert len(sizes) == n_buckets and sum(sizes) == UNITS * IN_UNITS + UNITS
+    text = comp.lower(*_data()).as_text()
+    reduces = re.findall(
+        r'"stablehlo\.all_reduce"\((%\w+)\).*?replica_groups = dense<'
+        r'(\[\[[^>]*\]\])>.*?\}\) : \(tensor<([^>]*)>\)', text, re.S)
+    assert all(groups == "[[0, 1, 2, 3]]" for _, groups, _ in reduces)
+    payloads = [(v, t) for v, _, t in reduces if "x" in t]
+    assert [t for _, t in payloads] == [f"{n}xf32" for n in sizes]
+    for v, _ in payloads:
+        assert re.search(rf"{v} = func\.call @clip\w*\(", text), v
+    plain = _step("fp32", "none").lower(*_data()).as_text()
+    assert "stablehlo.all_reduce" not in plain   # GSPMD's own, after lowering
 
 
 def test_error_feedback_residual_carries_quantization_error():
@@ -429,43 +438,3 @@ def test_autotune_parity_gate_rejects_and_admits_fp8():
         assert by_prec["fp8"].items_per_s > 0
     finally:
         mxconfig.set("autotune.fp8_parity_tol", prev)
-
-
-# ---------------------------------------------------------------------------
-# telemetry exposition + insight fleet rollup of the new counters
-# ---------------------------------------------------------------------------
-
-def test_per_axis_collective_counters_exposed():
-    telemetry.enable()
-    try:
-        telemetry.reset()
-        s = _step("fp32", "int8")
-        x, y = _data()
-        s(x, y)
-        c = telemetry.counters()
-        assert 'mesh.collective_bytes_total{axis="dp"}' in c
-        text = telemetry.exposition()
-        assert 'mesh_collective_bytes_total{axis="dp"}' in text
-    finally:
-        telemetry.disable()
-
-
-def test_insight_fleet_view_rolls_up_collective_traffic(tmp_path):
-    from mxnet_tpu import insight
-    d = str(tmp_path)
-    for rank, dp, tp in ((0, 1000, 40), (1, 3000, 60)):
-        payload = {"rank": rank, "time": time.time(), "counters": {
-            'mesh.collective_bytes_total{axis="dp"}': dp,
-            'mesh.collective_bytes_total{axis="tp"}': tp,
-            'zero.collective_bytes_total{op="all_gather"}': 7,
-            "comm.compressed_bytes_total": dp,
-            "comm.uncompressed_bytes_total": 4 * dp,
-        }, "gauges": {}}
-        with open(os.path.join(d, f"insight-{rank}.json"), "w") as f:
-            f.write(json.dumps(payload))
-    m = insight.merge_snapshots(d)
-    coll = m["collectives"]
-    assert coll["by_axis"]["dp"] == 4000
-    assert coll["by_axis"]["tp"] == 100
-    assert coll["zero_by_op"]["all_gather"] == 14
-    assert coll["compression_ratio"] == pytest.approx(4.0)

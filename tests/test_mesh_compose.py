@@ -154,21 +154,6 @@ def test_pipeline_microbatching_via_grad_accum():
     assert step._step._cache_size() == 1
 
 
-def test_collective_byte_counters():
-    x, y = _batch()
-    telemetry.enable()
-    telemetry.reset()
-    try:
-        step = _gpt_step(MeshConfig(dp=2, tp=2, pp=2), x)
-        step(x, y)
-        c = telemetry.counters(prefix="mesh.", aggregate=True)
-        assert c["mesh.dp_gradient_bytes_total"] > 0
-        assert c["mesh.tp_allreduce_bytes_total"] > 0
-        assert c["mesh.pp_stage_transfer_bytes_total"] > 0
-    finally:
-        telemetry.disable()
-
-
 # ---------------------------------------------------------------------------
 # ZeRO x TP: tensor-sharded params' state partitions over dp
 # ---------------------------------------------------------------------------

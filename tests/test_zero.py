@@ -271,24 +271,6 @@ def test_trainstate_bundles_sharded_step(tmp_path):
 # telemetry planes
 # ---------------------------------------------------------------------------
 
-def test_zero_collective_byte_counters():
-    from mxnet_tpu import telemetry
-    telemetry.enable()
-    try:
-        telemetry.reset()
-        step = _step(zero=2, grad_accum=2)
-        x, y = _data(n=16)
-        step(x.reshape(2, 8, 8), y.reshape(2, 8))
-        agg = telemetry.counters(aggregate=True)
-        ag = agg["zero.all_gather_bytes_total"]
-        rs = agg["zero.reduce_scatter_bytes_total"]
-        # dense 8x10: weight 80 pad->80, bias 10 pad->12 => 92 f32 = 368 B
-        assert ag == 368
-        assert rs == 2 * ag  # zero=2: one reduce-scatter per microbatch
-    finally:
-        telemetry.disable()
-
-
 def test_record_memory_gauges():
     """memory.* plane: backends that report PJRT memory_stats populate
     per-device gauges; stat-less backends (CPU) stay an empty no-op."""
