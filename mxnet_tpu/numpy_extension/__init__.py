@@ -620,7 +620,8 @@ def embedding(data, weight, input_dim=None, output_dim=None, dtype="float32",
     """
     idx = data._data if isinstance(data, ndarray) else jnp.asarray(data)
     if not sparse_grad:
-        return _invoke(lambda w: jnp.take(w, idx.astype(jnp.int32), axis=0),
+        from ..ops.lookup import take_rows
+        return _invoke(lambda w: take_rows(w, idx.astype(jnp.int32)),
                        (weight,), name="embedding")
 
     from .. import autograd as _ag

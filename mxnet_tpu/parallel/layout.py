@@ -19,7 +19,9 @@ Per leaf the optimizer state takes one of three forms:
   parameter's shape and spec with ``dp_axis`` inserted into its largest free
   dimension; gradients reduce-scatter onto it, the elementwise update runs
   on the (tp x dp)-sharded chunk and the new weights gather back to the
-  parameter's spec (ZeRO x TP).
+  parameter's spec (ZeRO x TP).  Also a replicated parameter whose flat
+  shards would cut its rows (:func:`columns_spec`; ``replicated_dp`` names
+  these leaves).
 
 crossed with pipeline stacking: under ``pp>1`` each repeated
 ``<prefix>layerN.<suffix>`` family is one ``(L, ...)`` leaf whose leading
@@ -107,6 +109,21 @@ def _insert_dp(spec, shape, dp_axis, dp_n):
     return P(*entries)
 
 
+def columns_spec(shape, dp_axis, dp_n):
+    """The DP-form spec of a REPLICATED leaf, or None where it stays FLAT.
+
+    A replicated leaf's optimizer state is its padded ravel in 1/dp shards
+    -- unless those shards would cut its rows (``shape[0] % dp_n``: GPT-2's
+    50257-row table under ``dp=4`` has 12564.25 rows a shard).  GSPMD can
+    neither reduce-scatter a gradient onto such a shard nor gather a
+    parameter from it: it all-reduces the leaf whole and pads.  Such a leaf
+    shards along its largest dimension that ``dp`` divides instead; where
+    none divides it stays FLAT with its padding."""
+    if not shape or shape[0] % dp_n == 0:
+        return None
+    return _insert_dp(P(), shape, dp_axis, dp_n)
+
+
 class Leaf(NamedTuple):
     """One trainable leaf's plan (a pp family is one leaf)."""
     shape: tuple        # the parameter's shape (a family's: stacked)
@@ -153,6 +170,9 @@ class StateLayout:
         dp_n = int(axis_sizes[dp_axis]) if self.zero else 1
         self.leaves = {n: self._plan_leaf(tuple(s), self.param_spec(n), dp_n)
                        for n, s in trainable.items()}
+        self.replicated_dp = [
+            n for n, l in self.leaves.items() if l.form == DP
+            and all(e is None for e in self.param_spec(n))]
 
         self.fp8_sites = []
         if fp8:
@@ -209,11 +229,14 @@ class StateLayout:
         return shapes
 
     def _plan_leaf(self, shape, spec, dp_n):
-        if self.zero and any(e is not None for e in spec):
-            sspec = _insert_dp(spec, shape, self.dp_axis, dp_n)
-            if sspec is not None:
-                return Leaf(shape, DP, shape, sspec)
-        elif self.zero:
+        if not self.zero:
+            return Leaf(shape, PARAM, shape, spec)
+        replicated = all(e is None for e in spec)
+        sspec = (columns_spec(shape, self.dp_axis, dp_n) if replicated
+                 else _insert_dp(spec, shape, self.dp_axis, dp_n))
+        if sspec is not None:
+            return Leaf(shape, DP, shape, sspec)
+        if replicated:
             padded = -(-math.prod(shape) // dp_n) * dp_n
             return Leaf(shape, FLAT, (padded,), P(self.dp_axis))
         return Leaf(shape, PARAM, shape, spec)
@@ -224,6 +247,23 @@ class StateLayout:
     def names(self, form):
         """The leaves whose optimizer state has ``form``, in step order."""
         return [n for n, l in self.leaves.items() if l.form == form]
+
+    def census(self, itemsize):
+        """Leaves and parameter bytes by form, and of the replicated leaves
+        that took the DP form: the ``train.plan`` span's attributes.
+        ``itemsize`` maps a parameter's own (per-layer) name to its
+        element's bytes."""
+        def nbytes(n):
+            member = self.families.get(n, (n,))[0]
+            return math.prod(self.leaves[n].shape) * itemsize[member]
+
+        out = {}
+        groups = {form: self.names(form) for form in (PARAM, FLAT, DP)}
+        groups["replicated_dp"] = self.replicated_dp
+        for key, names in groups.items():
+            out[f"{key}_leaves"] = len(names)
+            out[f"{key}_bytes"] = sum(map(nbytes, names))
+        return out
 
     # -- parameter form <-> state form ---------------------------------------
     def to_state_form(self, n, x):

@@ -242,10 +242,13 @@ class ShardedTrainStep:
         if self._compress != "none":
             bucket_elems = max(1, int(
                 float(_config.get("comm.bucket_mb")) * (1 << 20) / 4))
-        self.layout = lay = StateLayout(
-            t_shapes, a_shapes, param_specs, dict(mesh.shape),
-            zero=self.zero, dp_axis=dp_axis, fp8=self._fp8,
-            bucket_elems=bucket_elems)
+        with _trace.span("train.plan", category="train") as plan:
+            self.layout = lay = StateLayout(
+                t_shapes, a_shapes, param_specs, dict(mesh.shape),
+                zero=self.zero, dp_axis=dp_axis, fp8=self._fp8,
+                bucket_elems=bucket_elems)
+            plan.set(**lay.census(
+                {n: v.dtype.itemsize for n, v in trainable.items()}))
         self.param_specs = lay.param_specs
         self.fopt = FunctionalOptimizer(optimizer)
         if self.zero and not type(self.fopt.opt)._zero_partitionable:

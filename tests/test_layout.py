@@ -51,7 +51,10 @@ def _expected(zero, dp, tp, pp):
     def flat(size):   # a replicated leaf under zero>0; None at zero 0
         return (FLAT, (_pad(size, dp),), P("dp")) if zero else None
 
-    out["embed.weight"] = flat(80) or (PARAM, (10, 8), P())
+    # 10 rows: a quarter of the ravel would cut them, 8 columns divide
+    out["embed.weight"] = (
+        (DP, (10, 8), P(None, "dp")) if zero and dp == 4
+        else flat(80) or (PARAM, (10, 8), P()))
     out["head.bias"] = flat(10) or (PARAM, (10,), P())
     if pp == 1:
         for i in (0, 1):
@@ -94,6 +97,8 @@ def test_plan_names_form_and_spec_per_leaf(zero, dp, tp, pp):
     for form in (PARAM, FLAT, DP):
         assert lay.names(form) == [n for n in lay.leaves
                                    if want[n][0] == form]
+    assert lay.replicated_dp == (
+        ["embed.weight"] if zero and dp == 4 else [])
     if pp == 2:
         assert lay.families["dec.layer*.ln.gamma"] == (
             "dec.layer0.ln.gamma", "dec.layer1.ln.gamma")
@@ -104,12 +109,45 @@ def test_plan_names_form_and_spec_per_leaf(zero, dp, tp, pp):
         assert lay.families == {}
 
 
+@pytest.mark.parametrize("shape,zero,dp,want", [
+    # GPT-2's tied table: a quarter of its ravel is 12564.25 rows
+    ((50257, 1280), 1, 4, (DP, (50257, 1280), P(None, "dp"))),
+    ((50257, 1280), 2, 4, (DP, (50257, 1280), P(None, "dp"))),
+    ((50257, 1280), 1, 2, (DP, (50257, 1280), P(None, "dp"))),
+    # whole rows to every rank: flat, as before
+    ((1024, 1280), 1, 4, (FLAT, (1310720,), P("dp"))),
+    ((1280,), 1, 4, (FLAT, (1280,), P("dp"))),
+    ((50257, 1280), 1, 1, (FLAT, (64328960,), P("dp"))),
+    # no dimension divides: flat, padded
+    ((7, 5), 1, 4, (FLAT, (36,), P("dp"))),
+    ((7,), 1, 4, (FLAT, (8,), P("dp"))),
+    ((), 1, 4, (FLAT, (4,), P("dp"))),
+    # the largest dimension that divides, not the first
+    ((7, 8, 64), 1, 4, (DP, (7, 8, 64), P(None, None, "dp"))),
+    ((50257, 1280), 0, 4, (PARAM, (50257, 1280), P())),
+])
+def test_plan_of_a_replicated_leaf(shape, zero, dp, want):
+    """A replicated leaf takes the DP form only where 1/dp flat shards would
+    cut its rows and some dimension divides; the plan needs no array."""
+    lay = StateLayout({"w": shape}, {}, {}, {"dp": dp}, zero=zero)
+    leaf = lay.leaves["w"]
+    assert (leaf.form, leaf.state_shape, leaf.state_spec) == want
+    assert lay.replicated_dp == (["w"] if want[0] == DP else [])
+    census = lay.census({"w": 4})
+    size = 4 * int(onp.prod(shape, dtype=onp.int64))
+    assert census[f"{want[0]}_leaves"] == 1
+    assert census[f"{want[0]}_bytes"] == size
+    assert census["replicated_dp_bytes"] == (size if want[0] == DP else 0)
+    assert sum(census[f"{f}_leaves"] for f in (PARAM, FLAT, DP)) == 1
+
+
 def test_flat_padding_is_what_the_old_counter_summed():
-    """Dense 8 -> 10 at dp 4: weight 80 -> 80, bias 10 -> 12 (the sizes
-    tests/test_zero.py's byte-counter test asserted as 368 bytes)."""
+    """Dense 8 -> 10 at dp 4: bias 10 -> 12 flat and padded; the weight's
+    10 rows do not divide and its 8 columns do, so it keeps its shape."""
     lay = StateLayout({"weight": (10, 8), "bias": (10,)}, {}, {},
                       {"dp": 4}, zero=2)
-    assert lay.leaves["weight"].state_shape == (80,)
+    assert lay.leaves["weight"].state_shape == (10, 8)
+    assert lay.leaves["weight"].state_spec == P(None, "dp")
     assert lay.leaves["bias"].state_shape == (12,)
     b = onp.arange(10, dtype="float32")
     flat = lay.to_state_form("bias", b)
@@ -117,7 +155,9 @@ def test_flat_padding_is_what_the_old_counter_summed():
     assert (flat[:10] == b).all() and (flat[10:] == 0).all()
     onp.testing.assert_array_equal(lay.from_state_form("bias", flat), b)
     w = onp.ones((10, 8), "float32")
-    assert lay.to_state_form("weight", w).shape == (80,)
+    assert lay.to_state_form("weight", w) is w
+    assert StateLayout({"weight": (10, 8)}, {}, {}, {"dp": 2}, zero=2
+                       ).to_state_form("weight", w).shape == (80,)
 
 
 @pytest.mark.parametrize("kw,match", [
@@ -166,6 +206,7 @@ def test_buckets_and_residual_shapes_are_planned_from_names():
 PLANS = {
     "plain": dict(),
     "dp4-zero1": dict(zero=1, dp=4),
+    "dp2-zero1": dict(zero=1, dp=2),
     "dp2-tp2-zero2": dict(zero=2, dp=2, tp=2),
     "dp2-tp2-pp2-zero1": dict(zero=1, dp=2, tp=2, pp=2),
     "pp2": dict(pp=2),
@@ -228,11 +269,16 @@ def test_canonical_round_trip_is_bit_equal(name):
 @pytest.mark.parametrize("src,dst", [
     ("dp4-zero1", "dp2-tp2-zero2"), ("dp2-tp2-pp2-zero1", "plain"),
     ("plain", "dp2-tp2-pp2-zero1"), ("pp2", "dp4-zero1"),
-    ("dp4-zero1-ef", "dp2-ef")])
+    ("dp4-zero1-ef", "dp2-ef"),
+    # embed.weight FLAT where it was saved (as every plan before PR 31 had
+    # it), DP where it is loaded -- and back
+    ("dp2-zero1", "dp4-zero1"), ("dp4-zero1", "dp2-zero1")])
 def test_canonical_restores_across_plans(src, dst):
     """The cross-layout restore, without devices: what one plan wrote,
     another reads into ITS forms and writes back unchanged."""
     a, b = _plan(**PLANS[src]), _plan(**PLANS[dst])
+    if {src, dst} == {"dp2-zero1", "dp4-zero1"}:
+        assert {p.leaves["embed.weight"].form for p in (a, b)} == {FLAT, DP}
     canon = a.to_canonical(*_state(a, 5))
     there = b.from_canonical(canon, _state(b, 77))
     for n, s in there[2].items():

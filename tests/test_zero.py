@@ -74,15 +74,120 @@ def test_zero1_matches_replicated():
 
 
 def test_zero1_state_is_dp_sharded():
-    """The point of ZeRO-1: optimizer state lives in 1/dp flat shards."""
+    """The point of ZeRO-1: optimizer state lives in 1/dp shards -- flat
+    ones, or (the 10 x 8 weight, whose rows a flat quarter would cut)
+    along the dimension dp divides."""
     z1 = _step(zero=1)
     dp = 4
+    want = {"weight": P(None, "dp"), "bias": P("dp")}
     for n, leaves in ((n, jax.tree_util.tree_leaves(s))
                       for n, s in z1.states.items()):
         for leaf in leaves:
-            assert leaf.sharding.spec == P("dp"), (n, leaf.sharding)
+            assert leaf.sharding.spec == want[n], (n, leaf.sharding)
             shard = leaf.addressable_shards[0].data
             assert shard.size * dp == leaf.size, (n, shard.shape, leaf.shape)
+
+
+# a GPT whose vocabulary dp=4 does not divide, head tied to the table: the
+# table's flat quarter would cut its rows, so ZeRO lays it along the units
+_GPT = dict(vocab_size=66, units=16, num_layers=2, num_heads=2, max_length=8)
+_TABLE = "backbone.word_embed.weight"
+
+
+def _gpt_loss(logits, labels):
+    logp = jax.nn.log_softmax(logits.astype(jnp.float32), -1)
+    return -jnp.mean(jnp.take_along_axis(logp, labels[..., None], -1))
+
+
+def _gpt_step(zero, dp=4, **kw):
+    from mxnet_tpu.gluon.model_zoo.gpt import GPTForCausalLM
+    mx.random.seed(0)
+    net = GPTForCausalLM(dropout=0.0, embed_dropout=0.0, **_GPT)
+    net.initialize()
+    net(np.array(onp.zeros((8, 8), "int32")))     # materialize the params
+    return ShardedTrainStep(
+        net, _gpt_loss, mx.optimizer.create("adam", learning_rate=0.01),
+        make_mesh({"dp": dp}), batch_specs=(P("dp", None), P("dp", None)),
+        n_labels=1, zero=zero, **kw)
+
+
+def test_zero1_table_with_cut_rows_matches_replicated():
+    """The tied table in the DP form under zero=1 x grad_accum=2: three
+    updates give the zero=0 step's losses and parameters, and the lowered
+    step holds the table's gradient in column blocks, P(None, "dp")."""
+    import re
+    rs = onp.random.RandomState(2)
+    t = rs.randint(0, 66, (3, 8, 9)).astype("int32")
+    base = _gpt_step(zero=0)
+    z1 = _gpt_step(zero=1, grad_accum=2)
+    lay = z1.layout
+    assert lay.replicated_dp == [_TABLE]
+    assert lay.leaves[_TABLE].state_spec == P(None, "dp")
+    for leaf in jax.tree_util.tree_leaves(z1.states[_TABLE]):
+        assert leaf.sharding.spec == P(None, "dp")
+        assert leaf.addressable_shards[0].data.shape == (66, 4)
+    for b in t:
+        x, y = b[:, :-1], b[:, 1:]
+        l0 = float(base(x, y).asnumpy())
+        l1 = float(z1(x.reshape(2, 4, 8), y.reshape(2, 4, 8)).asnumpy())
+        onp.testing.assert_allclose(l1, l0, rtol=1e-5, atol=1e-6)
+    for n in base.trainable:
+        onp.testing.assert_allclose(
+            onp.asarray(z1.trainable[n]), onp.asarray(base.trainable[n]),
+            rtol=1e-5, atol=1e-5, err_msg=n)
+    x = onp.zeros((2, 4, 8), "int32")
+    text = z1.lower(x, x).as_text()
+    # the update pins the table and its gradient to the state's columns ...
+    assert len(re.findall(
+        r'sdy\.sharding_constraint %\w+ <@mesh, \[\{\}, \{"dp"\}\]> : '
+        r'tensor<66x16xf32>', text)) == 2
+    # ... and the lookup hands the gradient over in them: ids gathered once
+    # a micro-batch, where the forward uses them, an all-to-all each way
+    assert len(re.findall(
+        r'"stablehlo\.all_gather"\(%\w+\).*\(tensor<1x8xi32>\) -> '
+        r'tensor<4x8xi32>', text)) == 1
+    assert text.count('"stablehlo.all_to_all"') == 2
+    assert 'out_shardings=[<@mesh, [{}, {"dp"}]>] manual_axes' in text
+
+
+def test_train_plan_span_counts_the_forms():
+    """``mx/train.plan``: leaves and bytes by form, and the replicated
+    leaves that took the DP form -- GPT-2's own table, (50257, 1280), is
+    one leaf of 257 MB under dp=4 and none under dp=1."""
+    from mxnet_tpu.parallel.layout import StateLayout
+    was = mx.trace.active()
+    mx.trace.enable()
+    try:
+        mx.trace.clear()
+        _gpt_step(zero=1)
+        _gpt_step(zero=1, dp=1)
+        _gpt_step(zero=0)
+        plans = [ev["args"] for ev in mx.trace.spans(category="train")
+                 if ev["name"] == "train.plan"]
+    finally:
+        mx.trace.enable(was)
+    assert len(plans) == 3
+    z1, one, z0 = plans
+    table = 66 * 16 * 4
+    assert (z1["replicated_dp_leaves"], z1["replicated_dp_bytes"]) == \
+        (1, table)
+    assert (z1["dp_leaves"], z1["flat_leaves"], z1["param_leaves"]) == \
+        (1, 35, 0)                  # no tp axis: every other leaf is flat
+    assert z1["dp_bytes"] == table
+    assert (one["replicated_dp_leaves"], one["replicated_dp_bytes"]) == (0, 0)
+    assert (one["dp_leaves"], one["flat_leaves"]) == (0, 36)
+    assert (z0["param_leaves"], z0["dp_leaves"], z0["flat_leaves"]) == \
+        (36, 0, 0)
+    for p in plans:
+        assert sum(p[f"{f}_bytes"] for f in ("param", "flat", "dp")) == \
+            z0["param_bytes"]
+    big = StateLayout({"word_embed.weight": (50257, 1280),
+                       "position_embed.weight": (1024, 1280)}, {}, {},
+                      {"dp": 4}, zero=1)
+    census = big.census(dict.fromkeys(big.leaves, 4))
+    assert (census["replicated_dp_leaves"],
+            census["replicated_dp_bytes"]) == (1, 257315840)
+    assert (census["flat_leaves"], census["dp_leaves"]) == (1, 1)
 
 
 def test_zero2_with_grad_accum_matches_replicated():
