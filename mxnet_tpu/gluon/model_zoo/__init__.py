@@ -3,4 +3,5 @@ from . import vision  # noqa: F401
 from . import bert  # noqa: F401
 from . import gpt  # noqa: F401
 from . import afmoe  # noqa: F401
+from . import keye  # noqa: F401
 from . import model_store  # noqa: F401

@@ -16,7 +16,8 @@ from .activations import (  # noqa: F401
     Activation, LeakyReLU, PReLU, ELU, SELU, GELU, SiLU, Swish,
 )
 from .transformer import (  # noqa: F401
-    MultiHeadAttention, GroupedQueryAttention, PositionwiseFFN, GatedFFN,
+    MultiHeadAttention, GroupedQueryAttention, IndexedAttention,
+    SparseIndexer, PositionwiseFFN, GatedFFN,
     TransformerEncoder,
     TransformerEncoderCell, TransformerDecoderCell,
 )

@@ -132,13 +132,15 @@ class MoEDense(HybridBlock):
 
 
 class RoutedExperts(HybridBlock):
-    """One share of a sigmoid-routed, drop-free expert layer on
-    (batch, seq, units), plus the shared expert every share computes.
+    """One share of a drop-free expert layer on (batch, seq, units), plus
+    the shared expert every share computes (where the layer has one).
 
     ``num_experts`` is the published count the router scores;
     ``held = (lo, hi)`` the experts whose weights live here.  Per token:
-    ``s = sigmoid(u Wr)`` (float32), the ``num_experts_per_tok`` largest
-    of ``s + expert_bias`` are selected, their weights are
+    ``s = sigmoid(u Wr)`` or, with ``score_func="softmax"``, the softmax
+    of ``u Wr`` over all the experts (float32); the
+    ``num_experts_per_tok`` largest of ``s + expert_bias`` are selected,
+    their weights are
     ``route_scale * s_e / (sum of the selected s + 1e-20)`` — normalised
     over all selected experts, held or not — and the layer returns
     ``Shared(u) + sum over selected held e of w_e Expert_e(u)``, each
@@ -156,8 +158,13 @@ class RoutedExperts(HybridBlock):
 
     def __init__(self, units, hidden_size, num_experts, num_experts_per_tok,
                  held, rows_bound, shared_hidden_size=0, route_scale=1.0,
-                 dtype="float32"):
+                 dtype="float32", score_func="sigmoid"):
         super().__init__()
+        if score_func not in ("sigmoid", "softmax"):
+            raise ValueError(f"score_func {score_func!r} is neither "
+                             "'sigmoid' nor 'softmax'")
+        self._score = jax.nn.sigmoid if score_func == "sigmoid" \
+            else jax.nn.softmax
         lo, hi = held
         if not 0 <= lo < hi <= num_experts:
             raise ValueError(f"held experts {held} outside [0, "
@@ -204,7 +211,7 @@ class RoutedExperts(HybridBlock):
                 p._finish_deferred_init()
         n_exp, topk, lo, n_held = (self._n_exp, self._topk, self._lo,
                                    self._n_held)
-        bound, scale = self._rows, self._scale
+        bound, scale, score = self._rows, self._scale, self._score
         compute = amp.target_dtype() if amp.is_active() else None
         if _telemetry._active:
             _telemetry.inc("moe.rows_bound_total", bound)
@@ -214,7 +221,7 @@ class RoutedExperts(HybridBlock):
             dt = u.dtype if compute is None else compute
             with jax.named_scope("mx.moe"):
                 with jax.named_scope("mx.moe.route"):
-                    s = jax.nn.sigmoid(jnp.dot(
+                    s = score(jnp.dot(
                         u.astype(jnp.float32), router.astype(jnp.float32).T,
                         precision=jax.lax.Precision.HIGHEST))
                     _, idx = jax.lax.top_k(s + bias, topk)      # (T, k)

@@ -14,6 +14,7 @@ from __future__ import annotations
 from ... import numpy as np
 from ... import numpy_extension as npx
 from ..block import HybridBlock
+from ..parameter import Parameter
 from .basic_layers import Dense, Dropout, LayerNorm, RMSNorm
 
 
@@ -201,11 +202,11 @@ class GatedFFN(HybridBlock):
 
 
 class GroupedQueryAttention(HybridBlock):
-    """Gated causal self-attention on (batch, seq, units) with
-    ``num_heads`` query heads over ``num_kv_heads`` key/value heads, no
-    biases: RMSNorm over the head dimension on q and k (one scale vector
-    each, shared by the heads), and a sigmoid gate on the attention
-    output from a fourth projection of the input,
+    """Causal self-attention on (batch, seq, units) with ``num_heads``
+    query heads over ``num_kv_heads`` key/value heads, no biases: RMSNorm
+    over the head dimension on q and k (one scale vector each, shared by
+    the heads) and, with ``gate`` (the default), a sigmoid gate on the
+    attention output from a fourth projection of the input,
     ``(o * sigmoid(g)) Wo``.  What differs by layer is optional: rotary
     embedding on q and k, and a causal ``window``.  The core is
     ``ops.attention.multi_head_attention``: the flash kernels on a TPU,
@@ -214,7 +215,7 @@ class GroupedQueryAttention(HybridBlock):
 
     def __init__(self, units, num_heads, num_kv_heads, head_dim=None,
                  window=None, rotary=False, rope_theta=10000.0,
-                 epsilon=1e-5):
+                 epsilon=1e-5, gate=True):
         super().__init__()
         head_dim = units // num_heads if head_dim is None else head_dim
         if num_heads % num_kv_heads:
@@ -230,7 +231,9 @@ class GroupedQueryAttention(HybridBlock):
         self.query_proj = proj(num_heads * head_dim)
         self.key_proj = proj(num_kv_heads * head_dim)
         self.value_proj = proj(num_kv_heads * head_dim)
-        self.gate_proj = proj(num_heads * head_dim)
+        self._gate = bool(gate)
+        if gate:
+            self.gate_proj = proj(num_heads * head_dim)
         self.out_proj = proj(units)
         self.q_norm = RMSNorm(epsilon, in_channels=head_dim)
         self.k_norm = RMSNorm(epsilon, in_channels=head_dim)
@@ -239,8 +242,9 @@ class GroupedQueryAttention(HybridBlock):
         b, s, _ = t.shape
         return norm(t.reshape(b, s, heads, self._dim)).reshape(b, s, -1)
 
-    def forward(self, x):
-        from ...ops.attention import multi_head_attention
+    def _qkv(self, x):
+        """The core's operands: q and k normed and, where the layer has
+        positions, rotated."""
         q = self._heads_normed(self.query_proj(x), self.q_norm, self._heads)
         k = self._heads_normed(self.key_proj(x), self.k_norm,
                                self._kv_heads)
@@ -248,10 +252,151 @@ class GroupedQueryAttention(HybridBlock):
         if self._rotary:
             q = npx.rotary_embedding(q, self._heads, self._theta)
             k = npx.rotary_embedding(k, self._kv_heads, self._theta)
+        return q, k, v
+
+    def _output(self, out, x):
+        if self._gate:
+            out = out * npx.sigmoid(self.gate_proj(x))
+        return self.out_proj(out)
+
+    def forward(self, x):
+        from ...ops.attention import multi_head_attention
+        q, k, v = self._qkv(x)
         out = multi_head_attention(q, k, v, self._heads, causal=True,
                                    kv_heads=self._kv_heads,
                                    window=self._window)
-        return self.out_proj(out * npx.sigmoid(self.gate_proj(x)))
+        return self._output(out, x)
+
+
+def _amp_operands(*arrays):
+    """Raw arrays in AMP's type where it is on (what a ``Dense`` hands
+    its product), else as they are."""
+    from ... import amp
+    if not amp.is_active():
+        return arrays
+    return tuple(a.astype(amp.target_dtype()) for a in arrays)
+
+
+class SparseIndexer(HybridBlock):
+    """The indexer of a learned sparse attention (DeepSeek-V3.2's
+    "lightning indexer") on (batch, seq, units): ``num_heads`` small query
+    heads against one key head, ``qI = x WqI``, ``kI = LayerNorm(x WkI)``,
+    rotary on both, head weights ``w = x Ww / sqrt(num_heads)``, scores
+    ``I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s]) / sqrt(head_dim)``.
+    forward returns ``(scores (b, s, s) float32, selection (b, s, s)
+    int8)``: each query's ``topk`` best positions ``s <= t`` (all while
+    ``t < topk``; ties to the lower ``s``).
+
+    Its input is detached: nothing upstream learns from the indexer, and
+    the selection has no gradient, so the indexer's own leaves learn only
+    from a loss on the scores (``nn.IndexedAttention``'s alignment loss).
+    The score products take AMP's type where it is on, like a ``Dense``;
+    their sums and everything after are float32.
+    Counts that are no trained parameter ride the aux channel, holding
+    the **last** training call's values: ``selected_pairs`` (1,) and
+    ``select_grid`` (16, 16), selected pairs by sixteenth of the sequence
+    of the query and of the key.
+    """
+
+    def __init__(self, units, num_heads, head_dim, topk, rope_theta=10000.0,
+                 epsilon=1e-6):
+        super().__init__()
+        from ...ops.sparse_index import GRID
+        self._heads, self._dim, self._topk = num_heads, head_dim, int(topk)
+        self._theta = rope_theta
+        self.query_proj = Dense(num_heads * head_dim, use_bias=False,
+                                flatten=False)
+        self.key_proj = Dense(head_dim, use_bias=False, flatten=False)
+        self.weight_proj = Dense(num_heads, use_bias=False, flatten=False)
+        self.key_norm = LayerNorm(epsilon=epsilon, in_channels=head_dim)
+        self.selected_pairs = Parameter(
+            "selected_pairs", grad_req="null", shape=(1,), dtype="int32",
+            init="zeros")
+        self.select_grid = Parameter(
+            "select_grid", grad_req="null", shape=(GRID, GRID), dtype="int32",
+            init="zeros")
+
+    def forward(self, x):
+        import jax
+
+        from ... import autograd
+        from ...numpy.multiarray import _invoke
+        from ...ops import sparse_index
+        for p in (self.selected_pairs, self.select_grid):
+            if p._data is None:
+                p._finish_deferred_init()
+        heads, dim, topk = self._heads, self._dim, self._topk
+
+        def scores(q, k, w):
+            b, s, _ = q.shape
+            q, k = _amp_operands(q, k)
+            return sparse_index.index_scores(
+                q.reshape(b, s, heads, dim), k, w * heads ** -0.5)
+
+        def select(i):
+            chosen = sparse_index.select_topk(i, topk)
+            return (chosen,) + sparse_index.selection_counts(chosen)
+
+        # one scope a layer each: projections, norm, rotary and scores
+        # (their backward is this scope's transpose), then the selection
+        with jax.named_scope("mx.dsa.index"):
+            u = _invoke(jax.lax.stop_gradient, (x,), name="stop_gradient")
+            q = npx.rotary_embedding(self.query_proj(u), heads, self._theta)
+            k = npx.rotary_embedding(self.key_norm(self.key_proj(u)), 1,
+                                     self._theta)
+            index = _invoke(scores, (q, k, self.weight_proj(u)),
+                            name="sparse_index_scores")
+        with jax.named_scope("mx.dsa.select"):
+            chosen, pairs, grid = _invoke(select, (index,),
+                                          name="sparse_index_select")
+        if autograd.is_training():
+            self.selected_pairs.data()._rebind(pairs._data)
+            self.select_grid.data()._rebind(grid._data)
+        return index, chosen
+
+
+class IndexedAttention(GroupedQueryAttention):
+    """``GroupedQueryAttention`` whose every query reads only the keys its
+    ``SparseIndexer`` selects (``topk`` of the earlier positions), the
+    selection handed to the attention core as data; rotary positions on
+    q and k, no gate, no window.  forward returns
+    ``(output, alignment loss)``: the loss is ``mean_t KL(p_t ||
+    softmax_{S_t}(I[t, :]))`` with ``p_t`` the core's own probabilities
+    over the selected keys averaged over the heads — the only thing the
+    indexer's leaves learn from, and nothing else learns from it.  Add it
+    to the model's loss.
+    """
+
+    def __init__(self, units, num_heads, num_kv_heads, head_dim,
+                 index_heads, index_dim, topk, rope_theta=10000.0,
+                 epsilon=1e-5):
+        super().__init__(units, num_heads, num_kv_heads, head_dim,
+                         rotary=True, rope_theta=rope_theta,
+                         epsilon=epsilon, gate=False)
+        self.indexer = SparseIndexer(units, index_heads, index_dim, topk,
+                                     rope_theta=rope_theta, epsilon=epsilon)
+
+    def forward(self, x):
+        import jax
+
+        from ...numpy.multiarray import _invoke
+        from ...ops import sparse_index
+        from ...ops.attention import multi_head_attention
+        heads, kv_heads = self._heads, self._kv_heads
+        q, k, v = self._qkv(x)
+        index, chosen = self.indexer(x)
+        out = multi_head_attention(q, k, v, heads, causal=True,
+                                   kv_heads=kv_heads, selection=chosen)
+
+        def align(i, q_, k_):
+            # the core's operand type, so that p is what the core computed
+            q_, k_ = _amp_operands(q_, k_)
+            with jax.named_scope("mx.dsa.align"):
+                return sparse_index.align_loss(i, chosen._data, q_, k_,
+                                               heads, kv_heads)
+
+        loss = _invoke(align, (index, q, k), name="sparse_index_align")
+        return self._output(out, x), loss
 
 
 def _fused_ln_residual(x, h, ln, p):

@@ -78,9 +78,10 @@ def _sp_mesh():
     return None
 
 
-def _flash(qh, kh, vh, causal, window=None):
+def _flash(qh, kh, vh, causal, window=None, selection=None):
     """The Pallas flash kernel on (batch, heads, seq, dim) queries and
-    (batch, kv_heads, seq, dim) keys and values.
+    (batch, kv_heads, seq, dim) keys and values; ``selection`` (batch,
+    seq_q, seq_k), where given, goes with the batch.
 
     GSPMD cannot partition a Mosaic kernel — on a multi-device mesh the
     lowering stops with "Mosaic kernels cannot be automatically
@@ -99,11 +100,17 @@ def _flash(qh, kh, vh, causal, window=None):
     as is.
     """
     from .pallas.flash_attention import flash_attention
+    operands = (qh, kh, vh) + (() if selection is None else (selection,))
+
+    def kernel(q, k, v, *sel):
+        return flash_attention(q, k, v, causal=causal, window=window,
+                               **({"selection": sel[0]} if sel else {}))
+
     mesh = _scope_mesh()
     if (mesh is None or mesh.size == 1
             or set(jax.sharding.get_abstract_mesh().manual_axes)
             >= set(mesh.axis_names)):
-        return flash_attention(qh, kh, vh, causal=causal, window=window)
+        return kernel(*operands)
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
@@ -112,11 +119,11 @@ def _flash(qh, kh, vh, causal, window=None):
         return name if n > 1 and dim % n == 0 else None
 
     spec = P(axis("dp", qh.shape[0]), axis("tp", kh.shape[1]), None, None)
-    return shard_map(
-        lambda q, k, v: flash_attention(q, k, v, causal=causal,
-                                        window=window),
-        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_vma=False)(qh, kh, vh)
+    # a selection goes with the batch, whole a head
+    specs = (spec, spec, spec) + (P(spec[0], None, None),) * (
+        len(operands) - 3)
+    return shard_map(kernel, mesh=mesh, in_specs=specs, out_specs=spec,
+                     check_vma=False)(*operands)
 
 
 def write_prefill_kv(k_cache, v_cache, key, value, slot, heads):
@@ -449,16 +456,22 @@ def decode_attention(query, key, value, k_cache, v_cache, positions, heads):
 
 
 def multi_head_attention(query, key, value, heads, mask=None, dropout_p=0.0,
-                         causal=False, kv_heads=None, window=None):
+                         causal=False, kv_heads=None, window=None,
+                         selection=None):
     """Fused MHA on (batch, seq, heads*dim) queries and (batch, seq,
     kv_heads*dim) keys and values.
 
     ``kv_heads`` (default ``heads``) divides ``heads``: query head ``i``
     reads KV head ``i // (heads // kv_heads)``.  ``window`` (causal only)
-    keeps query ``i`` to the keys ``0 <= i - j < window``.
+    keeps query ``i`` to the keys ``0 <= i - j < window``.  ``selection``
+    (batch, seq_q, seq_k), integer or bool, is a mask that is data: query
+    ``i`` of a batch row reads key ``j`` only where it is nonzero, all
+    heads alike, on top of ``causal`` / ``window`` (a learned sparse
+    attention's top-k).  Unlike ``mask`` it stays on the kernels: they
+    take it as one more operand.  It has no gradient.
 
     Routing: sp-sharded scope -> ring attention (sequence parallelism over
-    ICI; ungrouped, unwindowed calls only); long unmasked sequences on
+    ICI; ungrouped, unwindowed calls without a selection only); long unmasked sequences on
     TPU -> Pallas flash kernel; otherwise the XLA dot_general
     composition. Attention-prob dropout (training only, reference:
     transformer attention cells) forces the XLA path.
@@ -473,8 +486,9 @@ def multi_head_attention(query, key, value, heads, mask=None, dropout_p=0.0,
     if not autograd.is_training():
         dropout_p = 0.0
     pure = mask is None and dropout_p == 0.0
-    plain = kv_heads == heads and window is None
+    plain = kv_heads == heads and window is None and selection is None
     sp_mesh = _sp_mesh() if pure and plain else None
+    sel = selection._data if hasattr(selection, "_data") else selection
 
     # one scope a layer: split, pad to the kernel's lanes, kernel, slice,
     # merge — the glue is this scope's time less its kernels'
@@ -500,8 +514,11 @@ def multi_head_attention(query, key, value, heads, mask=None, dropout_p=0.0,
             # no fallback here: a kernel Mosaic refuses must surface as
             # the compiler's error, not as a slower step
             return merge(_flash(split(q), split(k, kv_heads),
-                                split(v, kv_heads), causal, window))
+                                split(v, kv_heads), causal, window, sel))
         m = mask._data if hasattr(mask, "_data") else mask
+        if sel is not None:
+            chosen = (sel != 0)[:, None]
+            m = chosen if m is None else chosen & m.astype(bool)
         return _reference_attention(q, k, v, heads, m, causal, None,
                                     dropout_p, kv_heads, window)
 

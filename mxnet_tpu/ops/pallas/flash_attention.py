@@ -166,17 +166,34 @@ def _attended(q0, k0, shape, q_axis, seq_k, causal, window=None):
     return valid
 
 
+def _selected(tile):
+    """A selection tile (int8, key-major) as a mask: widened first, so
+    that the comparison is made in the layout of the iota masks."""
+    return tile.astype(jnp.int32) != 0
+
+
+def _pad_selection(sel, sk_full, sq_full):
+    """Zero rows and columns (nothing selected) up to the padded
+    ``(seq_k, seq_q)``."""
+    _, sk, sq = sel.shape
+    if sk == sk_full and sq == sq_full:
+        return sel
+    return jnp.pad(sel, ((0, 0), (0, sk_full - sk), (0, sq_full - sq)))
+
+
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, seq_k,
-                causal, scale, block_q, window=None):
+def _fwd_kernel(q_ref, k_ref, v_ref, *refs, block_k, seq_k, causal, scale,
+                block_q, window=None):
     """One q-block against its k-blocks, tiles transposed as in the
     backward (``sT = k @ q.T``): the running max and sum are ``(1, bq)``
     rows, reduced over sublanes and broadcast along them, and ``lse``
     leaves as it is kept.  The accumulator is ``(d, bq)`` and is turned
-    once, at the end."""
+    once, at the end.  ``refs`` are the outputs, after the selection's
+    ``(seq_k, bq)`` column block where the call has one."""
+    *sel_ref, o_ref, lse_ref = refs
     qi = pl.program_id(1)
     # keep MXU operands in the input dtype (bf16 on TPU): fp32 matmul
     # costs ~8x the MXU passes; accumulation is fp32 regardless via
@@ -185,7 +202,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, seq_k,
     bq, d = q.shape
     nk = k_ref.shape[1] // block_k
 
-    masked = causal or seq_k % block_k != 0
+    masked = causal or seq_k % block_k != 0 or bool(sel_ref)
 
     def body(j, carry):
         m_prev, l_prev, acc = carry
@@ -195,8 +212,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, seq_k,
         sT = jax.lax.dot_general(k, q, _NT,
                                  preferred_element_type=jnp.float32) * scale
         if masked:
-            sT = jnp.where(_attended(qi * block_q, k0, sT.shape, 1, seq_k,
-                                     causal, window), sT, _NEG_INF)
+            valid = _attended(qi * block_q, k0, sT.shape, 1, seq_k, causal,
+                              window)
+            if sel_ref:
+                valid &= _selected(sel_ref[0][0, pl.ds(k0, block_k), :])
+            sT = jnp.where(valid, sT, _NEG_INF)
         m_new = jnp.maximum(m_prev, jnp.max(sT, axis=0, keepdims=True))
         pT = jnp.exp(sT - m_new)
         corr = jnp.exp(m_prev - m_new)
@@ -226,7 +246,8 @@ def _kv_row(group):
     return lambda i: _div(i, group)
 
 
-def _fwd(q, k, v, causal, scale, block_q, block_k, interpret, window=None):
+def _fwd(q, k, v, causal, scale, block_q, block_k, interpret, window=None,
+         sel=None):
     bh, sq, d = q.shape
     sk = k.shape[1]
     kv = _kv_row(bh // k.shape[0])
@@ -248,6 +269,15 @@ def _fwd(q, k, v, causal, scale, block_q, block_k, interpret, window=None):
     # default scoped limit (16 MiB: fp32 at seq 8192) the call asks for
     # what it holds; shorter calls are compiled as they always were
     resident = 4 * sk_full * d * k.dtype.itemsize
+    operands, sel_specs = (q, k, v), []
+    if sel is not None:
+        # a q-block's column of the selection, whole like K and V, named
+        # by the batch row of the head: (seq_k, block_q) int8, twice
+        heads = bh // sel.shape[0]
+        operands += (_pad_selection(sel, sk_full, sq_full),)
+        sel_specs = [pl.BlockSpec((1, sk_full, block_q),
+                                  lambda i, j: (_div(i, heads), 0, j))]
+        resident += 2 * sk_full * block_q
     extra = {} if resident <= _VMEM_DEFAULT - (4 << 20) else {
         "compiler_params": pltpu.CompilerParams(
             vmem_limit_bytes=resident + (16 << 20))}
@@ -261,7 +291,7 @@ def _fwd(q, k, v, causal, scale, block_q, block_k, interpret, window=None):
             pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, sk_full, d), lambda i, j: (kv(i), 0, 0)),
             pl.BlockSpec((1, sk_full, d), lambda i, j: (kv(i), 0, 0)),
-        ],
+        ] + sel_specs,
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, 1, block_q), lambda i, j: (i, 0, j)),
@@ -273,7 +303,7 @@ def _fwd(q, k, v, causal, scale, block_q, block_k, interpret, window=None):
         interpret=interpret,
         name="mx_flash_fwd",
         **extra,
-    )(q, k, v)
+    )(*operands)
     if sq_pad:
         out = out[:, :sq]
         lse = lse[:, :, :sq]
@@ -283,17 +313,19 @@ def _fwd(q, k, v, causal, scale, block_q, block_k, interpret, window=None):
 @functools.partial(jax.custom_vjp,
                    nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
 def _flash(q, k, v, causal, scale, block_q, block_k, bwd_block_q,
-           bwd_block_k, interpret, window=None):
+           bwd_block_k, interpret, window=None, sel=None):
+    """``sel``: a selection ``(batch, seq_k, seq_q)`` int8, or None: an
+    empty pytree, so such a call has no operand and no residual for it."""
     out, _ = _fwd(q, k, v, causal, scale, block_q, block_k, interpret,
-                  window)
+                  window, sel)
     return out
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, bwd_block_q,
-               bwd_block_k, interpret, window=None):
+               bwd_block_k, interpret, window=None, sel=None):
     out, lse = _fwd(q, k, v, causal, scale, block_q, block_k, interpret,
-                    window)
-    return out, (q, k, v, out, lse)
+                    window, sel)
+    return out, (q, k, v, out, lse, sel)
 
 
 # ---------------------------------------------------------------------------
@@ -301,7 +333,7 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, bwd_block_q,
 # ---------------------------------------------------------------------------
 
 def _bwd_tile(q, do, lse, delta, kb, vb, q0, k0, seq_k, causal, scale,
-              window=None):
+              window=None, sel=None):
     """Shared recompute for one tile, transposed: returns (pT, dsT), both
     ``(block_k, block_q)``.
 
@@ -315,24 +347,28 @@ def _bwd_tile(q, do, lse, delta, kb, vb, q0, k0, seq_k, causal, scale,
     sT = jax.lax.dot_general(kb, q, _NT,
                              preferred_element_type=jnp.float32) * scale
     pT = jnp.exp(sT - lse)
-    if causal or seq_k % kb.shape[0] != 0:
-        pT = jnp.where(_attended(q0, k0, sT.shape, 1, seq_k, causal,
-                                 window), pT, 0.0)
+    if causal or seq_k % kb.shape[0] != 0 or sel is not None:
+        valid = _attended(q0, k0, sT.shape, 1, seq_k, causal, window)
+        if sel is not None:
+            valid &= _selected(sel)
+        pT = jnp.where(valid, pT, 0.0)
     dpT = jax.lax.dot_general(vb, do, _NT,
                               preferred_element_type=jnp.float32)
     dsT = pT * (dpT - delta) * scale
     return pT, dsT
 
 
-def _bwd_dkv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
-                    dk_ref, dv_ref, dk_acc, dv_acc, *, block_q, block_k,
-                    seq_k, causal, scale, window=None, group=1):
+def _bwd_dkv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, *refs,
+                    block_q, block_k, seq_k, causal, scale, window=None,
+                    group=1):
     """dK/dV for one k-block, accumulated in VMEM over sequential q-block
     steps (grid (bh, nk, nq): the last axis revisits the same output
     block, written once on its last step).  With grouped KV heads the
     grid is (b * kv_heads, nk, group, nq): the last two axes walk the
     q-blocks of every query head of the group into the same
-    accumulator."""
+    accumulator.  ``refs``: the selection's tile where the call has one,
+    the two outputs, the two accumulators."""
+    *sel_ref, dk_ref, dv_ref, dk_acc, dv_acc = refs
     j = pl.program_id(1)
     q_axis = 2 if group == 1 else 3
     if group == 1:
@@ -357,7 +393,8 @@ def _bwd_dkv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
         do = do_ref[0]
         pT, dsT = _bwd_tile(q, do, lse_ref[0], delta_ref[0], k_ref[0],
                             v_ref[0], qi * block_q, j * block_k, seq_k,
-                            causal, scale, window)
+                            causal, scale, window,
+                            *(r[0] for r in sel_ref))
         dv_acc[...] += jax.lax.dot_general(
             pT.astype(do.dtype), do, _NN,
             preferred_element_type=jnp.float32)
@@ -371,11 +408,12 @@ def _bwd_dkv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, dq_ref,
-                   dq_acc, *, block_q, block_k, seq_k, causal, scale,
-                   window=None):
+def _bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, *refs,
+                   block_q, block_k, seq_k, causal, scale, window=None):
     """dQ for one q-block, accumulated in VMEM over sequential k-block
-    steps."""
+    steps.  ``refs``: the selection's tile where the call has one, the
+    output, the accumulator."""
+    *sel_ref, dq_ref, dq_acc = refs
     qi, j = pl.program_id(1), pl.program_id(2)
 
     @pl.when(j == 0)
@@ -387,7 +425,8 @@ def _bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, dq_ref,
         kb = k_ref[0]
         _, dsT = _bwd_tile(q_ref[0], do_ref[0], lse_ref[0], delta_ref[0],
                            kb, v_ref[0], qi * block_q, j * block_k, seq_k,
-                           causal, scale, window)
+                           causal, scale, window,
+                           *(r[0] for r in sel_ref))
         dq_acc[...] += jax.lax.dot_general(
             dsT.astype(kb.dtype), kb, _TN,
             preferred_element_type=jnp.float32)
@@ -408,7 +447,7 @@ def _flash_bwd(causal, scale, block_q, block_k, bwd_block_q, bwd_block_k,
     backward holds ~2x the forward's accumulators per tile, so its tuned
     optimum is usually smaller.
     """
-    q, k, v, out, lse = res
+    q, k, v, out, lse, sel = res
     bh, sq, d = q.shape
     bkv, sk = k.shape[:2]
     group = bh // bkv
@@ -431,6 +470,8 @@ def _flash_bwd(causal, scale, block_q, block_k, bwd_block_q, bwd_block_k,
         k, v = jnp.pad(k, pad), jnp.pad(v, pad)
     sq_full, sk_full = sq + sq_pad, sk + sk_pad
     nq, nk = sq_full // bq, sk_full // bk
+    # the kernels' operand list: empty without a selection
+    sel = [] if sel is None else [_pad_selection(sel, sk_full, sq_full)]
 
     # a step that has no work names the block of the nearest step that
     # has, so the pipeline finds it resident and fetches nothing.  An
@@ -475,6 +516,9 @@ def _flash_bwd(causal, scale, block_q, block_k, bwd_block_q, bwd_block_k,
         dkv_q = pl.BlockSpec((1, bq, d), lambda i, b, a: (i, q_of(a, b), 0))
         dkv_r = pl.BlockSpec((1, 1, bq), lambda i, b, a: (i, 0, q_of(a, b)))
         dkv_k = pl.BlockSpec((1, bk, d), lambda i, b, a: (i, b, 0))
+        dkv_sel = [pl.BlockSpec(
+            (1, bk, bq), lambda i, b, a: (
+                _div(i, bh // s.shape[0]), b, q_of(a, b))) for s in sel]
     else:
         # one KV head a grid row; its query heads are rows i*group + g
         def q_row(i, g):
@@ -486,10 +530,13 @@ def _flash_bwd(causal, scale, block_q, block_k, bwd_block_q, bwd_block_k,
         dkv_r = pl.BlockSpec(
             (1, 1, bq), lambda i, b, g, a: (q_row(i, g), 0, q_of(a, b)))
         dkv_k = pl.BlockSpec((1, bk, d), lambda i, b, g, a: (i, b, 0))
+        dkv_sel = [pl.BlockSpec(
+            (1, bk, bq), lambda i, b, g, a: (
+                _div(i, bkv // s.shape[0]), b, q_of(a, b))) for s in sel]
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, group=group, **kw),
         grid=dkv_grid,
-        in_specs=[dkv_q, dkv_q, dkv_r, dkv_r, dkv_k, dkv_k],
+        in_specs=[dkv_q, dkv_q, dkv_r, dkv_r, dkv_k, dkv_k] + dkv_sel,
         out_specs=[dkv_k, dkv_k],
         out_shape=[
             jax.ShapeDtypeStruct((bkv, sk_full, d), k.dtype),
@@ -499,29 +546,33 @@ def _flash_bwd(causal, scale, block_q, block_k, bwd_block_q, bwd_block_k,
                         pltpu.VMEM((bk, d), jnp.float32)],
         interpret=interpret,
         name="mx_flash_bwd_dkv",
-    )(q, do, lse, delta, k, v)
+    )(q, do, lse, delta, k, v, *sel)
 
     q_spec = pl.BlockSpec((1, bq, d), lambda i, a, b: (i, a, 0))
     r_spec = pl.BlockSpec((1, 1, bq), lambda i, a, b: (i, 0, a))
     kv = _kv_row(group)
     k_spec = pl.BlockSpec((1, bk, d),
                           lambda i, a, b: (kv(i), k_of(a, b), 0))
+    sel_spec = [pl.BlockSpec(
+        (1, bk, bq), lambda i, a, b: (
+            _div(i, bh // s.shape[0]), k_of(a, b), a)) for s in sel]
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **kw),
         grid=(bh, nq, nk),
-        in_specs=[q_spec, q_spec, r_spec, r_spec, k_spec, k_spec],
+        in_specs=[q_spec, q_spec, r_spec, r_spec, k_spec, k_spec] + sel_spec,
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((bh, sq_full, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
         name="mx_flash_bwd_dq",
-    )(q, do, lse, delta, k, v)
+    )(q, do, lse, delta, k, v, *sel)
 
     if sq_pad:
         dq = dq[:, :sq]
     if sk_pad:
         dk, dv = dk[:, :sk], dv[:, :sk]
-    return dq, dk, dv
+    # a selection is data with no tangent
+    return dq, dk, dv, None
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -529,7 +580,7 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                     block_k=None, bwd_block_q=None, bwd_block_k=None,
-                    interpret=False, window=None):
+                    interpret=False, window=None, selection=None):
     """Multi-head attention, scores never materialized in HBM.
 
     q: (batch, heads, seq_q, head_dim); k/v: (batch, kv_heads, seq_k,
@@ -538,7 +589,11 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     that head (nothing is repeated in HBM) and dK/dV sum over the group
     inside the kernel.  ``window`` (with ``causal``) keeps query ``i`` to
     the keys ``0 <= i - j < window``; tiles left of that band are skipped
-    like tiles above the diagonal.
+    like tiles above the diagonal.  ``selection`` (batch, seq_q, seq_k),
+    integer or bool, keeps query ``i`` of a batch row to the keys ``j``
+    where it is nonzero, for all the row's heads alike and on top of
+    ``causal`` / ``window``; it has no gradient and skips no tile.  A
+    query it leaves no key gets an undefined (finite) row.
     Returns (batch, heads, seq_q, head_dim).
 
     Block shapes default to ``mx.autotune.resolve_blocks`` — the tuned
@@ -582,8 +637,15 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     if d_pad:
         pad = ((0, 0), (0, 0), (0, d_pad))
         qr, kr, vr = (jnp.pad(qr, pad), jnp.pad(kr, pad), jnp.pad(vr, pad))
+    sel = None
+    if selection is not None:
+        if selection.shape != (b, sq, sk):
+            raise ValueError(f"selection {selection.shape} is not (batch, "
+                             f"seq_q, seq_k) = {(b, sq, sk)}")
+        # key-major once, for all three kernels
+        sel = jnp.swapaxes(selection.astype(jnp.int8), 1, 2)
     out = _flash(qr, kr, vr, causal, scale, block_q, block_k, bwd_block_q,
-                 bwd_block_k, interpret, window)
+                 bwd_block_k, interpret, window, sel)
     if d_pad:
         out = out[..., :d]
     return out.reshape(b, h, sq, d)

@@ -1,0 +1,204 @@
+"""A learned sparse attention's indexer: scores, selection, alignment.
+
+A small side network (the "lightning indexer" of the DeepSeek-V3.2
+report) scores every earlier position for every query; the attention
+then reads only the ``topk`` best.  Three functions on raw ``jax``
+arrays, composed by ``nn.IndexedAttention``:
+
+* ``index_scores``: ``I[t, s] = sum_j w[t, j] * relu(qI[t, j] . kI[s]) /
+  sqrt(d)`` for ``j`` over the indexer's heads.  The per-head products
+  are ``(heads, seq, seq)``; they exist a block of queries at a time
+  (``lax.map``), each block made again in the backward pass, so only the
+  ``(seq, seq)`` sum is ever whole.
+* ``select_topk``: for each query the ``topk`` positions ``s <= t`` with
+  the largest scores (all of them while ``t < topk``), ties to the lower
+  ``s``, as an int8 mask.  No sort: the ``topk``-th largest score of a
+  row is found by bisection on the bits of an order-preserving integer
+  key — 32 counting passes over the scores, whatever ``topk`` — and the
+  mask is one comparison with it.  Rows where more scores tie with the
+  threshold than it may admit take a second path (a running count along
+  the row) that a ``lax.cond`` enters only when some row needs it.
+* ``align_loss``: ``mean_t KL(p_t || softmax_{S_t}(I[t, :]))`` with
+  ``p_t`` the attention's own probabilities over the selected keys,
+  averaged over the heads — what the indexer is trained towards.  The
+  attention's probabilities are ``(heads, seq, seq)`` too: one pass over
+  q and k in query blocks computes the value and, in the same pass, the
+  closed-form gradient ``(softmax_{S_t}(I) - p) / T``, which is all the
+  backward pass keeps.  Nothing else gets a gradient from it.
+
+The selection is a mask ``(batch, seq, seq)`` and not gathered keys: the
+flash kernels take it as an operand (``ops/pallas/flash_attention.py``).
+"""
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+
+#: bytes of per-head products one block of queries may hold
+_BLOCK_BYTES = 1 << 27
+#: sixteenths of the sequence ``selection_counts`` sorts pairs into
+GRID = 16
+
+
+def _query_block(seq, heads):
+    """Queries a block: a power of two that divides ``seq`` and keeps
+    ``heads x block x seq`` float32 under ``_BLOCK_BYTES``."""
+    want = max(1, _BLOCK_BYTES // (4 * heads * seq))
+    return math.gcd(seq, 1 << (want.bit_length() - 1))
+
+
+def _blocked(t, block):
+    """(b, s, ...) -> (s / block, b, block, ...): the mapped axis first."""
+    b, s = t.shape[:2]
+    return jnp.moveaxis(t.reshape(b, s // block, block, *t.shape[2:]), 1, 0)
+
+
+def _unblocked(t):
+    """(n, b, block, ...) -> (b, n * block, ...)."""
+    t = jnp.moveaxis(t, 0, 1)
+    return t.reshape(t.shape[0], t.shape[1] * t.shape[2], *t.shape[3:])
+
+
+def index_scores(q_idx, k_idx, weights):
+    """q_idx (b, s, heads, d), k_idx (b, s, d), weights (b, s, heads) ->
+    scores (b, s, s) float32; entries above the diagonal are computed
+    and mean nothing.  The products take their operands in the type they
+    come in and accumulate in float32; everything after is float32."""
+    b, s, heads, d = q_idx.shape
+    weights = weights.astype(jnp.float32) * (1.0 / math.sqrt(d))
+    block = _query_block(s, heads)
+
+    @jax.checkpoint
+    def one(args):
+        qb, wb = args                                   # (b, block, h, ..)
+        per_head = jnp.einsum("bqhd,bkd->bqhk", qb, k_idx,
+                              preferred_element_type=jnp.float32)
+        return jnp.sum(jax.nn.relu(per_head) * wb[..., None], axis=2)
+
+    return _unblocked(jax.lax.map(
+        one, (_blocked(q_idx, block), _blocked(weights, block))))
+
+
+def _ordered_key(x):
+    """float32 -> uint32 with the same order (no NaNs): the sign bit
+    flipped on positives, every bit on negatives.  Finite scores give
+    keys above 0."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    return bits ^ jnp.where(bits >> 31 == 1, jnp.uint32(0xFFFFFFFF),
+                            jnp.uint32(0x80000000))
+
+
+def select_topk(scores, topk):
+    """scores (b, s, s) float32 -> int8 (b, s, s): 1 where query ``t``
+    reads key ``s``: ``s <= t`` and the score is among the row's ``topk``
+    largest of those (ties to the lower ``s``).  Exactly
+    ``min(t + 1, topk)`` a row."""
+    b, s, _ = scores.shape
+    rows = jnp.arange(s, dtype=jnp.int32)[:, None]
+    causal = jnp.arange(s, dtype=jnp.int32)[None, :] <= rows
+    # keys above the diagonal are 0, under every score's key
+    key = jnp.where(causal, _ordered_key(jax.lax.stop_gradient(scores)),
+                    jnp.uint32(0))
+    want = jnp.minimum(rows[:, 0] + 1, topk)[None, :]          # (1, s)
+
+    def bit(i, thr):
+        cand = thr | (jnp.uint32(1) << (jnp.uint32(31) - i.astype(
+            jnp.uint32)))
+        n = jnp.sum(key >= cand[..., None], axis=-1, dtype=jnp.int32)
+        return jnp.where(n >= topk, cand, thr)
+
+    # the largest value ``topk`` or more keys reach: the topk-th largest
+    # key of a row that has as many, else 0 (everything under the
+    # diagonal is taken)
+    thr = jax.lax.fori_loop(0, 32, bit, jnp.zeros((b, s), jnp.uint32))
+    thr = thr[..., None]
+    reach = (key >= thr) & causal
+
+    def with_ties():
+        # more keys equal the threshold than it may admit: the first
+        # ``need`` of them along the row
+        above = (key > thr) & causal
+        need = want - jnp.sum(above, axis=-1, dtype=jnp.int32)
+        equal = (key == thr) & causal
+        nth = jnp.cumsum(equal.astype(jnp.int32), axis=-1)
+        return above | (equal & (nth <= need[..., None]))
+
+    exact = jnp.all(jnp.sum(reach, axis=-1, dtype=jnp.int32) == want)
+    return jax.lax.cond(exact, lambda: reach, with_ties).astype(jnp.int8)
+
+
+def selection_counts(selection):
+    """selection (b, s, s) -> (pairs selected (1,), pairs by sixteenth of
+    the sequence the query and the key lie in (16, 16)), int32, summed
+    over the batch.  Position ``t`` lies in sixteenth ``16 t // s``."""
+    b, s, _ = selection.shape
+    hot = ((jnp.arange(s) * GRID // s)[:, None]
+           == jnp.arange(GRID)).astype(jnp.int8)
+    # integer products, int32 sums: exact whatever the back-end's
+    # floating-point product would round
+    by_key = jax.lax.dot_general(
+        selection.astype(jnp.int8), hot, (((2,), (0,)), ((), ())),
+        preferred_element_type=jnp.int32)                    # (b, s, 16)
+    grid = jnp.sum(hot.astype(jnp.int32)[:, :, None]
+                   * jnp.sum(by_key, axis=0)[:, None, :], axis=0)
+    return jnp.sum(grid).reshape(1), grid
+
+
+def align_loss(scores, selection, q, k, heads, kv_heads):
+    """``mean_t KL(p_t || softmax_{S_t}(scores[t]))`` over all ``b * s``
+    queries: ``p_t`` is the mean over the ``heads`` of the attention's
+    probabilities on the selected keys ``S_t`` (q (b, s, heads * d) and
+    k (b, s, kv_heads * d) as the attention core gets them, scaled by
+    ``1 / sqrt(d)``), a constant here.  Differentiable in ``scores``
+    alone, by the closed form ``(softmax_{S_t}(scores) - p) / (b s)``."""
+    return _align(scores, selection, jax.lax.stop_gradient(q),
+                  jax.lax.stop_gradient(k), heads, kv_heads)
+
+
+def _align_pass(scores, selection, q, k, heads, kv_heads):
+    b, s, hd = q.shape
+    d = hd // heads
+    group = heads // kv_heads
+    scale = 1.0 / math.sqrt(d)
+    block = _query_block(s, heads)
+    kh = k.reshape(b, s, kv_heads, d)
+
+    def one(args):
+        qb, ib, mb = args                   # (b, block, ...) of one block
+        chosen = mb != 0
+        att = jnp.einsum("bqngd,bknd->bngqk",
+                         qb.reshape(b, block, kv_heads, group, d), kh,
+                         preferred_element_type=jnp.float32) * scale
+        att = jax.nn.softmax(jnp.where(chosen[:, None, None], att, -1e30),
+                             axis=-1)
+        p = jnp.where(chosen, jnp.mean(att, axis=(1, 2)), 0.0)
+        logq = jax.nn.log_softmax(
+            jnp.where(chosen, ib.astype(jnp.float32), -1e30), axis=-1)
+        kl = jnp.sum(jax.scipy.special.xlogy(p, p)
+                     - jnp.where(chosen, p * logq, 0.0))
+        return kl, jnp.where(chosen, jnp.exp(logq), 0.0) - p
+
+    kl, d_scores = jax.lax.map(one, (
+        _blocked(q, block), _blocked(scores, block),
+        _blocked(selection, block)))
+    tokens = b * s
+    return jnp.sum(kl) / tokens, _unblocked(d_scores) / tokens
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _align(scores, selection, q, k, heads, kv_heads):
+    return _align_pass(scores, selection, q, k, heads, kv_heads)[0]
+
+
+def _align_fwd(scores, selection, q, k, heads, kv_heads):
+    return _align_pass(scores, selection, q, k, heads, kv_heads)
+
+
+def _align_bwd(heads, kv_heads, d_scores, g):
+    return g * d_scores, None, None, None
+
+
+_align.defvjp(_align_fwd, _align_bwd)
