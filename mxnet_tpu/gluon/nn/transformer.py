@@ -365,6 +365,14 @@ class IndexedAttention(GroupedQueryAttention):
     over the selected keys averaged over the heads — the only thing the
     indexer's leaves learn from, and nothing else learns from it.  Add it
     to the model's loss.
+
+    Where the core ran its flash kernels (a TPU, a sequence of 512 or
+    more) it hands out each head's log-sum-exp over the selected keys,
+    and the loss is then one Pallas pass over q and k
+    (``ops/pallas/dsa_align.py``: no head's probabilities reach HBM) for
+    a sequence its blocks divide into; elsewhere (the CPU, short or
+    ragged sequences) it is the XLA composition of
+    ``ops/sparse_index.py``, the kernel's oracle.
     """
 
     def __init__(self, units, num_heads, num_kv_heads, head_dim,
@@ -385,17 +393,19 @@ class IndexedAttention(GroupedQueryAttention):
         heads, kv_heads = self._heads, self._kv_heads
         q, k, v = self._qkv(x)
         index, chosen = self.indexer(x)
-        out = multi_head_attention(q, k, v, heads, causal=True,
-                                   kv_heads=kv_heads, selection=chosen)
+        out, lse = multi_head_attention(q, k, v, heads, causal=True,
+                                        kv_heads=kv_heads, selection=chosen,
+                                        return_lse=True)
 
-        def align(i, q_, k_):
+        def align(i, q_, k_, *lse_):
             # the core's operand type, so that p is what the core computed
             q_, k_ = _amp_operands(q_, k_)
             with jax.named_scope("mx.dsa.align"):
                 return sparse_index.align_loss(i, chosen._data, q_, k_,
-                                               heads, kv_heads)
+                                               heads, kv_heads, *lse_)
 
-        loss = _invoke(align, (index, q, k), name="sparse_index_align")
+        loss = _invoke(align, (index, q, k) + (() if lse is None else (lse,)),
+                       name="sparse_index_align")
         return self._output(out, x), loss
 
 

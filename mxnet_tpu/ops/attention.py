@@ -78,10 +78,32 @@ def _sp_mesh():
     return None
 
 
-def _flash(qh, kh, vh, causal, window=None, selection=None):
+def _kernel_mesh():
+    """The mesh a Mosaic call made here has to be ``shard_map``ped over,
+    or None: no mesh scope, one device, or already inside a shard_map
+    over the whole mesh (the compressed-gradient path), where operands
+    are per-device and a kernel is called as is."""
+    mesh = _scope_mesh()
+    if (mesh is None or mesh.size == 1
+            or set(jax.sharding.get_abstract_mesh().manual_axes)
+            >= set(mesh.axis_names)):
+        return None
+    return mesh
+
+
+def _mesh_axis(mesh, name, dim):
+    """``name`` where the mesh has that axis and it divides ``dim``,
+    else None (the dim stays replicated)."""
+    n = int(mesh.shape.get(name, 1))
+    return name if n > 1 and dim % n == 0 else None
+
+
+def _flash(qh, kh, vh, causal, window=None, selection=None,
+           return_lse=False):
     """The Pallas flash kernel on (batch, heads, seq, dim) queries and
     (batch, kv_heads, seq, dim) keys and values; ``selection`` (batch,
-    seq_q, seq_k), where given, goes with the batch.
+    seq_q, seq_k), where given, goes with the batch.  With
+    ``return_lse`` the result is ``(out, lse (batch, heads, seq))``.
 
     GSPMD cannot partition a Mosaic kernel — on a multi-device mesh the
     lowering stops with "Mosaic kernels cannot be automatically
@@ -104,25 +126,23 @@ def _flash(qh, kh, vh, causal, window=None, selection=None):
 
     def kernel(q, k, v, *sel):
         return flash_attention(q, k, v, causal=causal, window=window,
-                               **({"selection": sel[0]} if sel else {}))
+                               **({"selection": sel[0]} if sel else {}),
+                               **({"return_lse": True} if return_lse
+                                  else {}))
 
-    mesh = _scope_mesh()
-    if (mesh is None or mesh.size == 1
-            or set(jax.sharding.get_abstract_mesh().manual_axes)
-            >= set(mesh.axis_names)):
+    mesh = _kernel_mesh()
+    if mesh is None:
         return kernel(*operands)
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    def axis(name, dim):
-        n = int(mesh.shape.get(name, 1))
-        return name if n > 1 and dim % n == 0 else None
-
-    spec = P(axis("dp", qh.shape[0]), axis("tp", kh.shape[1]), None, None)
+    spec = P(_mesh_axis(mesh, "dp", qh.shape[0]),
+             _mesh_axis(mesh, "tp", kh.shape[1]), None, None)
     # a selection goes with the batch, whole a head
     specs = (spec, spec, spec) + (P(spec[0], None, None),) * (
         len(operands) - 3)
-    return shard_map(kernel, mesh=mesh, in_specs=specs, out_specs=spec,
+    out_specs = (spec, P(*spec[:3])) if return_lse else spec
+    return shard_map(kernel, mesh=mesh, in_specs=specs, out_specs=out_specs,
                      check_vma=False)(*operands)
 
 
@@ -457,7 +477,7 @@ def decode_attention(query, key, value, k_cache, v_cache, positions, heads):
 
 def multi_head_attention(query, key, value, heads, mask=None, dropout_p=0.0,
                          causal=False, kv_heads=None, window=None,
-                         selection=None):
+                         selection=None, return_lse=False):
     """Fused MHA on (batch, seq, heads*dim) queries and (batch, seq,
     kv_heads*dim) keys and values.
 
@@ -469,6 +489,12 @@ def multi_head_attention(query, key, value, heads, mask=None, dropout_p=0.0,
     heads alike, on top of ``causal`` / ``window`` (a learned sparse
     attention's top-k).  Unlike ``mask`` it stays on the kernels: they
     take it as one more operand.  It has no gradient.
+
+    ``return_lse``: return ``(out, lse)``.  ``lse`` (batch, heads, seq)
+    float32 is each query's log-sum-exp of its scaled logits over the
+    keys it read, a constant — what the forward flash kernel keeps for
+    the backward, so it exists only where the kernels ran; on every other
+    route ``lse`` is None.
 
     Routing: sp-sharded scope -> ring attention (sequence parallelism over
     ICI; ungrouped, unwindowed calls without a selection only); long unmasked sequences on
@@ -513,8 +539,11 @@ def multi_head_attention(query, key, value, heads, mask=None, dropout_p=0.0,
         if _runtime.on_tpu() and pure and sk >= min_seq:
             # no fallback here: a kernel Mosaic refuses must surface as
             # the compiler's error, not as a slower step
-            return merge(_flash(split(q), split(k, kv_heads),
-                                split(v, kv_heads), causal, window, sel))
+            out = _flash(split(q), split(k, kv_heads), split(v, kv_heads),
+                         causal, window, sel, return_lse)
+            if return_lse:
+                return merge(out[0]), out[1]
+            return merge(out)
         m = mask._data if hasattr(mask, "_data") else mask
         if sel is not None:
             chosen = (sel != 0)[:, None]
@@ -522,4 +551,7 @@ def multi_head_attention(query, key, value, heads, mask=None, dropout_p=0.0,
         return _reference_attention(q, k, v, heads, m, causal, None,
                                     dropout_p, kv_heads, window)
 
-    return _invoke(fn, (query, key, value), name="multi_head_attention")
+    out = _invoke(fn, (query, key, value), name="multi_head_attention")
+    if return_lse and not isinstance(out, tuple):
+        return out, None        # a route that keeps no such statistic
+    return out

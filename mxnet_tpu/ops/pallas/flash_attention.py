@@ -311,21 +311,23 @@ def _fwd(q, k, v, causal, scale, block_q, block_k, interpret, window=None,
 
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11))
 def _flash(q, k, v, causal, scale, block_q, block_k, bwd_block_q,
-           bwd_block_k, interpret, window=None, sel=None):
+           bwd_block_k, interpret, window=None, stats=False, sel=None):
     """``sel``: a selection ``(batch, seq_k, seq_q)`` int8, or None: an
-    empty pytree, so such a call has no operand and no residual for it."""
-    out, _ = _fwd(q, k, v, causal, scale, block_q, block_k, interpret,
-                  window, sel)
-    return out
+    empty pytree, so such a call has no operand and no residual for it.
+    ``stats``: return ``(out, lse)``, the forward kernel's second result
+    (the backward's residual) beside its first."""
+    out, lse = _fwd(q, k, v, causal, scale, block_q, block_k, interpret,
+                    window, sel)
+    return (out, lse) if stats else out
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, bwd_block_q,
-               bwd_block_k, interpret, window=None, sel=None):
+               bwd_block_k, interpret, window=None, stats=False, sel=None):
     out, lse = _fwd(q, k, v, causal, scale, block_q, block_k, interpret,
                     window, sel)
-    return out, (q, k, v, out, lse, sel)
+    return ((out, lse) if stats else out), (q, k, v, out, lse, sel)
 
 
 # ---------------------------------------------------------------------------
@@ -437,7 +439,7 @@ def _bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, *refs,
 
 
 def _flash_bwd(causal, scale, block_q, block_k, bwd_block_q, bwd_block_k,
-               interpret, window, res, do):
+               interpret, window, stats, res, do):
     """Blocked Pallas backward (flash-style residuals: out + logsumexp).
 
     Memory is O(seq): P is rebuilt per (q-block, k-block) tile in VMEM from
@@ -448,6 +450,8 @@ def _flash_bwd(causal, scale, block_q, block_k, bwd_block_q, bwd_block_k,
     optimum is usually smaller.
     """
     q, k, v, out, lse, sel = res
+    if stats:
+        do, _ = do      # the statistics are handed out as constants
     bh, sq, d = q.shape
     bkv, sk = k.shape[:2]
     group = bh // bkv
@@ -580,7 +584,8 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                     block_k=None, bwd_block_q=None, bwd_block_k=None,
-                    interpret=False, window=None, selection=None):
+                    interpret=False, window=None, selection=None,
+                    return_lse=False):
     """Multi-head attention, scores never materialized in HBM.
 
     q: (batch, heads, seq_q, head_dim); k/v: (batch, kv_heads, seq_k,
@@ -594,7 +599,10 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     where it is nonzero, for all the row's heads alike and on top of
     ``causal`` / ``window``; it has no gradient and skips no tile.  A
     query it leaves no key gets an undefined (finite) row.
-    Returns (batch, heads, seq_q, head_dim).
+    Returns (batch, heads, seq_q, head_dim); with ``return_lse`` a pair of
+    that and the log-sum-exp of every query's scaled logits over the keys
+    it read, (batch, heads, seq_q) float32: what the forward kernel keeps
+    for the backward, handed out as a constant (no gradient).
 
     Block shapes default to ``mx.autotune.resolve_blocks`` — the tuned
     winner for this (seq_q, seq_k, head_dim) bucket when one is loaded,
@@ -645,7 +653,12 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
         # key-major once, for all three kernels
         sel = jnp.swapaxes(selection.astype(jnp.int8), 1, 2)
     out = _flash(qr, kr, vr, causal, scale, block_q, block_k, bwd_block_q,
-                 bwd_block_k, interpret, window, sel)
+                 bwd_block_k, interpret, window, return_lse, sel)
+    if return_lse:
+        out, lse = out
     if d_pad:
         out = out[..., :d]
-    return out.reshape(b, h, sq, d)
+    out = out.reshape(b, h, sq, d)
+    if return_lse:
+        return out, jax.lax.stop_gradient(lse).reshape(b, h, sq)
+    return out

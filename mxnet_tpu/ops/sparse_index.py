@@ -22,9 +22,19 @@ arrays, composed by ``nn.IndexedAttention``:
   ``p_t`` the attention's own probabilities over the selected keys,
   averaged over the heads — what the indexer is trained towards.  The
   attention's probabilities are ``(heads, seq, seq)`` too: one pass over
-  q and k in query blocks computes the value and, in the same pass, the
-  closed-form gradient ``(softmax_{S_t}(I) - p) / T``, which is all the
-  backward pass keeps.  Nothing else gets a gradient from it.
+  q and k computes the value and, in the same pass, the closed-form
+  gradient ``(softmax_{S_t}(I) - p) / T``, which is all the backward
+  pass keeps.  Nothing else gets a gradient from it.  Which pass runs is
+  decided by what the call can see.  Handed each head's log-sum-exp over
+  the selected keys (the attention core's forward flash kernel computed
+  it: a TPU, a sequence of 512 or more) at a sequence its blocks divide
+  into, it is the Pallas kernel ``mx_dsa_align``
+  (``ops/pallas/dsa_align.py``), in which a tile's per-head
+  probabilities never leave VMEM.  Everywhere else — the CPU, the
+  tier-1 tests, short or ragged sequences — it is the XLA composition
+  ``_align_pass``, in query blocks (``lax.map``), which writes each
+  block's ``(heads, block, seq)`` logits to HBM; it is also the kernel's
+  oracle.
 
 The selection is a mask ``(batch, seq, seq)`` and not gathered keys: the
 flash kernels take it as an operand (``ops/pallas/flash_attention.py``).
@@ -147,18 +157,26 @@ def selection_counts(selection):
     return jnp.sum(grid).reshape(1), grid
 
 
-def align_loss(scores, selection, q, k, heads, kv_heads):
+def align_loss(scores, selection, q, k, heads, kv_heads, lse=None):
     """``mean_t KL(p_t || softmax_{S_t}(scores[t]))`` over all ``b * s``
     queries: ``p_t`` is the mean over the ``heads`` of the attention's
     probabilities on the selected keys ``S_t`` (q (b, s, heads * d) and
     k (b, s, kv_heads * d) as the attention core gets them, scaled by
     ``1 / sqrt(d)``), a constant here.  Differentiable in ``scores``
-    alone, by the closed form ``(softmax_{S_t}(scores) - p) / (b s)``."""
+    alone, by the closed form ``(softmax_{S_t}(scores) - p) / (b s)``.
+
+    ``lse`` (b, heads, s), where the core hands it out
+    (``multi_head_attention(return_lse=True)``: its flash kernels ran),
+    is each head's log-sum-exp over ``S_t``, a constant; with it and a
+    sequence ``mx_dsa_align``'s blocks divide into, the pass is the
+    Pallas kernel, else the XLA composition."""
     return _align(scores, selection, jax.lax.stop_gradient(q),
-                  jax.lax.stop_gradient(k), heads, kv_heads)
+                  jax.lax.stop_gradient(k), lse, heads, kv_heads)
 
 
 def _align_pass(scores, selection, q, k, heads, kv_heads):
+    """The XLA composition (and the kernel's oracle): per-head
+    probabilities a block of queries at a time."""
     b, s, hd = q.shape
     d = hd // heads
     group = heads // kv_heads
@@ -188,17 +206,57 @@ def _align_pass(scores, selection, q, k, heads, kv_heads):
     return jnp.sum(kl) / tokens, _unblocked(d_scores) / tokens
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _align(scores, selection, q, k, heads, kv_heads):
-    return _align_pass(scores, selection, q, k, heads, kv_heads)[0]
+def _align_kernel_pass(scores, selection, q, k, lse, heads, kv_heads):
+    """The same by ``ops/pallas/dsa_align.py``.  GSPMD cannot partition
+    a Mosaic call, so under a mesh it is a ``shard_map`` with the batch
+    over 'dp' (``ops/attention.py::_flash``); a query's heads are summed
+    inside the kernel, so they stay whole on every device."""
+    from .. import runtime
+    from .attention import _kernel_mesh, _mesh_axis
+    from .pallas.dsa_align import align_pass
+    tokens = q.shape[0] * q.shape[1]
+    d = q.shape[2] // heads
+
+    def kernel(i, m, q_, k_, l):
+        def split(t, n):        # (b, s, n*d) -> (b, n, s, d)
+            return t.reshape(*t.shape[:2], n, d).transpose(0, 2, 1, 3)
+
+        return align_pass(i, m, split(q_, heads), split(k_, kv_heads), l,
+                          tokens, interpret=runtime.pallas_interpret())
+
+    operands = (scores, selection, q, k, lse)
+    mesh = _kernel_mesh()
+    if mesh is None:
+        kl, d_scores = kernel(*operands)
+    else:
+        from jax import shard_map
+        from jax.sharding import PartitionSpec as P
+        spec = P(_mesh_axis(mesh, "dp", q.shape[0]), None, None)
+        kl, d_scores = shard_map(
+            kernel, mesh=mesh, in_specs=(spec,) * 5, out_specs=(spec, spec),
+            check_vma=False)(*operands)
+    return jnp.sum(kl) / tokens, d_scores
 
 
-def _align_fwd(scores, selection, q, k, heads, kv_heads):
+def _pass(scores, selection, q, k, lse, heads, kv_heads):
+    from .pallas.dsa_align import BLOCK
+    if lse is not None and q.shape[1] % BLOCK == 0:
+        return _align_kernel_pass(scores, selection, q, k, lse, heads,
+                                  kv_heads)
     return _align_pass(scores, selection, q, k, heads, kv_heads)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _align(scores, selection, q, k, lse, heads, kv_heads):
+    return _pass(scores, selection, q, k, lse, heads, kv_heads)[0]
+
+
+def _align_fwd(scores, selection, q, k, lse, heads, kv_heads):
+    return _pass(scores, selection, q, k, lse, heads, kv_heads)
+
+
 def _align_bwd(heads, kv_heads, d_scores, g):
-    return g * d_scores, None, None, None
+    return g * d_scores, None, None, None, None
 
 
 _align.defvjp(_align_fwd, _align_bwd)

@@ -338,3 +338,33 @@ def test_compiled_for_a_v5e_no_sparse_statistic_and_no_fp32_gradient(
     assert not re.search(r"f32\[[\d,]*,1\]", entry)
     assert not re.search(r"f32\[128,1024,128\]", entry)
     assert "f32[128,1,1024]" in entry
+
+
+def test_the_alignment_kernel_compiles_for_a_v5e_at_the_cells_size(one_v5e):
+    """``mx_dsa_align`` (ops/pallas/dsa_align.py) at ``keye-train-8k``'s
+    shapes — 32 heads over 4 KV heads x 128, 8192 tokens, bf16 — is taken
+    by Mosaic as written, and its program holds the ``(seq, seq)`` arrays
+    it is given and returns and no ``heads x seq x seq`` one."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from mxnet_tpu.ops.pallas import dsa_align
+    b, h, hk, s, d = 1, 32, 4, 8192, 128
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e)
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        with jax.default_matmul_precision("default"):
+            text = jax.jit(lambda *a: dsa_align.align_pass(*a, b * s)).lower(
+                spec((b, s, s), jnp.float32), spec((b, s, s), jnp.int8),
+                spec((b, h, s, d), jnp.bfloat16),
+                spec((b, hk, s, d), jnp.bfloat16),
+                spec((b, h, s), jnp.float32)).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "mx_dsa_align" in text
+    sizes = [onp.prod([int(n) for n in dims.split(",")]) for dims in
+             re.findall(r"\b[a-z]+[0-9]+\[([0-9,]+)\]", text)]
+    assert max(sizes) == b * s * s

@@ -3,13 +3,15 @@
 Interpret-mode tests (the CPU suite) say a kernel's arithmetic is right;
 only libtpu's Mosaic compiler says whether it fits VMEM, whether its
 layouts and its int8/fp8 dots are accepted on this ``device_kind``.  This
-script compiles each of the five kernels with ``interpret=False`` at the
+script compiles each of the six kernels with ``interpret=False`` at the
 shapes the model zoo uses and the static blocks the device gets
 (``autotune.kernels._STATIC_DEFAULTS``), and checks numerics against a
 plain-jnp reference:
 
     flash_attention        fwd + bwd, bf16 and fp32, seq 512..8192, with
                            grouped KV heads and a causal window
+    dsa_align              the sparse indexer's loss and its gradient,
+                           against the XLA composition
     ln_residual            fwd + bwd, bf16 and fp32
     quantized_matmul       int8 x int8 -> int32
     fp8_matmul             e4m3 and e5m2
@@ -193,6 +195,41 @@ def _fp8_case(M, N, K, fmt):
     return f"fp8_matmul {M}x{N}x{K} {fmt}", check
 
 
+def _dsa_align_case(B, H, KV, S, D, topk, dtype):
+    """``mx_dsa_align`` on the statistics the forward flash kernel hands
+    out, against the XLA composition it replaces (its oracle)."""
+    def check():
+        from mxnet_tpu.ops import sparse_index
+        from mxnet_tpu.ops.pallas import flash_attention as fa
+        ks = jax.random.split(jax.random.PRNGKey(3), 4)
+        q = jax.random.normal(ks[0], (B, S, H * D), dtype)
+        k, v = (jax.random.normal(kk, (B, S, KV * D), dtype)
+                for kk in ks[1:3])
+        scores = 2.0 * jax.random.normal(ks[3], (B, S, S), jnp.float32)
+        sel = jax.jit(lambda i: sparse_index.select_topk(i, topk))(scores)
+
+        def split(t, n):
+            return t.reshape(B, S, n, D).transpose(0, 2, 1, 3)
+
+        lse = jax.jit(lambda q, k, v: fa.flash_attention(
+            split(q, H), split(k, KV), split(v, KV), causal=True,
+            selection=sel, return_lse=True)[1])(q, k, v)
+        got, g = jax.jit(jax.value_and_grad(
+            lambda i: sparse_index.align_loss(i, sel, q, k, H, KV, lse)))(
+                scores)
+        want, gr = jax.jit(jax.value_and_grad(
+            lambda i: sparse_index.align_loss(i, sel, q, k, H, KV)))(scores)
+        res = {"loss_relerr": abs(float(got) / float(want) - 1.0),
+               "grad_relerr": _relerr([g], [gr]),
+               "off_selection": float(jnp.max(jnp.abs(
+                   jnp.where(sel == 0, g, 0.0))))}
+        assert res["loss_relerr"] < 1e-4 and res["grad_relerr"] < 1e-3 \
+            and res["off_selection"] == 0.0, res
+        return res
+    return (f"dsa_align b{B}h{H}kv{KV}s{S}d{D} top{topk} "
+            f"{jnp.dtype(dtype).name}"), check
+
+
 def _conv_case(N, H, W, Cin, Cout):
     def check():
         from mxnet_tpu.ops.pallas_conv_bwd import (conv3x3_bn_relu_ref,
@@ -239,6 +276,10 @@ def cases():
                                kv_heads=1, window=2048))
         out.append(_flash_case(1, 8, 8192, 128, dtype, causal=True,
                                kv_heads=1, window=2048, bwd=False))
+    # the sparse indexer's loss: the keye zoo family's heads at the
+    # cell's length, and a batch of shorter float32 rows
+    out.append(_dsa_align_case(1, 32, 4, 8192, 128, 2048, bf16))
+    out.append(_dsa_align_case(2, 8, 2, 1024, 128, 256, f32))
     for dtype in (bf16, f32):
         out.append(_ln_case(32 * 128, 768, dtype))       # BERT-base bs32
     out.append(_int8_case(1024, 3072, 768, "gelu"))      # GPT-2 FFN up
