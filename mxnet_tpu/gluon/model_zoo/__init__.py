@@ -4,4 +4,5 @@ from . import bert  # noqa: F401
 from . import gpt  # noqa: F401
 from . import afmoe  # noqa: F401
 from . import keye  # noqa: F401
+from . import nemotron_h  # noqa: F401
 from . import model_store  # noqa: F401

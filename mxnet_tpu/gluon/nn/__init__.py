@@ -22,5 +22,6 @@ from .transformer import (  # noqa: F401
     TransformerEncoderCell, TransformerDecoderCell,
 )
 from .moe import MoEDense, RoutedExperts  # noqa: F401
+from .ssm import Mamba2Mixer  # noqa: F401
 from .fuse import FusableSequential  # noqa: F401
 from ..block import Block, HybridBlock, SymbolBlock  # noqa: F401

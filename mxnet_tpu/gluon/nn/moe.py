@@ -131,6 +131,15 @@ class MoEDense(HybridBlock):
                             self.w_out.data()), name="moe_dense")
 
 
+#: ``lax.ragged_dot``'s operands are padded with zeros to widths this
+#: divides: XLA's TPU kernel takes its blocks from what divides a width
+#: (512, 256 or 128), and at 2688 x 1856, which 256 divides neither way,
+#: its 128 x 128 blocks took 14.6-16.2 ms a layer's two products, forward
+#: and backward, against 8.0-8.5 at 2816 x 2048 (v5e, PERF.md section 6,
+#: PR 36).  Widths it divides already (or under it) are left alone.
+_BLOCK = 256
+
+
 class RoutedExperts(HybridBlock):
     """One share of a drop-free expert layer on (batch, seq, units), plus
     the shared expert every share computes (where the layer has one).
@@ -143,8 +152,13 @@ class RoutedExperts(HybridBlock):
     their weights are
     ``route_scale * s_e / (sum of the selected s + 1e-20)`` — normalised
     over all selected experts, held or not — and the layer returns
-    ``Shared(u) + sum over selected held e of w_e Expert_e(u)``, each
-    expert a SwiGLU of width ``hidden_size``.
+    ``Shared(u) + sum over selected held e of w_e Expert_e(u)``.  An
+    expert of width ``hidden_size`` is, by ``activation``, a SwiGLU of
+    three matrices, ``(silu(u Wg) * (u Wu)) Wd`` (``"swiglu"``, the
+    default), or a squared-ReLU feed-forward of two,
+    ``relu(u Wu)**2 Wd`` (``"relu2"``: no gate matrix exists, routed or
+    shared); the shared expert has the routed experts' form at its own
+    width.
 
     The assignments that name a held expert are sorted by expert and
     computed as ragged groups of at most ``rows_bound`` rows in all (a
@@ -158,8 +172,13 @@ class RoutedExperts(HybridBlock):
 
     def __init__(self, units, hidden_size, num_experts, num_experts_per_tok,
                  held, rows_bound, shared_hidden_size=0, route_scale=1.0,
-                 dtype="float32", score_func="sigmoid"):
+                 dtype="float32", score_func="sigmoid",
+                 activation="swiglu"):
         super().__init__()
+        if activation not in ("swiglu", "relu2"):
+            raise ValueError(f"activation {activation!r} is neither "
+                             "'swiglu' nor 'relu2'")
+        self._gated = activation == "swiglu"
         if score_func not in ("sigmoid", "softmax"):
             raise ValueError(f"score_func {score_func!r} is neither "
                              "'sigmoid' nor 'softmax'")
@@ -175,8 +194,10 @@ class RoutedExperts(HybridBlock):
         n = self._n_held
         self.router = Parameter("router", shape=(num_experts, units),
                                 dtype=dtype)
-        self.w_gate = Parameter("w_gate", shape=(n, units, hidden_size),
-                                dtype=dtype)
+        if self._gated:
+            self.w_gate = Parameter("w_gate",
+                                    shape=(n, units, hidden_size),
+                                    dtype=dtype)
         self.w_up = Parameter("w_up", shape=(n, units, hidden_size),
                               dtype=dtype)
         self.w_down = Parameter("w_down", shape=(n, hidden_size, units),
@@ -188,13 +209,14 @@ class RoutedExperts(HybridBlock):
                                      init="zeros")
         self.rows_over = Parameter("rows_over", grad_req="null",
                                    shape=(1,), dtype="int32", init="zeros")
-        # the shared expert's three matrices, laid out as Dense keeps
-        # them (out, in); computed inside the layer's own scope
+        # the shared expert's matrices, laid out as Dense keeps them
+        # (out, in); computed inside the layer's own scope
         self._shared = bool(shared_hidden_size)
         if self._shared:
             f = shared_hidden_size
-            self.shared_gate = Parameter("shared_gate", shape=(f, units),
-                                         dtype=dtype)
+            if self._gated:
+                self.shared_gate = Parameter("shared_gate",
+                                             shape=(f, units), dtype=dtype)
             self.shared_up = Parameter("shared_up", shape=(f, units),
                                        dtype=dtype)
             self.shared_down = Parameter("shared_down", shape=(units, f),
@@ -202,11 +224,17 @@ class RoutedExperts(HybridBlock):
 
     def forward(self, x):
         from ... import amp, autograd
-        shared = (self.shared_gate, self.shared_up, self.shared_down) \
-            if self._shared else ()
-        for p in (self.router, self.w_gate, self.w_up, self.w_down,
-                  self.expert_bias, self.expert_load, self.rows_over) \
-                + shared:
+        gated = self._gated
+        # an expert's matrices in the order it applies them; a relu^2
+        # expert has no gate matrix
+        experts = ((self.w_gate,) if gated else ()) \
+            + (self.w_up, self.w_down)
+        shared = ()
+        if self._shared:
+            shared = ((self.shared_gate,) if gated else ()) \
+                + (self.shared_up, self.shared_down)
+        for p in (self.router, self.expert_bias, self.expert_load,
+                  self.rows_over) + experts + shared:
             if p._data is None:
                 p._finish_deferred_init()
         n_exp, topk, lo, n_held = (self._n_exp, self._topk, self._lo,
@@ -216,7 +244,17 @@ class RoutedExperts(HybridBlock):
         if _telemetry._active:
             _telemetry.inc("moe.rows_bound_total", bound)
 
-        def fn(x_, router, w_gate, w_up, w_down, bias, *sh):
+        def hidden(product, mats):
+            """An expert's hidden activation: ``product(w)`` is its input
+            times one of ``mats``, its matrices but the last."""
+            if gated:
+                gate, up = mats
+                return jax.nn.silu(product(gate)) * product(up)
+            return jnp.square(jax.nn.relu(product(mats[0])))
+
+        def fn(x_, router, *rest):
+            n = len(experts)
+            mats, bias, sh = rest[:n], rest[n], rest[n + 1:]
             u = x_.reshape(-1, x_.shape[-1])
             dt = u.dtype if compute is None else compute
             with jax.named_scope("mx.moe"):
@@ -257,17 +295,26 @@ class RoutedExperts(HybridBlock):
                     # transposed.  They are selected away, never
                     # multiplied away, after every product: the select's
                     # transpose does the same to the cotangents
+                    w = w.astype(dt)
+                    # zeros up to the next multiple of the kernel's block
+                    # change no value
+                    k, n = w.shape[1:]
+                    pad_k, pad_n = (-d % _BLOCK if d > _BLOCK else 0
+                                    for d in (k, n))
+                    if pad_k or pad_n:
+                        rows = jnp.pad(rows, ((0, 0), (0, pad_k)))
+                        w = jnp.pad(w, ((0, 0), (0, pad_k), (0, pad_n)))
                     return jnp.where(live, jax.lax.ragged_dot(
-                        rows, w.astype(dt), sizes), 0)
+                        rows, w, sizes)[:, :n], 0)
 
                 with jax.named_scope("mx.moe.experts"):
-                    y = grouped(jax.nn.silu(grouped(x_rows, w_gate))
-                                * grouped(x_rows, w_up), w_down)
+                    y = grouped(hidden(lambda w: grouped(x_rows, w),
+                                       mats[:-1]), mats[-1])
                     out = 0.0
                     if sh:
-                        g, up, down = (m.astype(dt) for m in sh)
+                        *ins, down = (m.astype(dt) for m in sh)
                         ud = u.astype(dt)
-                        out = ((jax.nn.silu(ud @ g.T) * (ud @ up.T))
+                        out = (hidden(lambda w: ud @ w.T, ins)
                                @ down.T).astype(jnp.float32)
                 out = out + jnp.zeros(u.shape, jnp.float32).at[token].add(
                     y.astype(jnp.float32) * w_rows)
@@ -278,9 +325,9 @@ class RoutedExperts(HybridBlock):
                         over.astype(jnp.float32).reshape(1))
 
         out, load, over = _invoke(
-            fn, (x, self.router.data(), self.w_gate.data(),
-                 self.w_up.data(), self.w_down.data(),
-                 self.expert_bias.data()) + tuple(p.data() for p in shared),
+            fn, (x, self.router.data())
+            + tuple(p.data() for p in experts)
+            + (self.expert_bias.data(),) + tuple(p.data() for p in shared),
             name="routed_experts")
         if autograd.is_training():
             counted, past = self.expert_load.data(), self.rows_over.data()

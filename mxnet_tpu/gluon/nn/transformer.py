@@ -1,13 +1,13 @@
 """Transformer layers.
 
-Reference parity: the reference ships fused attention *ops*
-(src/operator/contrib/transformer.cc:675-828 interleaved_matmul_selfatt_qk/
-valatt, encdec variants) but no Gluon transformer *layers* — those lived in
-gluon-nlp (BERTEncoder/TransformerEncoderCell). This module provides the
-layer family those ops exist to serve, TPU-native: attention lowers to the
-Pallas flash kernel on TPU (mxnet_tpu/ops/pallas/flash_attention.py) and an
-XLA dot_general composition elsewhere; sequence sharding for long context
-rides mxnet_tpu.parallel.ring_attention.
+Reference parity: the reference ships fused attention *ops* (src/operator/
+contrib/transformer.cc:675-828) and no Gluon transformer *layers* (gluon-nlp
+had them); these are that family: the Pallas flash kernels on a TPU, an XLA
+composition elsewhere, ring attention for sequence sharding.
+
+A Mosaic kernel's compile-cache key holds its callers' line numbers: a line
+added or removed above a ``multi_head_attention`` call here recompiles every
+step that runs one, once a cell (PERF.md section 6, PR 32).
 """
 from __future__ import annotations
 
@@ -15,7 +15,7 @@ from ... import numpy as np
 from ... import numpy_extension as npx
 from ..block import HybridBlock
 from ..parameter import Parameter
-from .basic_layers import Dense, Dropout, LayerNorm, RMSNorm
+from .basic_layers import Dense, Dropout, Identity, LayerNorm, RMSNorm
 
 
 class MultiHeadAttention(HybridBlock):
@@ -203,19 +203,18 @@ class GatedFFN(HybridBlock):
 
 class GroupedQueryAttention(HybridBlock):
     """Causal self-attention on (batch, seq, units) with ``num_heads``
-    query heads over ``num_kv_heads`` key/value heads, no biases: RMSNorm
-    over the head dimension on q and k (one scale vector each, shared by
-    the heads) and, with ``gate`` (the default), a sigmoid gate on the
-    attention output from a fourth projection of the input,
-    ``(o * sigmoid(g)) Wo``.  What differs by layer is optional: rotary
-    embedding on q and k, and a causal ``window``.  The core is
-    ``ops.attention.multi_head_attention``: the flash kernels on a TPU,
-    the XLA composition elsewhere.
+    query heads over ``num_kv_heads`` key/value heads, no biases.  With
+    ``qk_norm`` (the default) RMSNorm over the head dimension on q and k,
+    one scale vector each; with ``gate`` (the default) a sigmoid gate on
+    the output from a fourth projection of the input, ``(o * sigmoid(g))
+    Wo``.  Optional by layer: rotary embedding on q and k, and a causal
+    ``window``.  The core is ``ops.attention.multi_head_attention``: the
+    flash kernels on a TPU, the XLA composition elsewhere.
     """
 
     def __init__(self, units, num_heads, num_kv_heads, head_dim=None,
                  window=None, rotary=False, rope_theta=10000.0,
-                 epsilon=1e-5, gate=True):
+                 epsilon=1e-5, gate=True, qk_norm=True):
         super().__init__()
         head_dim = units // num_heads if head_dim is None else head_dim
         if num_heads % num_kv_heads:
@@ -235,8 +234,9 @@ class GroupedQueryAttention(HybridBlock):
         if gate:
             self.gate_proj = proj(num_heads * head_dim)
         self.out_proj = proj(units)
-        self.q_norm = RMSNorm(epsilon, in_channels=head_dim)
-        self.k_norm = RMSNorm(epsilon, in_channels=head_dim)
+        norm = (lambda: RMSNorm(epsilon, in_channels=head_dim)) if qk_norm \
+            else Identity                     # no norm, no parameter
+        self.q_norm, self.k_norm = norm(), norm()
 
     def _heads_normed(self, t, norm, heads):
         b, s, _ = t.shape

@@ -235,6 +235,13 @@ declare_metric("moe.rows_bound_total", "counter",
                "static bound on the rows a RoutedExperts layer hands its "
                "held experts, once per traced call (what the data really "
                "sent is the layer's expert_load / rows_over aux state)")
+declare_metric("ssm.scan_tokens_total", "counter",
+               "tokens (batch x sequence) handed to ops.ssm.ssd_scan, "
+               "once per traced call")
+declare_metric("ssm.scan_chunks_total", "counter",
+               "chunks x heads of those calls: the (chunk, chunk) "
+               "triangular products one call makes (a sequence the chunk "
+               "does not divide counts its padded last chunk)")
 
 
 # -- switches ---------------------------------------------------------------
