@@ -26,9 +26,11 @@ What the kernel moves and visits (``mx_dsa_align``):
 * After the last head: ``p`` on the selected and causal entries, the
   tile's share of ``sum(xlogy(p, p) - p * logq)`` added to a per-query
   row ``(1, block_q)`` that stays resident along the row, and
-  ``d_scores`` written once, in the scores' own layout (the scores' tile
-  is turned on the way in, the gradient's on the way out: two
-  transposes a tile against ``heads`` products).
+  ``d_scores`` written once.  The scores, the selection and ``d_scores``
+  are all **key-major**, ``(batch, seq_k, seq_q)``, as the tiles are:
+  the layout ``mx_dsa_scores`` emits and XLA keeps the selection in, so
+  no tile is turned in the kernel and the step holds one layout of each
+  ``(seq, seq)`` array.
 * Causal tiles (``flash_attention.tile_counts``): a tile above the
   diagonal is *skipped* — its blocks not fetched, because the index maps
   clamp to the row's last tile with work — and its ``d_scores`` written
@@ -57,10 +59,10 @@ BLOCK = 512
 def _align_kernel(q_ref, k_ref, lse_ref, lse_i_ref, i_ref, sel_ref, d_ref,
                   kl_ref, acc_ref, *, block, seq, heads, group, scale,
                   tokens):
-    """One (q-block, k-block) tile for every head.  ``sel_ref`` is the
-    selection's tile key-major, ``i_ref`` / ``d_ref`` the scores' and
-    their gradient's query-major; ``kl_ref`` the q-block's per-query
-    row, revisited along the k-blocks."""
+    """One (q-block, k-block) tile for every head.  ``sel_ref``,
+    ``i_ref`` and ``d_ref`` are the selection's, the scores' and their
+    gradient's tiles, key-major; ``kl_ref`` the q-block's per-query row,
+    revisited along the k-blocks."""
     qi, kj = pl.program_id(1), pl.program_id(2)
     runs = _tile_runs(qi, kj, block, block, True)
 
@@ -83,29 +85,37 @@ def _align_kernel(q_ref, k_ref, lse_ref, lse_i_ref, i_ref, sel_ref, d_ref,
         valid = _attended(qi * block, kj * block, acc_ref.shape, 1, seq,
                           True) & _selected(sel_ref[0])
         p = jnp.where(valid, acc_ref[...] / heads, 0.0)
-        logq = i_ref[0].T - lse_i_ref[0]
+        logq = i_ref[0] - lse_i_ref[0]
         plogp = jnp.where(p > 0, p * jnp.log(jnp.where(p > 0, p, 1.0)), 0.0)
         kl_ref[0] += jnp.sum(plogp - jnp.where(valid, p * logq, 0.0),
                              axis=0, keepdims=True)
-        d_ref[0] = (jnp.where(valid, jnp.exp(logq) - p, 0.0) / tokens).T
+        d_ref[0] = jnp.where(valid, jnp.exp(logq) - p, 0.0) / tokens
 
     @pl.when(jnp.logical_not(runs))
     def _empty():
         d_ref[0] = jnp.zeros_like(d_ref[0])
 
 
-def align_pass(scores, selection, q, k, lse, tokens, interpret=False,
-               block=None):
-    """scores (b, s, s) float32, selection (b, s, s) int8 (within the
-    causal triangle), q (b, heads, s, d), k (b, kv_heads, s, d), lse
-    (b, heads, s) float32: each head's log-sum-exp of ``q . k *
-    d**-0.5`` over the selected keys.  Returns (per-query KL (b, 1, s),
-    ``d_scores`` (b, s, s)), the latter divided by ``tokens``."""
-    b, heads, s, d = q.shape
-    kv_heads = k.shape[1]
+def tile_block(s, block=None):
+    """Queries and keys a tile at sequence ``s``: ``BLOCK`` (or the
+    caller's), clamped to the sequence, which it has to divide."""
     block = min(BLOCK if block is None else block, s)
     if s % block:
         raise ValueError(f"sequence {s} is no multiple of the block {block}")
+    return block
+
+
+def align_pass(scores, selection, q, k, lse, tokens, interpret=False,
+               block=None):
+    """scores float32 and selection int8 (within the causal triangle),
+    both **key-major** (b, s_k, s_q); q (b, heads, s, d), k (b,
+    kv_heads, s, d), lse (b, heads, s) float32: each head's log-sum-exp
+    of ``q . k * d**-0.5`` over the selected keys.  Returns (per-query
+    KL (b, 1, s), ``d_scores`` key-major (b, s_k, s_q)), the latter
+    divided by ``tokens``."""
+    b, heads, s, d = q.shape
+    kv_heads = k.shape[1]
+    block = tile_block(s, block)
     if _telemetry._active:
         _count_tiles(("dsa_align",), b, s, s, block, block, True)
     scale = 1.0 / (d ** 0.5)
@@ -114,8 +124,8 @@ def align_pass(scores, selection, q, k, lse, tokens, interpret=False,
         q, k = jnp.pad(q, pad), jnp.pad(k, pad)
         d = q.shape[-1]
     lse_i = jax.nn.logsumexp(
-        jnp.where(selection != 0, scores, -1e30), axis=-1)[:, None, :]
-    sel = jnp.swapaxes(selection.astype(jnp.int8), 1, 2)     # key-major
+        jnp.where(selection != 0, scores, -1e30), axis=1, keepdims=True)
+    sel = selection.astype(jnp.int8)
     n = s // block
 
     def last(a, c):     # square tiles: q-block ``a`` reaches k-block ``a``
@@ -127,6 +137,8 @@ def align_pass(scores, selection, q, k, lse, tokens, interpret=False,
     # accumulator and a tile's float32 temporaries
     resident = 2 * ((heads + kv_heads) * block * d * q.dtype.itemsize
                     + heads * 8 * block * 4 + block * block * 9)
+    # a key-major tile of the scores, and of the selection
+    tile = pl.BlockSpec((1, block, block), lambda i, a, c: (i, last(a, c), a))
     d_scores, kl = pl.pallas_call(
         functools.partial(_align_kernel, block=block, seq=s, heads=heads,
                           group=heads // kv_heads, scale=scale,
@@ -138,13 +150,11 @@ def align_pass(scores, selection, q, k, lse, tokens, interpret=False,
                          lambda i, a, c: (i, 0, last(a, c), 0)),
             pl.BlockSpec((1, heads, 1, block), lambda i, a, c: (i, 0, 0, a)),
             pl.BlockSpec((1, 1, block), lambda i, a, c: (i, 0, a)),
-            pl.BlockSpec((1, block, block),
-                         lambda i, a, c: (i, a, last(a, c))),
-            pl.BlockSpec((1, block, block),
-                         lambda i, a, c: (i, last(a, c), a)),
+            tile,
+            tile,
         ],
         out_specs=[
-            pl.BlockSpec((1, block, block), lambda i, a, c: (i, a, c)),
+            pl.BlockSpec((1, block, block), lambda i, a, c: (i, c, a)),
             pl.BlockSpec((1, 1, block), lambda i, a, c: (i, 0, a)),
         ],
         out_shape=[

@@ -7,9 +7,21 @@ arrays, composed by ``nn.IndexedAttention``:
 
 * ``index_scores``: ``I[t, s] = sum_j w[t, j] * relu(qI[t, j] . kI[s]) /
   sqrt(d)`` for ``j`` over the indexer's heads.  The per-head products
-  are ``(heads, seq, seq)``; they exist a block of queries at a time
-  (``lax.map``), each block made again in the backward pass, so only the
-  ``(seq, seq)`` sum is ever whole.
+  are ``(heads, seq, seq)``; only the ``(seq, seq)`` sum is ever whole.
+  Which pass runs is decided by what the call can see.  On a TPU, at a
+  sequence of whole 512-blocks (and a head count and width whose
+  q-block VMEM holds), it is the Pallas kernels ``mx_dsa_scores`` and,
+  backward, ``mx_dsa_scores_bwd`` (``ops/pallas/dsa_scores.py``): a
+  tile's per-head products live in VMEM only, tiles above the diagonal
+  are skipped, and the scores are emitted **key-major** — the layout
+  XLA gives their every consumer here (the top-k's counts and the
+  log-sum-exp reduce over the keys; the selection is key-major too), so
+  the ``swapaxes`` round the kernels are layouts and the step holds no
+  second copy of a ``(seq, seq)`` array.  Everywhere else — the CPU, the
+  tier-1 tests, short or ragged sequences — it is the XLA composition
+  ``_composed_scores``: the products exist a block of queries at a time
+  (``lax.map``), each block made again in the backward pass; it is also
+  the kernels' oracle.
 * ``select_topk``: for each query the ``topk`` positions ``s <= t`` with
   the largest scores (all of them while ``t < topk``), ties to the lower
   ``s``, as an int8 mask.  No sort: the ``topk``-th largest score of a
@@ -74,11 +86,30 @@ def _unblocked(t):
 
 def index_scores(q_idx, k_idx, weights):
     """q_idx (b, s, heads, d), k_idx (b, s, d), weights (b, s, heads) ->
-    scores (b, s, s) float32; entries above the diagonal are computed
-    and mean nothing.  The products take their operands in the type they
-    come in and accumulate in float32; everything after is float32."""
+    scores (b, s, s) float32; entries above the diagonal mean nothing
+    (the composition computes them, the kernels write zeros in the tiles
+    wholly above it).  The products take their operands in the type they
+    come in and accumulate in float32; everything after is float32.
+
+    Which pass runs is decided by what the call can see: on a TPU, at a
+    sequence ``ops/pallas/dsa_scores.py``'s blocks divide into and a
+    head count and width whose q-block it can hold, the Pallas kernels;
+    everywhere else the XLA composition, which is also their oracle."""
+    from .. import runtime
+    from .pallas import dsa_scores
     b, s, heads, d = q_idx.shape
     weights = weights.astype(jnp.float32) * (1.0 / math.sqrt(d))
+    if runtime.on_tpu() and dsa_scores.fits(s, heads, d,
+                                            q_idx.dtype.itemsize):
+        return _kernel_scores(q_idx, k_idx, weights)
+    return _composed_scores(q_idx, k_idx, weights)
+
+
+def _composed_scores(q_idx, k_idx, weights):
+    """The XLA composition (and the kernels' oracle): the per-head
+    products a block of queries at a time, each block made again in the
+    backward pass.  ``weights`` float32, the scale in them."""
+    b, s, heads, d = q_idx.shape
     block = _query_block(s, heads)
 
     @jax.checkpoint
@@ -90,6 +121,69 @@ def index_scores(q_idx, k_idx, weights):
 
     return _unblocked(jax.lax.map(
         one, (_blocked(q_idx, block), _blocked(weights, block))))
+
+
+def _batch_over_dp(kernel, *operands):
+    """``kernel(*operands)``, every operand and result with the batch
+    first.  GSPMD cannot partition a Mosaic call, so under a mesh it is
+    a ``shard_map`` with the batch over 'dp' (``ops/attention.py::
+    _flash``); the indexer's and the attention's heads are summed inside
+    the kernels, so they stay whole on every device."""
+    from .attention import _kernel_mesh, _mesh_axis
+    mesh = _kernel_mesh()
+    if mesh is None:
+        return kernel(*operands)
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+    spec = P(_mesh_axis(mesh, "dp", operands[0].shape[0]))
+    return shard_map(kernel, mesh=mesh, in_specs=spec, out_specs=spec,
+                     check_vma=False)(*operands)
+
+
+def _heads_first(t):
+    """(b, s, heads, ...) <-> (b, heads, s, ...)."""
+    return jnp.swapaxes(t, 1, 2)
+
+
+def _scores_kernel_pass(q_idx, k_idx, weights):
+    """``_composed_scores`` by ``ops/pallas/dsa_scores.py``.  The kernel
+    emits the scores key-major; the ``swapaxes`` is a layout to XLA."""
+    from .. import runtime
+    from .pallas.dsa_scores import scores_pass
+
+    def kernel(q, k, w):
+        return scores_pass(_heads_first(q), k, _heads_first(w),
+                           interpret=runtime.pallas_interpret())
+
+    return jnp.swapaxes(_batch_over_dp(kernel, q_idx, k_idx, weights), 1, 2)
+
+
+_kernel_scores = jax.custom_vjp(_scores_kernel_pass)
+
+
+def _kernel_scores_fwd(q_idx, k_idx, weights):
+    return (_scores_kernel_pass(q_idx, k_idx, weights),
+            (q_idx, k_idx, weights))
+
+
+def _kernel_scores_bwd(res, g):
+    from .. import runtime
+    from .pallas.dsa_scores import scores_bwd_pass
+    q_idx, k_idx, weights = res
+
+    def kernel(q, k, w, g_t):
+        dq, dk, dw = scores_bwd_pass(_heads_first(q), k, _heads_first(w),
+                                     g_t, interpret=runtime.pallas_interpret())
+        return (_heads_first(dq).astype(q.dtype), dk.astype(k.dtype),
+                _heads_first(dw))
+
+    # traced under the caller's scopes, ``transpose(jvp(...))`` round
+    # them, like any other backward operation
+    return _batch_over_dp(kernel, q_idx, k_idx, weights,
+                          jnp.swapaxes(g, 1, 2))
+
+
+_kernel_scores.defvjp(_kernel_scores_fwd, _kernel_scores_bwd)
 
 
 def _ordered_key(x):
@@ -207,35 +301,26 @@ def _align_pass(scores, selection, q, k, heads, kv_heads):
 
 
 def _align_kernel_pass(scores, selection, q, k, lse, heads, kv_heads):
-    """The same by ``ops/pallas/dsa_align.py``.  GSPMD cannot partition
-    a Mosaic call, so under a mesh it is a ``shard_map`` with the batch
-    over 'dp' (``ops/attention.py::_flash``); a query's heads are summed
-    inside the kernel, so they stay whole on every device."""
+    """The same by ``ops/pallas/dsa_align.py``, which takes the scores
+    and the selection and returns ``d_scores`` key-major: the layout
+    ``mx_dsa_scores`` emits and XLA keeps the selection in, so each
+    ``swapaxes`` here is a layout and not a copy."""
     from .. import runtime
-    from .attention import _kernel_mesh, _mesh_axis
     from .pallas.dsa_align import align_pass
     tokens = q.shape[0] * q.shape[1]
     d = q.shape[2] // heads
 
-    def kernel(i, m, q_, k_, l):
+    def kernel(i_t, m_t, q_, k_, l):
         def split(t, n):        # (b, s, n*d) -> (b, n, s, d)
             return t.reshape(*t.shape[:2], n, d).transpose(0, 2, 1, 3)
 
-        return align_pass(i, m, split(q_, heads), split(k_, kv_heads), l,
+        return align_pass(i_t, m_t, split(q_, heads), split(k_, kv_heads), l,
                           tokens, interpret=runtime.pallas_interpret())
 
-    operands = (scores, selection, q, k, lse)
-    mesh = _kernel_mesh()
-    if mesh is None:
-        kl, d_scores = kernel(*operands)
-    else:
-        from jax import shard_map
-        from jax.sharding import PartitionSpec as P
-        spec = P(_mesh_axis(mesh, "dp", q.shape[0]), None, None)
-        kl, d_scores = shard_map(
-            kernel, mesh=mesh, in_specs=(spec,) * 5, out_specs=(spec, spec),
-            check_vma=False)(*operands)
-    return jnp.sum(kl) / tokens, d_scores
+    kl, d_scores = _batch_over_dp(
+        kernel, jnp.swapaxes(scores, 1, 2), jnp.swapaxes(selection, 1, 2),
+        q, k, lse)
+    return jnp.sum(kl) / tokens, jnp.swapaxes(d_scores, 1, 2)
 
 
 def _pass(scores, selection, q, k, lse, heads, kv_heads):

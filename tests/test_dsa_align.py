@@ -33,6 +33,14 @@ def _split(t, n):
     return t.reshape(b, s, n, nd // n).transpose(0, 2, 1, 3)
 
 
+def _align_pass(scores, sel, *rest, **kw):
+    """``dsa_align.align_pass`` on query-major scores and selection: the
+    kernel takes and returns them key-major."""
+    kl, d = dsa_align.align_pass(jnp.swapaxes(scores, 1, 2),
+                                 jnp.swapaxes(sel, 1, 2), *rest, **kw)
+    return kl, jnp.swapaxes(d, 1, 2)
+
+
 def _plain_lse(sel, q, k, h, hk):
     """Each head's log-sum-exp over the selected keys, written plainly."""
     d = q.shape[-1] // h
@@ -62,7 +70,7 @@ def test_kernel_is_the_composition(b, s, h, hk, d, topk, block):
         set(min(t + 1, topk) for t in range(s)))
     with jax.default_matmul_precision("highest"):
         want, want_d = sparse_index._align_pass(scores, sel, q, k, h, hk)
-        kl, got_d = dsa_align.align_pass(
+        kl, got_d = _align_pass(
             scores, sel, _split(q, h), _split(k, hk),
             _plain_lse(sel, q, k, h, hk), b * s, interpret=True,
             block=block)
@@ -79,7 +87,7 @@ def test_bf16_operands_take_one_mxu_product_a_head():
     the same operands."""
     scores, sel, q, k = _operands(2, 64, 8, 2, 16, 24, dtype=jnp.bfloat16)
     want, want_d = sparse_index._align_pass(scores, sel, q, k, 8, 2)
-    kl, got_d = dsa_align.align_pass(
+    kl, got_d = _align_pass(
         scores, sel, _split(q, 8), _split(k, 2),
         _plain_lse(sel, q, k, 8, 2), 128, interpret=True, block=16)
     onp.testing.assert_allclose(jnp.sum(kl) / 128, want, rtol=1e-5)
@@ -89,9 +97,9 @@ def test_bf16_operands_take_one_mxu_product_a_head():
 def test_a_sequence_the_blocks_do_not_divide_is_refused():
     scores, sel, q, k = _operands(1, 48, 2, 1, 8, 5)
     with pytest.raises(ValueError, match="no multiple of the block"):
-        dsa_align.align_pass(scores, sel, _split(q, 2), _split(k, 1),
-                             _plain_lse(sel, q, k, 2, 1), 48,
-                             interpret=True, block=32)
+        _align_pass(scores, sel, _split(q, 2), _split(k, 1),
+                    _plain_lse(sel, q, k, 2, 1), 48, interpret=True,
+                    block=32)
 
 
 # -- the statistics the forward flash kernel hands out ----------------------
@@ -231,7 +239,7 @@ def test_indexed_attention_takes_the_kernel_where_the_core_took_its_own(
     """``nn.IndexedAttention`` on the TPU's routes (kernels interpreted)
     against itself on the CPU's: output, loss, and every leaf's gradient
     — the indexer's from ``L_I`` through ``mx_dsa_align``'s
-    ``d_scores``."""
+    ``d_scores`` and ``mx_dsa_scores_bwd``."""
     from mxnet_tpu import functional
     net = _indexed_attention()
     x = jnp.asarray(onp.random.RandomState(1).randn(2, 32, 32), jnp.float32)
@@ -250,7 +258,8 @@ def test_indexed_attention_takes_the_kernel_where_the_core_took_its_own(
         names = [e.params["name"] for e in _pallas_calls(
             jax.make_jaxpr(jax.grad(lambda p: loss(p)[0]))(params).jaxpr)]
         (got, got_li), g_got = jax.value_and_grad(loss, has_aux=True)(params)
-    assert sorted(names) == ["mx_dsa_align", "mx_flash_bwd_dkv",
+    assert sorted(names) == ["mx_dsa_align", "mx_dsa_scores",
+                             "mx_dsa_scores_bwd", "mx_flash_bwd_dkv",
                              "mx_flash_bwd_dq", "mx_flash_fwd"]
     assert float(want_li) > 0
     onp.testing.assert_allclose(got_li, want_li, rtol=1e-5)

@@ -3,7 +3,7 @@
 Interpret-mode tests (the CPU suite) say a kernel's arithmetic is right;
 only libtpu's Mosaic compiler says whether it fits VMEM, whether its
 layouts and its int8/fp8 dots are accepted on this ``device_kind``.  This
-script compiles each of the six kernels with ``interpret=False`` at the
+script compiles each of the seven kernels with ``interpret=False`` at the
 shapes the model zoo uses and the static blocks the device gets
 (``autotune.kernels._STATIC_DEFAULTS``), and checks numerics against a
 plain-jnp reference:
@@ -12,6 +12,8 @@ plain-jnp reference:
                            grouped KV heads and a causal window
     dsa_align              the sparse indexer's loss and its gradient,
                            against the XLA composition
+    dsa_scores             the sparse indexer's scores and their three
+                           gradients, against the XLA composition
     ln_residual            fwd + bwd, bf16 and fp32
     quantized_matmul       int8 x int8 -> int32
     fp8_matmul             e4m3 and e5m2
@@ -230,6 +232,52 @@ def _dsa_align_case(B, H, KV, S, D, topk, dtype):
             f"{jnp.dtype(dtype).name}"), check
 
 
+def _dsa_scores_case(B, H, S, D, dtype):
+    """``index_scores`` as the chip takes it (``mx_dsa_scores`` and
+    ``mx_dsa_scores_bwd``) against the XLA composition it replaces (its
+    oracle): every causal pair's score, and ``dqI``, ``dkI``, ``dw`` for
+    a cotangent on under half of the causal pairs."""
+    def check():
+        from mxnet_tpu.ops import sparse_index
+        from mxnet_tpu.ops.pallas import dsa_scores
+        assert dsa_scores.fits(S, H, D, jnp.dtype(dtype).itemsize)
+        ks = jax.random.split(jax.random.PRNGKey(4), 5)
+        q = jax.random.normal(ks[0], (B, S, H, D), dtype)
+        k = jax.random.normal(ks[1], (B, S, D), dtype)
+        w = jax.random.normal(ks[2], (B, S, H), jnp.float32) / H ** 0.5
+        causal = jnp.tril(jnp.ones((S, S), bool))
+        g = jnp.where(causal & (jax.random.uniform(ks[3], (B, S, S)) < 0.44),
+                      jax.random.normal(ks[4], (B, S, S), jnp.float32), 0.0)
+
+        def kernels(q, k, w):
+            return jnp.where(causal, sparse_index.index_scores(q, k, w), 0.0)
+
+        def composed(q, k, w):
+            return jnp.where(causal, sparse_index._composed_scores(
+                q, k, w * (1.0 / D ** 0.5)), 0.0)
+
+        def both(f):
+            out, vjp = jax.vjp(f, q, k, w)
+            return out, vjp(g)
+
+        got, grads = jax.jit(lambda: both(kernels))()
+        # float32 operands: XLA's default on the chip is one bf16 pass,
+        # Mosaic's several; the oracle is the composition at ``highest``
+        with jax.default_matmul_precision("highest"):
+            want, refs = jax.jit(lambda: both(composed))()
+        res = {"scores_relerr": _relerr([got], [want]),
+               "dq_relerr": _relerr(grads[:1], refs[:1]),
+               "dk_relerr": _relerr(grads[1:2], refs[1:2]),
+               "dw_relerr": _relerr(grads[2:], refs[2:])}
+        # bf16: the composition hands dq and dk back in bf16 and sums
+        # dk's query blocks in it; the kernels accumulate in float32
+        tol = 3e-2 if dtype == jnp.bfloat16 else 2e-3
+        assert res["scores_relerr"] < 1e-4 and res["dw_relerr"] < 1e-3 \
+            and res["dq_relerr"] < tol and res["dk_relerr"] < tol, res
+        return res
+    return f"dsa_scores b{B}h{H}s{S}d{D} {jnp.dtype(dtype).name}", check
+
+
 def _conv_case(N, H, W, Cin, Cout):
     def check():
         from mxnet_tpu.ops.pallas_conv_bwd import (conv3x3_bn_relu_ref,
@@ -280,6 +328,10 @@ def cases():
     # cell's length, and a batch of shorter float32 rows
     out.append(_dsa_align_case(1, 32, 4, 8192, 128, 2048, bf16))
     out.append(_dsa_align_case(2, 8, 2, 1024, 128, 256, f32))
+    # the indexer's scores: the cell's 16 heads of 64 against one key
+    # head, and a batch of shorter float32 rows
+    out.append(_dsa_scores_case(1, 16, 8192, 64, bf16))
+    out.append(_dsa_scores_case(2, 4, 1024, 64, f32))
     for dtype in (bf16, f32):
         out.append(_ln_case(32 * 128, 768, dtype))       # BERT-base bs32
     out.append(_int8_case(1024, 3072, 768, "gelu"))      # GPT-2 FFN up
