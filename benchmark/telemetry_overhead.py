@@ -9,7 +9,10 @@ measures that cost against a tight eager-op loop and fails if the probes
 add more than the budget (default 2%) — the guard that keeps future
 instrumentation honest.  The trace-enabled path is also measured and
 reported (informational: enabling tracing is a deliberate choice, only
-the disabled paths are gated).
+the disabled paths are gated).  So is ``mx.trace.span()`` with the
+recorder off: once the start-up record is full it may cost no more than
+the budget over the bare ``TraceAnnotation`` it was before the record
+existed; the cost of one of the record's 256 kept spans is reported.
 
 Method: time a tight eager add loop (N ops, synced once) as the
 baseline, then the same loop with K extra disabled probes per iteration
@@ -139,6 +142,37 @@ def _goodput_loop(a, n, probes_per_op, goodput):
     return time.perf_counter() - t0
 
 
+def _span_loop(a, n, probes_per_op, make):
+    """Same shape, one span entered and left per probe, recorder off:
+    ``make`` is ``mx.trace.span`` with the start-up record full, or the
+    bare ``_Annotation`` that was all of the off path before the record."""
+    t0 = time.perf_counter()
+    out = a
+    probe = range(probes_per_op)
+    for _ in range(n):
+        out = out + a
+        for _ in probe:
+            with make("bench.op"):
+                pass
+    out._data.block_until_ready()
+    return time.perf_counter() - t0
+
+
+def _kept_span_ns(trace):
+    """ns for one of the start-up record's kept spans (recorder off): the
+    record is emptied for the timing and put back after it."""
+    kept, room = trace._startup[:], trace._startup_room
+    trace._startup[:], trace._startup_room = [], trace.STARTUP_SPANS
+    try:
+        t0 = time.perf_counter()
+        for _ in range(trace.STARTUP_SPANS):
+            with trace.span("bench.op"):
+                pass
+        return (time.perf_counter() - t0) / trace.STARTUP_SPANS * 1e9
+    finally:
+        trace._startup[:], trace._startup_room = kept, room
+
+
 def _trace_enabled_loop(a, n, trace):
     """Eager loop with one real recorded span per op (tracing ON)."""
     t0 = time.perf_counter()
@@ -170,7 +204,13 @@ def run(n=2000, probes_per_op=32, repeats=7, budget=0.02):
     resolve_blocks("flash_attention", (256, 256, 64))  # static table fill
     base_s, probed_s, tprobed_s, bprobed_s = [], [], [], []
     rprobed_s, sprobed_s, gprobed_s, fprobed_s, ton_s = [], [], [], [], []
+    bare_s, full_s, kept_ns = [], [], []
+    trace._startup_room = 0             # span() past the record's bound
     for _ in range(repeats):
+        bare_s.append(_span_loop(
+            a, n, probes_per_op, lambda name: trace._Annotation("mx/" + name)))
+        full_s.append(_span_loop(a, n, probes_per_op, trace.span))
+        kept_ns.append(_kept_span_ns(trace))
         base_s.append(_loop(a, n, 0, telemetry))
         probed_s.append(_loop(a, n, probes_per_op, telemetry))
         tprobed_s.append(_trace_loop(a, n, probes_per_op, trace))
@@ -192,6 +232,8 @@ def run(n=2000, probes_per_op=32, repeats=7, budget=0.02):
     gprobed = statistics.median(gprobed_s)
     fprobed = statistics.median(fprobed_s)
     ton = statistics.median(ton_s)
+    bare = statistics.median(bare_s)
+    full = statistics.median(full_s)
     # cost of the K probes, scaled to the ~1 probe a real dispatch adds
     per_probe = max(0.0, probed - base) / probes_per_op
     per_trace_probe = max(0.0, tprobed - base) / probes_per_op
@@ -207,6 +249,8 @@ def run(n=2000, probes_per_op=32, repeats=7, budget=0.02):
     stream_ratio = per_stream_probe / base
     goodput_ratio = per_goodput_probe / base
     servefleet_ratio = per_servefleet_probe / base
+    # what span() costs with the recorder off over the bare annotation
+    span_ratio = max(0.0, full - bare) / probes_per_op / base
     return {"ops": n, "probes_per_op": probes_per_op, "repeats": repeats,
             "baseline_s": round(base, 6), "probed_s": round(probed, 6),
             "trace_probed_s": round(tprobed, 6),
@@ -237,11 +281,17 @@ def run(n=2000, probes_per_op=32, repeats=7, budget=0.02):
             "goodput_overhead_ratio": round(goodput_ratio, 6),
             "servefleet_overhead_ratio": round(servefleet_ratio, 6),
             "trace_enabled_ratio": round(max(0.0, ton - base) / base, 6),
+            "span_off_bare_annotation_ns":
+                round(max(0.0, bare - base) / probes_per_op / n * 1e9, 2),
+            "span_off_record_full_ns":
+                round(max(0.0, full - base) / probes_per_op / n * 1e9, 2),
+            "span_off_record_kept_ns": round(statistics.median(kept_ns), 2),
+            "span_off_overhead_ratio": round(span_ratio, 6),
             "budget": budget,
             "ok": ratio < budget and trace_ratio < budget
                   and blackbox_ratio < budget and resolve_ratio < budget
                   and stream_ratio < budget and goodput_ratio < budget
-                  and servefleet_ratio < budget}
+                  and servefleet_ratio < budget and span_ratio < budget}
 
 
 def main(argv=None):
@@ -297,13 +347,20 @@ def main(argv=None):
         print(f"servefleet overhead ratio "
               f"{r['servefleet_overhead_ratio'] * 100:9.4f} % "
               f"(budget {r['budget'] * 100:g}%)")
+        print(f"span() recorder off: bare annotation "
+              f"{r['span_off_bare_annotation_ns']:.0f} ns, start-up record "
+              f"full {r['span_off_record_full_ns']:.0f} ns, one of its "
+              f"256 kept spans {r['span_off_record_kept_ns']:.0f} ns")
+        print(f"span() off-path ratio    "
+              f"{r['span_off_overhead_ratio'] * 100:9.4f} % "
+              f"(over the bare annotation; budget {r['budget'] * 100:g}%)")
     if not r["ok"]:
         print("FAIL: a disabled observability fast path exceeds the "
               "overhead budget", file=sys.stderr)
         return 1
     print("OK: disabled telemetry + trace + blackbox + untuned "
-          "resolve_blocks + stream + goodput + servefleet fast paths "
-          "within budget")
+          "resolve_blocks + stream + goodput + servefleet fast paths and "
+          "span() past the start-up record within budget")
     return 0
 
 

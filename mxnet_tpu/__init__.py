@@ -18,6 +18,8 @@ Layer map vs the reference (see SURVEY.md):
   - KVStore (src/kvstore/)                  -> XLA collectives on a device mesh
   - src/operator/** kernels                 -> jnp/lax lowering + Pallas kernels
 """
+import time as _time
+_T_IMPORT = _time.perf_counter_ns()  # first, so the span holds every module
 
 __version__ = "2.0.0a1"
 
@@ -117,3 +119,11 @@ def waitall():
     undelivered jax.Arrays; the engine module tracks live arrays.
     """
     engine.wait_all()
+
+
+# the package's import as the first span of mx.trace.startup()
+import sys as _sys
+trace.emit("import", _T_IMPORT // 1000,
+           (_time.perf_counter_ns() - _T_IMPORT) // 1000, category="startup",
+           modules=sum(1 for _m in _sys.modules
+                       if _m == __name__ or _m.startswith(__name__ + ".")))

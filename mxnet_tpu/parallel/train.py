@@ -41,6 +41,14 @@ def _unwrap(tree):
         is_leaf=lambda x: isinstance(x, ndarray))
 
 
+def _leaves_and_bytes(tree):
+    """A placed tree's leaf count and global bytes, from shapes and item
+    sizes alone (no device call): what the placement spans carry."""
+    leaves = jax.tree_util.tree_leaves(tree)
+    return {"leaves": len(leaves),
+            "bytes": sum(l.size * l.dtype.itemsize for l in leaves)}
+
+
 class FunctionalOptimizer:
     """Pure-functional adapter over a mxnet_tpu Optimizer instance so its
     update rule can run inside a jit/pjit trace (the analog of the fused
@@ -161,6 +169,17 @@ class ShardedTrainStep:
                  n_labels=1, param_specs=None, donate=True,
                  steps_per_call=1, zero=0, grad_accum=1, remat=None,
                  dp_axis="dp", precision="fp32", grad_compress=None):
+        # construction is one host span, ``mx/train.init``, holding
+        # ``mx/train.plan``, ``mx/train.place`` and ``mx/train.states``;
+        # mx.trace.startup() keeps it with their counts
+        with _trace.span("train.init", category="train"):
+            self._init(block, loss_fn, optimizer, mesh, batch_specs,
+                       n_labels, param_specs, donate, steps_per_call, zero,
+                       grad_accum, remat, dp_axis, precision, grad_compress)
+
+    def _init(self, block, loss_fn, optimizer, mesh, batch_specs, n_labels,
+              param_specs, donate, steps_per_call, zero, grad_accum, remat,
+              dp_axis, precision, grad_compress):
         from ..optimizer import optimizer as opt_mod
         from ..gluon.block import resolve_remat_policy, _REMAT_OFF
         if isinstance(optimizer, str):
@@ -258,37 +277,44 @@ class ShardedTrainStep:
                 "ZeRO shards — use zero=0")
 
         # -- place: parameters, optimizer state, extra state --
+        # (both spans count from shapes: nothing here waits for the device)
         sh = self._sh
-        self.trainable = {
-            n: jax.device_put(v, sh(lay.param_spec(n)))
-            for n, v in lay.stack(trainable).items()}
-        self.aux = {
-            n: jax.device_put(v, sh(lay.param_spec(n)))
-            for n, v in lay.stack(aux).items()}
-        self.states = {}
-        for n, v in self.trainable.items():
-            leaf = lay.leaves[n]
-            s = self.fopt.init(n, lay.to_state_form(n, v))
-            bad = [l.shape for l in jax.tree_util.tree_leaves(s)
-                   if l.shape != leaf.state_shape]
-            if bad and leaf.form != PARAM:
-                raise MXNetError(
-                    f"{type(self.fopt.opt).__name__} state for '{n}' is not "
-                    f"elementwise (leaf shapes {bad}); zero>0 unsupported")
-            self.states[n] = jax.device_put(s, sh(leaf.state_spec))
-        self._fp8_margin = 1.0
-        fp8_state = jax.device_put(_fp8.init_state(lay.fp8_sites), sh(P()))
-        if self._fp8:
-            self._fp8_margin = float(_config.get("amp.fp8_margin"))
-            # serve-side engines key quantization guards off this tag
-            # (it also rides save_states metadata for cold loads)
-            block._fp8_trained = True
-        resid_state = jax.device_put(
-            {b: jnp.zeros(shape, jnp.float32)
-             for b, shape in lay.resid_shapes.items()}, sh(P(dp_axis)))
-        self.extra = {"fp8": fp8_state, "resid": resid_state}
-        if lay.names(FLAT):
-            self._build_zero_update()
+        with _trace.span("train.place", category="train") as place:
+            self.trainable = {
+                n: jax.device_put(v, sh(lay.param_spec(n)))
+                for n, v in lay.stack(trainable).items()}
+            self.aux = {
+                n: jax.device_put(v, sh(lay.param_spec(n)))
+                for n, v in lay.stack(aux).items()}
+            place.set(**_leaves_and_bytes((self.trainable, self.aux)))
+        with _trace.span("train.states", category="train") as placed:
+            self.states = {}
+            for n, v in self.trainable.items():
+                leaf = lay.leaves[n]
+                s = self.fopt.init(n, lay.to_state_form(n, v))
+                bad = [l.shape for l in jax.tree_util.tree_leaves(s)
+                       if l.shape != leaf.state_shape]
+                if bad and leaf.form != PARAM:
+                    raise MXNetError(
+                        f"{type(self.fopt.opt).__name__} state for '{n}' is "
+                        f"not elementwise (leaf shapes {bad}); zero>0 "
+                        "unsupported")
+                self.states[n] = jax.device_put(s, sh(leaf.state_spec))
+            self._fp8_margin = 1.0
+            fp8_state = jax.device_put(
+                _fp8.init_state(lay.fp8_sites), sh(P()))
+            if self._fp8:
+                self._fp8_margin = float(_config.get("amp.fp8_margin"))
+                # serve-side engines key quantization guards off this tag
+                # (it also rides save_states metadata for cold loads)
+                block._fp8_trained = True
+            resid_state = jax.device_put(
+                {b: jnp.zeros(shape, jnp.float32)
+                 for b, shape in lay.resid_shapes.items()}, sh(P(dp_axis)))
+            self.extra = {"fp8": fp8_state, "resid": resid_state}
+            if lay.names(FLAT):
+                self._build_zero_update()
+            placed.set(**_leaves_and_bytes((self.states, self.extra)))
 
         # -- build: the step, then its accumulate / steps_per_call wrappers --
         def base_step(trainable, aux, states, extra, rng, lr, t, *batch):

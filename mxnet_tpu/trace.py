@@ -42,6 +42,21 @@ Design points, mirroring the rest of the observability plane:
   backward ``transpose(jvp(mx.fwd))``, ``mx.optimizer``, ``mx.attn``).
   Spans nest as the profiler nests them: on one thread, a span's parent
   is the span whose interval contains it.
+- **Start-up record.** The first ``STARTUP_SPANS`` (256) spans a process
+  finishes are kept whether or not the recorder or a profiler is on:
+  name, start and end on ``profiler.now_us()`` (``perf_counter``: the
+  clock of ``_compile_cache.report()``'s ``at``), thread and ``.set()``
+  counts.  ``startup()`` returns them oldest first, each with the span
+  that contains it as ``parent`` — where a slow start went, asked of the
+  program itself: ``import`` (every ``mxnet_tpu.*`` module), then
+  ``train.init`` round ``train.plan``, ``train.place`` and
+  ``train.states``, then the first ``train.call``, whose
+  ``train.dispatch`` is the wall time of trace + lower + load.  Past
+  256 a disabled ``span()`` is a bare annotation again, after one
+  integer comparison; a program that fetches hundreds of arrays
+  (``ndarray.asnumpy``) before it builds its step fills the record with
+  those.  ``clear()`` leaves it: it describes the process, not a
+  session.
 """
 from __future__ import annotations
 
@@ -61,7 +76,7 @@ from . import telemetry as _telemetry
 __all__ = ["enable", "disable", "active", "configure", "span", "begin",
            "emit", "make_span", "ingest", "current_context", "adopt",
            "attach", "spans", "clear", "stats", "export", "clock_us",
-           "SpanHandle"]
+           "SpanHandle", "STARTUP_SPANS", "startup"]
 
 _telemetry.declare_metric(
     "trace.dropped_total", "counter",
@@ -81,6 +96,11 @@ _tls = threading.local()
 #: shared monotonic clock (μs) — the profiler's epoch, valid across
 #: processes on Linux (CLOCK_MONOTONIC is system-wide).
 clock_us = _profiler.now_us
+
+#: how many spans of a process the start-up record keeps
+STARTUP_SPANS = 256
+_startup = []                   # (name, start_us, end_us, thread, attrs)
+_startup_room = STARTUP_SPANS   # the gate: slots the record has left
 
 
 def _new_id():
@@ -155,14 +175,74 @@ def _finish(name, category, start_us, dur_us, trace_id, span_id,
                                dur_us, dict(attrs) if attrs else None)
 
 
+def _keep(name, start_us, end_us, attrs):
+    """One finished span into the start-up record, while it has room."""
+    global _startup_room
+    with _lock:
+        if _startup_room > 0:
+            _startup_room -= 1
+            _startup.append((name, start_us, end_us, threading.get_ident(),
+                             attrs))
+
+
+def startup():
+    """The start-up record: the first ``STARTUP_SPANS`` spans this
+    process finished (``span()`` and ``emit()``, recorder on or off),
+    oldest first, as ``{"name", "start_s", "end_s", "thread", "parent",
+    "attrs"}``.  Times are ``time.perf_counter()`` seconds; ``parent`` is
+    the index, in this list, of the innermost kept span of the same
+    thread whose interval contains the span (None at the top, and never
+    a span of another thread)."""
+    with _lock:
+        kept = list(_startup)
+    # a container sorts before what it holds; of two equal intervals the
+    # one finished later is the outer
+    kept = [kept[i] for i in sorted(
+        range(len(kept)), key=lambda i: (kept[i][1], -kept[i][2], -i))]
+    out = []
+    for i, (name, start, end, thread, attrs) in enumerate(kept):
+        parent = next((j for j in range(i - 1, -1, -1)
+                       if kept[j][3] == thread and kept[j][2] >= end), None)
+        out.append({"name": name, "start_s": start / 1e6,
+                    "end_s": end / 1e6, "thread": thread, "parent": parent,
+                    "attrs": dict(attrs)})
+    return out
+
+
 class _Annotation(jax.profiler.TraceAnnotation):
-    """What ``span()`` returns while the recorder is off: the span on the
-    profiler's timeline alone, chainable like a recorded one."""
+    """What ``span()`` returns while the recorder is off and the start-up
+    record is full: the span on the profiler's timeline alone, chainable
+    like a recorded one."""
 
     __slots__ = ()
 
     def set(self, **attrs):
         return self
+
+
+class _Kept(_Annotation):
+    """A span of the start-up record while the recorder is off: the same
+    annotation, with its stamps and counts kept."""
+
+    __slots__ = ("_name", "_attrs", "_t0")
+
+    def __init__(self, name, attrs):
+        super().__init__("mx/" + name)
+        self._name = name
+        self._attrs = attrs
+
+    def set(self, **attrs):
+        self._attrs.update(attrs)
+        return self
+
+    def __enter__(self):
+        super().__enter__()
+        self._t0 = _profiler.now_us()
+        return self
+
+    def __exit__(self, *exc):
+        _keep(self._name, self._t0, _profiler.now_us(), self._attrs)
+        return super().__exit__(*exc)
 
 
 class _Span:
@@ -205,6 +285,8 @@ class _Span:
             if st:
                 st.pop()
             self._onstack = False
+        if _startup_room > 0:
+            _keep(self.name, self._t0, t1, self.attrs)
         _finish(self.name, self.category, self._t0,
                 max(0, t1 - self._t0), self.trace_id, self.span_id,
                 self.parent_id, self.attrs)
@@ -215,10 +297,13 @@ def span(name, category="app", **attrs):
     """``with trace.span("train.step", step=n): ...`` — nested spans
     parent automatically through the thread-local context stack.  In any
     ``jax.profiler`` session the span is ``mx/<name>`` on the profiler's
-    timeline; while the recorder is disabled that is all it is."""
-    if not _active:
-        return _Annotation("mx/" + name)
-    return _Span(name, category, attrs)
+    timeline; the first ``STARTUP_SPANS`` of a process are also kept in
+    the start-up record (:func:`startup`), recorder on or off."""
+    if _active:
+        return _Span(name, category, attrs)
+    if _startup_room > 0:
+        return _Kept(name, attrs)
+    return _Annotation("mx/" + name)
 
 
 class SpanHandle:
@@ -274,7 +359,11 @@ def begin(name, category="app", parent=None, **attrs):
 
 def emit(name, start_us, dur_us, parent=None, category="app", **attrs):
     """Record an already-timed span directly (per-decode-step spans whose
-    wall time was measured anyway — no context-stack traffic)."""
+    wall time was measured anyway — no context-stack traffic).  Kept in
+    the start-up record while that has room, recorder on or off."""
+    if _startup_room > 0:
+        start = int(start_us)
+        _keep(name, start, start + max(0, int(dur_us)), attrs)
     if not _active:
         return
     sid = _new_id()

@@ -234,3 +234,90 @@ def test_report_lists_a_program_once_and_a_second_process_hits(tmp_path):
     assert runs[0][0]["hit"] is False and runs[0][0]["cache_retrieval_s"] == 0
     assert runs[1][0]["hit"] is True
     assert 0 < runs[1][0]["cache_retrieval_s"] <= runs[1][0]["backend_s"]
+
+
+# -- set-up seen from inside: the start-up record ----------------------------
+
+_STARTUP_PROBE = """
+import json, sys
+sys.path.insert(0, sys.argv[1])
+import numpy as onp
+import test_one_trace as t
+from mxnet_tpu import _compile_cache, trace
+assert not trace.active()
+step, x = t._tiny_step()
+for _ in range(3):
+    step(x, x).asnumpy()
+print(json.dumps({"startup": trace.startup(),
+                  "report": _compile_cache.report()}))
+"""
+
+
+@pytest.fixture(scope="module")
+def fresh_process():
+    """``mx.trace.startup()`` and ``_compile_cache.report()`` of a new
+    process that built a tiny step and called it three times, recorder
+    off, no profiler, no cache."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=_REPO)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    env.pop("MXNET_TRACE", None)
+    out = subprocess.run(
+        [sys.executable, "-c", _STARTUP_PROBE,
+         os.path.dirname(os.path.abspath(__file__))],
+        env=env, capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-2000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def _named(kept, name):
+    return [(i, s) for i, s in enumerate(kept) if s["name"] == name]
+
+
+def test_startup_holds_the_import_first(fresh_process):
+    kept = fresh_process["startup"]
+    assert kept[0]["name"] == "import" and kept[0]["parent"] is None
+    assert kept[0]["attrs"]["modules"] > 100
+    assert all(s["start_s"] >= kept[0]["end_s"] for s in kept[1:])
+    assert len({s["thread"] for s in kept}) == 1
+    assert [s["name"] for s in kept].count("ndarray.asnumpy") == 3
+
+
+@pytest.mark.parametrize("part,counts", [
+    ("train.plan", ("param_leaves", "param_bytes")),
+    ("train.place", ("leaves", "bytes")),
+    ("train.states", ("leaves", "bytes")),
+])
+def test_train_init_contains_its_parts_with_their_counts(
+        fresh_process, part, counts):
+    kept = fresh_process["startup"]
+    ((i_init, init),) = _named(kept, "train.init")
+    ((_, s),) = _named(kept, part)
+    assert s["parent"] == i_init and init["parent"] is None
+    assert init["start_s"] <= s["start_s"] <= s["end_s"] <= init["end_s"]
+    assert all(s["attrs"][c] > 0 for c in counts)
+    if part == "train.states":      # Adam: two moments a parameter
+        ((_, place),) = _named(kept, "train.place")
+        assert s["attrs"]["leaves"] == 2 * place["attrs"]["leaves"]
+        assert s["attrs"]["bytes"] == 2 * place["attrs"]["bytes"]
+
+
+def test_the_first_dispatch_holds_the_step_program_and_the_third_none(
+        fresh_process):
+    kept, report = fresh_process["startup"], fresh_process["report"]
+    calls, dispatches = _named(kept, "train.call"), \
+        _named(kept, "train.dispatch")
+    assert len(calls) == len(dispatches) == 3
+    for (i_call, call), (_, d) in zip(calls, dispatches):
+        assert d["parent"] == i_call and call["parent"] is None
+    for part in ("train.shard_batch", "train.scalars"):
+        assert [s["parent"] for _, s in _named(kept, part)] \
+            == [i for i, _ in calls]
+    (step,) = [r for r in report if r["fun_name"] == "jit(base_step)"]
+    first, third = dispatches[0][1], dispatches[2][1]
+    assert first["start_s"] < step["at"] <= first["end_s"]
+    # the first dispatch is the wall time of trace + lower + compile
+    assert first["end_s"] - first["start_s"] >= step["backend_s"]
+    assert not [r for r in report
+                if third["start_s"] <= r["at"] <= third["end_s"]]
+    ((_, init),) = _named(kept, "train.init")
+    assert init["end_s"] <= calls[0][1]["start_s"]

@@ -154,6 +154,142 @@ def test_knobs_arm_configure():
     assert not trace.active()
 
 
+# -- start-up record ----------------------------------------------------------
+
+@pytest.fixture
+def fresh_record(monkeypatch):
+    """The start-up record as a new process has it: empty, all its room
+    (this process filled its own long before this file ran)."""
+    monkeypatch.setattr(trace, "_startup", [])
+    monkeypatch.setattr(trace, "_startup_room", trace.STARTUP_SPANS)
+
+
+def _recorder(on):
+    if on:
+        trace.enable(buffer=4 * trace.STARTUP_SPANS)
+    assert trace.active() == on
+
+
+def test_startup_keeps_the_first_spans_with_everything_off_then_stops(
+        fresh_record):
+    assert not trace.active() and trace.startup() == []
+    for i in range(trace.STARTUP_SPANS):
+        sp = trace.span(f"s{i}", category="test", i=i)
+        assert type(sp) is trace._Kept
+        with sp as got:
+            assert got.set(twice=2 * i) is got
+    # the record is full: the off path is the bare annotation again
+    last = trace.span("one_too_many", x=1)
+    assert type(last) is trace._Annotation
+    with last as got:
+        assert got.set(y=2) is got
+    trace.emit("one_too_many", trace.clock_us(), 1)
+    kept = trace.startup()
+    assert [s["name"] for s in kept] \
+        == [f"s{i}" for i in range(trace.STARTUP_SPANS)]
+    assert kept[7]["attrs"] == {"i": 7, "twice": 14}
+    assert all(s["parent"] is None and s["thread"] == threading.get_ident()
+               and s["start_s"] <= s["end_s"] for s in kept)
+    assert trace.spans() == []              # the ring saw none of it
+
+
+@pytest.mark.parametrize("recorder", [False, True])
+def test_startup_times_are_perf_counter_seconds(fresh_record, recorder):
+    import time
+    _recorder(recorder)
+    t0 = time.perf_counter()
+    with trace.span("timed"):
+        time.sleep(0.002)
+    t1 = time.perf_counter()
+    (s,) = trace.startup()
+    assert t0 <= s["start_s"] + 1e-6 and s["end_s"] <= t1 + 1e-6
+    assert s["end_s"] - s["start_s"] >= 0.002
+
+
+@pytest.mark.parametrize("recorder", [False, True])
+def test_startup_keeps_an_emitted_span(fresh_record, recorder):
+    _recorder(recorder)
+    t = trace.clock_us()
+    with trace.span("outer"):
+        pass
+    # already timed, stamped before ``outer`` opened: it sorts first
+    trace.emit("import", t - 500, 400, category="startup", modules=3)
+    first, second = trace.startup()
+    assert first["name"] == "import" and second["name"] == "outer"
+    assert first["attrs"] == {"modules": 3}
+    assert first["end_s"] - first["start_s"] == pytest.approx(400e-6)
+    assert first["parent"] is None and second["parent"] is None
+    assert len(trace.spans()) == (2 if recorder else 0)
+
+
+@pytest.mark.parametrize("recorder", [False, True])
+def test_startup_parent_is_the_containing_span_of_the_same_thread(
+        fresh_record, recorder):
+    _recorder(recorder)
+    with trace.span("a"):
+        with trace.span("b"):
+            with trace.span("c"):
+                pass
+        with trace.span("d"):
+            pass
+        # another thread's span lies inside ``a``'s interval, not under it
+        t = threading.Thread(target=lambda: trace.span("other").__enter__()
+                             .__exit__(None, None, None))
+        t.start()
+        t.join(timeout=10)
+        assert not t.is_alive()
+    with trace.span("e"):
+        pass
+    kept = trace.startup()
+    names = [s["name"] for s in kept]
+    assert names == ["a", "b", "c", "d", "other", "e"]
+    parent = {s["name"]: (None if s["parent"] is None
+                          else names[s["parent"]]) for s in kept}
+    assert parent == {"a": None, "b": "a", "c": "b", "d": "a",
+                      "other": None, "e": None}
+    assert kept[4]["thread"] != kept[0]["thread"]
+
+
+@pytest.mark.parametrize("recorder", [False, True])
+def test_clear_leaves_the_startup_record(fresh_record, recorder):
+    _recorder(recorder)
+    with trace.span("kept", category="test"):
+        pass
+    trace.clear()
+    assert trace.spans() == []
+    assert [s["name"] for s in trace.startup()] == ["kept"]
+    # a copy: the caller cannot edit the record
+    trace.startup()[0]["attrs"]["x"] = 1
+    assert trace.startup()[0]["attrs"] == {}
+
+
+def test_recorder_on_fills_ring_and_record_alike(fresh_record):
+    trace.enable(buffer=4 * trace.STARTUP_SPANS)
+    n = trace.STARTUP_SPANS + 5
+    for i in range(n):
+        with trace.span(f"s{i}", category="test") as sp:
+            sp.set(i=i)
+    ring, kept = trace.spans(category="test"), trace.startup()
+    assert len(ring) == n and len(kept) == trace.STARTUP_SPANS
+    for ev, s in zip(ring, kept):
+        assert ev["name"] == s["name"] and ev["args"]["i"] == s["attrs"]["i"]
+        assert ev["ts"] == round(s["start_s"] * 1e6)
+        assert ev["ts"] + ev["dur"] == round(s["end_s"] * 1e6)
+    # async handles are the ring's alone: they end on any thread
+    trace.begin("handle").end()
+    assert len(trace.startup()) == trace.STARTUP_SPANS
+
+
+def test_this_process_kept_the_packages_import():
+    """The real record, whatever ran since: the package's import is in
+    it, from the first line of ``mxnet_tpu/__init__.py`` to its last."""
+    kept = trace.startup()
+    (imp,) = [s for s in kept if s["name"] == "import"]
+    assert imp["parent"] is None and imp["attrs"]["modules"] > 100
+    assert 0 < imp["end_s"] - imp["start_s"] < 600
+    assert len(kept) <= trace.STARTUP_SPANS
+
+
 # -- clock + profiler bridge ------------------------------------------------
 
 def test_shared_clock_and_profiler_mirroring():
