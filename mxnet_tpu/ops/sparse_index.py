@@ -27,9 +27,21 @@ arrays, composed by ``nn.IndexedAttention``:
   ``s``, as an int8 mask.  No sort: the ``topk``-th largest score of a
   row is found by bisection on the bits of an order-preserving integer
   key — 32 counting passes over the scores, whatever ``topk`` — and the
-  mask is one comparison with it.  Rows where more scores tie with the
-  threshold than it may admit take a second path (a running count along
-  the row) that a ``lax.cond`` enters only when some row needs it.
+  mask is one comparison with it.  Which pass runs is decided by what
+  the call can see.  On a TPU, at a sequence of whole blocks whose panel
+  VMEM holds, it is the Pallas kernel ``mx_dsa_select``
+  (``ops/pallas/dsa_select.py``): a block of queries' scores, key-major
+  as ``mx_dsa_scores`` emits them, are fetched once, the 32 passes count
+  over them in VMEM up to the diagonal, and one sweep writes the mask
+  key-major, as the flash kernels and ``mx_dsa_align`` take it; a panel
+  in which some row ties at its threshold finds, by a second bisection
+  on the key index, the first of the equal keys it may admit.
+  Everywhere else — the CPU, the tier-1 tests, short or ragged
+  sequences — it is the XLA bisection ``_composed_select``, each of
+  whose passes reads the ``(seq, seq)`` keys from HBM, and whose rows
+  that tie take a second path (a running count along the row) that a
+  ``lax.cond`` enters only when some row needs it; it is also the
+  kernel's oracle.
 * ``align_loss``: ``mean_t KL(p_t || softmax_{S_t}(I[t, :]))`` with
   ``p_t`` the attention's own probabilities over the selected keys,
   averaged over the heads — what the indexer is trained towards.  The
@@ -199,7 +211,37 @@ def select_topk(scores, topk):
     """scores (b, s, s) float32 -> int8 (b, s, s): 1 where query ``t``
     reads key ``s``: ``s <= t`` and the score is among the row's ``topk``
     largest of those (ties to the lower ``s``).  Exactly
-    ``min(t + 1, topk)`` a row."""
+    ``min(t + 1, topk)`` a row.
+
+    Which pass runs is decided by what the call can see: on a TPU, at a
+    sequence one of ``ops/pallas/dsa_select.py``'s blocks divides into
+    and whose panel VMEM holds, the Pallas kernel; everywhere else the
+    XLA bisection, which is also its oracle."""
+    from .. import runtime
+    from .pallas import dsa_select
+    if runtime.on_tpu() and dsa_select.fits(scores.shape[1]):
+        return _kernel_select(scores, topk)
+    return _composed_select(scores, topk)
+
+
+def _kernel_select(scores, topk):
+    """``_composed_select`` by ``ops/pallas/dsa_select.py``, which takes
+    the scores and returns the mask key-major: each ``swapaxes`` is a
+    layout to XLA."""
+    from .. import runtime
+    from .pallas.dsa_select import select_pass
+
+    def kernel(i_t):
+        return select_pass(i_t, topk, interpret=runtime.pallas_interpret())
+
+    return jnp.swapaxes(_batch_over_dp(kernel, jnp.swapaxes(
+        jax.lax.stop_gradient(scores), 1, 2)), 1, 2)
+
+
+def _composed_select(scores, topk):
+    """The XLA bisection (and the kernel's oracle): 32 counting passes
+    over the ``(seq, seq)`` keys; rows that tie at their threshold by a
+    running count along the row, inside a ``lax.cond``."""
     b, s, _ = scores.shape
     rows = jnp.arange(s, dtype=jnp.int32)[:, None]
     causal = jnp.arange(s, dtype=jnp.int32)[None, :] <= rows

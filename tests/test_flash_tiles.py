@@ -368,3 +368,30 @@ def test_the_alignment_kernel_compiles_for_a_v5e_at_the_cells_size(one_v5e):
     sizes = [onp.prod([int(n) for n in dims.split(",")]) for dims in
              re.findall(r"\b[a-z]+[0-9]+\[([0-9,]+)\]", text)]
     assert max(sizes) == b * s * s
+
+
+def test_the_top_k_kernel_compiles_for_a_v5e_at_the_cells_size(one_v5e):
+    """``mx_dsa_select`` (ops/pallas/dsa_select.py) at ``keye-train-8k``'s
+    shape — 8192 tokens, float32 scores, ``topk`` 2048 — is taken by Mosaic
+    as written (a 16 MB panel twice, the keys' scratch, signed compares,
+    a dynamic trip count, conditions with vector results, int8 stores),
+    and its program holds the scores it is given and the int8 mask."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from mxnet_tpu.ops.pallas import dsa_select
+    b, s, topk = 1, 8192, 2048
+    assert dsa_select.panel_block(s) == 512
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = jax.jit(lambda i: dsa_select.select_pass(i, topk)).lower(
+            jax.ShapeDtypeStruct((b, s, s), jnp.float32,
+                                 sharding=one_v5e)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "mx_dsa_select" in text
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes == b * s * s * 4
+    assert memory.temp_size_in_bytes == 0
+    assert memory.output_size_in_bytes == b * s * s

@@ -3,7 +3,7 @@
 Interpret-mode tests (the CPU suite) say a kernel's arithmetic is right;
 only libtpu's Mosaic compiler says whether it fits VMEM, whether its
 layouts and its int8/fp8 dots are accepted on this ``device_kind``.  This
-script compiles each of the seven kernels with ``interpret=False`` at the
+script compiles each of the eight kernels with ``interpret=False`` at the
 shapes the model zoo uses and the static blocks the device gets
 (``autotune.kernels._STATIC_DEFAULTS``), and checks numerics against a
 plain-jnp reference:
@@ -14,6 +14,8 @@ plain-jnp reference:
                            against the XLA composition
     dsa_scores             the sparse indexer's scores and their three
                            gradients, against the XLA composition
+    dsa_select             the sparse indexer's top-k mask, against the
+                           XLA bisection, with and without ties
     ln_residual            fwd + bwd, bf16 and fp32
     quantized_matmul       int8 x int8 -> int32
     fp8_matmul             e4m3 and e5m2
@@ -278,6 +280,35 @@ def _dsa_scores_case(B, H, S, D, dtype):
     return f"dsa_scores b{B}h{H}s{S}d{D} {jnp.dtype(dtype).name}", check
 
 
+def _dsa_select_case(B, S, topk, ties):
+    """``select_topk`` as the chip takes it (``mx_dsa_select``) against
+    the XLA bisection it replaces (its oracle): equal masks, exactly
+    ``min(t + 1, topk)`` a row.  ``ties``: scores rounded to a few
+    values, so that every row ties at its threshold."""
+    def check():
+        from mxnet_tpu.ops import sparse_index
+        from mxnet_tpu.ops.pallas import dsa_select
+        assert dsa_select.fits(S)
+        scores = 2.0 * jax.random.normal(jax.random.PRNGKey(5), (B, S, S),
+                                         jnp.float32)
+        if ties:
+            scores = jnp.round(scores * 4.0) * 0.25
+        # as ``mx_dsa_scores`` leaves them: zeros above the diagonal
+        scores = jnp.tril(scores)
+        got = jax.jit(lambda i: sparse_index.select_topk(i, topk))(scores)
+        want = jax.jit(
+            lambda i: sparse_index._composed_select(i, topk))(scores)
+        rows = jnp.sum(got.astype(jnp.int32), axis=-1)
+        res = {"differ": int(jnp.sum(got != want)),
+               "rows_off": int(jnp.sum(
+                   rows != jnp.minimum(jnp.arange(S) + 1, topk))),
+               "above_diagonal": int(jnp.sum(jnp.triu(got, 1) != 0))}
+        assert res["differ"] == 0 and res["rows_off"] == 0 \
+            and res["above_diagonal"] == 0, res
+        return res
+    return f"dsa_select b{B}s{S} top{topk}{' ties' if ties else ''}", check
+
+
 def _conv_case(N, H, W, Cin, Cout):
     def check():
         from mxnet_tpu.ops.pallas_conv_bwd import (conv3x3_bn_relu_ref,
@@ -332,6 +363,13 @@ def cases():
     # head, and a batch of shorter float32 rows
     out.append(_dsa_scores_case(1, 16, 8192, 64, bf16))
     out.append(_dsa_scores_case(2, 4, 1024, 64, f32))
+    # the indexer's top-k: the cell's sequence and ``topk`` (a row or
+    # two of random float32 scores tie at their threshold), a batch of
+    # shorter rows, and scores of which every row ties
+    out.append(_dsa_select_case(1, 8192, 2048, ties=False))
+    out.append(_dsa_select_case(2, 1024, 256, ties=False))
+    out.append(_dsa_select_case(1, 8192, 2048, ties=True))
+    out.append(_dsa_select_case(1, 2048, 512, ties=True))
     for dtype in (bf16, f32):
         out.append(_ln_case(32 * 128, 768, dtype))       # BERT-base bs32
     out.append(_int8_case(1024, 3072, 768, "gelu"))      # GPT-2 FFN up
