@@ -17,7 +17,7 @@ from .activations import (  # noqa: F401
 )
 from .transformer import (  # noqa: F401
     MultiHeadAttention, GroupedQueryAttention, IndexedAttention,
-    SparseIndexer, PositionwiseFFN, GatedFFN,
+    LatentAttention, SparseIndexer, PositionwiseFFN, GatedFFN,
     TransformerEncoder,
     TransformerEncoderCell, TransformerDecoderCell,
 )

@@ -665,3 +665,82 @@ def positional_encoding(seq_len, units, dtype="float32"):
     table[:, 0::2] = onp.sin(angle)
     table[:, 1::2] = onp.cos(angle[:, : units // 2])
     return np.array(table)
+
+
+class LatentAttention(HybridBlock):
+    """Multi-head latent attention (DeepSeek-V2's MLA) on (batch, seq,
+    units), causal, no biases.  Queries and keys/values are projected
+    through low-rank latents with an RMSNorm each:
+    ``c_q = RMS(x W_qa)`` (``q_lora_rank``), ``q = c_q W_qb``, a head
+    ``(qk_nope_head_dim | qk_rope_head_dim)``;
+    ``[c_kv | k_r] = x W_kva`` (``kv_lora_rank | qk_rope_head_dim``),
+    ``[k_nope | v] = RMS(c_kv) W_kvb``, a head
+    ``(qk_nope_head_dim | v_head_dim)``.  The rotary embedding turns
+    each head's ``rope`` part of q and the **one** ``k_r``, which every
+    head's key then carries: ``k_h = [k_nope_h | k_r]``.  Scores are
+    scaled by the whole query head's width.
+
+    The plain form: K is assembled in HBM with ``k_r`` repeated a head,
+    and the core is ``ops.attention.multi_head_attention`` with as many
+    KV heads as query heads — the flash kernels on a TPU, the XLA
+    composition elsewhere.  The core takes one head width, so
+    ``v_head_dim`` has to equal ``qk_nope_head_dim + qk_rope_head_dim``.
+    Training path only: no latent cache, no absorbed decode form.
+
+    Scopes: ``mx.mla`` round the body, ``mx.mla.assemble`` round what
+    exists only because the keys are latent (the rotary on the ``rope``
+    parts, the split of ``W_kvb``'s output, the repeat of ``k_r`` and the
+    concatenations).
+    """
+
+    def __init__(self, units, num_heads, q_lora_rank, kv_lora_rank,
+                 qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
+                 rope_theta=10000.0, epsilon=1e-5):
+        super().__init__()
+        if v_head_dim != qk_nope_head_dim + qk_rope_head_dim:
+            raise ValueError(
+                f"value heads of {v_head_dim} beside query / key heads of "
+                f"{qk_nope_head_dim} + {qk_rope_head_dim}: the attention "
+                "core takes one head width")
+        self._heads, self._kv_rank = num_heads, kv_lora_rank
+        self._nope, self._rope, self._theta = (qk_nope_head_dim,
+                                               qk_rope_head_dim, rope_theta)
+
+        def proj(n):
+            return Dense(n, use_bias=False, flatten=False)
+
+        self.q_a_proj = proj(q_lora_rank)
+        self.q_a_norm = RMSNorm(epsilon, in_channels=q_lora_rank)
+        self.q_b_proj = proj(num_heads * v_head_dim)
+        self.kv_a_proj = proj(kv_lora_rank + qk_rope_head_dim)
+        self.kv_a_norm = RMSNorm(epsilon, in_channels=kv_lora_rank)
+        self.kv_b_proj = proj(num_heads * (qk_nope_head_dim + v_head_dim))
+        self.out_proj = proj(units)
+
+    def forward(self, x):
+        import jax
+
+        from ...ops.attention import multi_head_attention
+        heads, nope, rope = self._heads, self._nope, self._rope
+        b, s, _ = x.shape
+        with jax.named_scope("mx.mla"):
+            q = self.q_b_proj(self.q_a_norm(self.q_a_proj(x)))
+            kv_a = self.kv_a_proj(x)
+            kv = self.kv_b_proj(self.kv_a_norm(kv_a[..., :self._kv_rank]))
+            with jax.named_scope("mx.mla.assemble"):
+                q = q.reshape(b, s, heads, -1)
+                kv = kv.reshape(b, s, heads, -1)
+                q_r = npx.rotary_embedding(
+                    q[..., nope:].reshape(b, s, -1), heads, self._theta)
+                k_r = npx.rotary_embedding(kv_a[..., self._kv_rank:], 1,
+                                           self._theta)
+                q = np.concatenate(
+                    [q[..., :nope], q_r.reshape(b, s, heads, rope)], axis=-1)
+                k = np.concatenate(
+                    [kv[..., :nope], np.broadcast_to(
+                        k_r.reshape(b, s, 1, rope), (b, s, heads, rope))],
+                    axis=-1)
+                q, k, v = (t.reshape(b, s, -1)
+                           for t in (q, k, kv[..., nope:]))
+            out = multi_head_attention(q, k, v, heads, causal=True)
+            return self.out_proj(out)

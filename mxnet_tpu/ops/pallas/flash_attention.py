@@ -590,32 +590,32 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
 
     q: (batch, heads, seq_q, head_dim); k/v: (batch, kv_heads, seq_k,
     head_dim) with ``heads`` a multiple of ``kv_heads``: query head ``i``
-    reads KV head ``i // (heads // kv_heads)``, the K/V index maps name
-    that head (nothing is repeated in HBM) and dK/dV sum over the group
-    inside the kernel.  ``window`` (with ``causal``) keeps query ``i`` to
-    the keys ``0 <= i - j < window``; tiles left of that band are skipped
-    like tiles above the diagonal.  ``selection`` (batch, seq_q, seq_k),
-    integer or bool, keeps query ``i`` of a batch row to the keys ``j``
-    where it is nonzero, for all the row's heads alike and on top of
-    ``causal`` / ``window``; it has no gradient and skips no tile.  A
-    query it leaves no key gets an undefined (finite) row.
-    Returns (batch, heads, seq_q, head_dim); with ``return_lse`` a pair of
-    that and the log-sum-exp of every query's scaled logits over the keys
-    it read, (batch, heads, seq_q) float32: what the forward kernel keeps
-    for the backward, handed out as a constant (no gradient).
+    reads KV head ``i // (heads // kv_heads)`` through the K/V index maps
+    (nothing is repeated in HBM) and dK/dV sum over the group inside the
+    kernel.  q, k and v share one ``head_dim`` (a ValueError otherwise);
+    compiled for a v5e and run there at 64 (padded to the 128 lanes), 128 and
+    256.  ``window`` (with ``causal``) keeps query ``i`` to the keys
+    ``0 <= i - j < window``; tiles left of that band are skipped like tiles
+    above the diagonal.  ``selection`` (batch, seq_q, seq_k), integer or
+    bool, keeps query ``i`` of a batch row to the keys ``j`` where it is
+    nonzero, for all the row's heads alike and on top of ``causal`` /
+    ``window``; it has no gradient and skips no tile.  A query it leaves no
+    key gets an undefined (finite) row.  Returns q's shape; with
+    ``return_lse`` a pair of that and the log-sum-exp of every query's scaled
+    logits over its keys, (batch, heads, seq_q) float32, a constant.
 
-    Block shapes default to ``mx.autotune.resolve_blocks`` — the tuned
-    winner for this (seq_q, seq_k, head_dim) bucket when one is loaded,
-    else the per-device static table (CPU row keeps the historical
-    1024/512).  The backward tiles independently via bwd_block_q /
-    bwd_block_k.  Explicit values always win.  Compiled for a TPU, a block
-    shorter than its sequence is a multiple of the 128 lanes (the
-    statistics carry the sequence there); the interpreter takes any.
+    Blocks default to ``mx.autotune.resolve_blocks`` (a bucket's tuned
+    winner, else the device's static row), the backward's independently;
+    explicit values win.  On a TPU a block under its sequence is a multiple
+    of the 128 lanes.
     """
     b, h, sq, d = q.shape
     hk, sk = k.shape[1:3]
     if h % hk:
         raise ValueError(f"{h} query heads do not group over {hk} KV heads")
+    if not d == k.shape[3] == v.shape[3]:
+        raise ValueError(f"head widths {d}, {k.shape[3]} and {v.shape[3]} of "
+                         "q, k and v: the kernels take one head width")
     if window is not None and not causal:
         raise ValueError("a window is a causal band: pass causal=True")
     if scale is None:

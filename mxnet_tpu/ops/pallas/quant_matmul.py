@@ -29,12 +29,14 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ... import runtime as _runtime
 
 __all__ = ["quantized_matmul", "fp8_matmul", "fp8_capable", "FP8_FORMATS"]
 
 _INT8_MAX = 127.0
+_VMEM_DEFAULT = 16 << 20    # Mosaic's scoped VMEM limit a kernel
 
 #: fp8 storage formats: name -> (dtype, absmax of the format)
 FP8_FORMATS = {
@@ -192,6 +194,14 @@ def fp8_matmul(x, w_q, w_scale, x_scale, bias=None, act=None, fmt="e4m3",
          else bias.astype(jnp.float32))
     bp = _pad2(b[None, :], 1, np_)
     xs = jnp.asarray(x_scale, jnp.float32).reshape(1, 1)
+    # K rides whole: past the default scoped limit (16 MiB: fp32
+    # activations at K 5120 and more) the call asks for what its tiles
+    # hold, twice for the pipeline, and their fp32 / fp8 copies in the
+    # body; shorter calls are compiled as they always were
+    tiles = kp * (bm * xp.dtype.itemsize + bn) + 4 * bm * bn
+    extra = {} if 2 * tiles <= _VMEM_DEFAULT - (2 << 20) else {
+        "compiler_params": pltpu.CompilerParams(
+            vmem_limit_bytes=2 * tiles + 5 * bm * kp + (16 << 20))}
     out = pl.pallas_call(
         functools.partial(_fp8_kernel, act=act, fmt=fmt),
         grid=(grid_m, grid_n),
@@ -206,5 +216,6 @@ def fp8_matmul(x, w_q, w_scale, x_scale, bias=None, act=None, fmt="e4m3",
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
         interpret=interpret,
         name="mx_fp8_matmul",
+        **extra,
     )(xs, xp, wp, wsp, bp)
     return out[:m, :n]
