@@ -10,11 +10,19 @@ Raw ``jax`` arrays in, raw arrays out; ``nn.Mamba2Mixer`` composes them.
   recurrence is a ``(chunk, chunk)`` lower-triangular product a head,
   ``y_q += sum_{k<=q} (C_q . B_k) exp(a_q - a_k) dt_k x_k`` with ``a`` the
   running sum of ``dt A``; a chunk's contribution to the state at its end
-  is one more product; the state is carried from chunk to chunk by a
-  ``lax.scan`` over ``seq / chunk`` steps; and what the entering state
-  adds to a chunk's outputs is a last product.  The largest array is
-  ``(heads, seq, chunk)``: nothing is ``(heads, seq, seq)``, and
-  ``chunk`` changes no value.
+  is one more product; the state is carried from chunk to chunk; and
+  what the entering state adds to a chunk's outputs is a last product.
+  Nothing is ``(heads, seq, seq)``, and ``chunk`` changes no value.
+  Which pass runs is decided by what the call can see.  On a TPU, at
+  shapes their tiles fill (``ops/pallas/ssd_scan.py::fits``), it is the
+  Pallas kernels ``mx_ssd_fwd`` and, backward, ``mx_ssd_bwd``: a chunk's
+  decays and masked products live in VMEM only, the state is carried in
+  a VMEM scratch down a sequential grid axis, and the operands are read
+  once.  Everywhere else — the CPU, the tier-1 tests, small or ragged
+  shapes — it is the XLA composition ``_ssd_chunked``, whose largest
+  array is ``(heads, seq, chunk)`` and whose state is carried by a
+  ``lax.scan`` over ``seq / chunk`` steps; it is also the kernels'
+  oracle.
 * ``causal_conv1d``: depthwise, ``kernel`` taps to the left with a bias,
   as shifted multiply-adds (optionally through SiLU).
 * ``gated_rms_norm``: ``RMSNorm(y * silu(z)) * w`` over groups of
@@ -27,13 +35,17 @@ The four products' operands (``C . B``, the triangular product, the
 chunk's state, the state's output) take the type ``x`` arrives in —
 bfloat16 under ``mx.amp``, as a ``Dense``'s do.
 
-**The backward pass** is autodiff of the chunked form, with each of the
-three functions under ``jax.checkpoint``: nothing a function computes
-inside is kept for its backward pass — not the ``(heads, seq, chunk)``
-decays, not the chunk states — only its arguments, and the function is
-made again when its gradient is taken (a third more scan time for
-~0.7 GB a layer at 8192 tokens).  ``docs/STATE_SPACE.md`` has the
-equations and the accounting.
+**The backward pass** of the convolution, of the norm and of the
+composition ``_ssd_chunked`` is autodiff, each under ``jax.checkpoint``:
+nothing a function computes inside is kept for its backward pass — not
+the ``(heads, seq, chunk)`` decays, not the chunk states — only its
+arguments, and the function is made again when its gradient is taken (a
+third more scan time for ~0.7 GB a layer at 8192 tokens).  The kernels
+have their own (``jax.custom_vjp``): ``mx_ssd_bwd`` walks the chunks in
+reverse with the state's cotangent in its scratch and makes the decays
+again in VMEM; beside the operands it keeps the float32 state entering
+each chunk (134 MB a layer at 8192 tokens), and the forward is not run
+again.  ``docs/STATE_SPACE.md`` has the equations and the accounting.
 
 A sequence ``chunk`` does not divide is padded at its end with ``dt = 0``
 and ``x = 0``: a padded step decays nothing and adds nothing, and its
@@ -51,6 +63,16 @@ from .. import telemetry as _telemetry
 _F32 = jnp.float32
 
 
+def _whole_chunks(chunk, *tensors):
+    """``(b, s, ...)`` tensors padded with zeros at the sequence's end to
+    whole chunks."""
+    pad = -tensors[0].shape[1] % chunk
+    if not pad:
+        return tensors
+    return tuple(jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+                 for t in tensors)
+
+
 @functools.partial(jax.checkpoint, static_argnums=(6,))
 def _ssd_chunked(x, dt, a_head, b_mat, c_mat, d_skip, chunk):
     with jax.named_scope("mx.ssm.scan"):
@@ -58,11 +80,8 @@ def _ssd_chunked(x, dt, a_head, b_mat, c_mat, d_skip, chunk):
         groups, state = b_mat.shape[2:]
         per = heads // groups
         dtype = x.dtype
-        pad = -seq % chunk
-        if pad:
-            x, dt, b_mat, c_mat = (
-                jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
-                for t in (x, dt, b_mat, c_mat))
+        x, dt, b_mat, c_mat = _whole_chunks(chunk, x, dt, b_mat, c_mat)
+        pad = x.shape[1] - seq
         n = (seq + pad) // chunk
         xs = x.reshape(batch, n, chunk, groups, per, dim)
         xf = xs.astype(_F32)
@@ -117,16 +136,94 @@ def ssd_scan(x, dt, a_head, b_mat, c_mat, d_skip, chunk=128):
     """The state-space scan: ``x (b, s, H, P)``, ``dt (b, s, H)`` the
     step sizes (positive, after their softplus), ``a_head (H,)`` the
     negative decay rate a head, ``b_mat`` / ``c_mat (b, s, G, N)``,
-    ``d_skip (H,)`` -> ``y (b, s, H, P)`` in ``x``'s type."""
+    ``d_skip (H,)`` -> ``y (b, s, H, P)`` in ``x``'s type.
+
+    Which pass runs is decided by what the call can see: on a TPU, at
+    shapes ``ops/pallas/ssd_scan.py``'s tiles fill (``fits``), the Pallas
+    kernels; everywhere else the XLA composition, which is also their
+    oracle."""
+    from .. import runtime
+    from .pallas import ssd_scan as kernels
     heads, groups = x.shape[2], b_mat.shape[2]
     if heads % groups:
         raise ValueError(f"{heads} heads do not group over {groups} "
                          "B / C groups")
+    chunk = int(chunk)
+    by_kernel = runtime.on_tpu() and kernels.fits(
+        x.shape[1], heads, x.shape[3], groups, b_mat.shape[3], chunk,
+        x.dtype.itemsize)
     if _telemetry._active:
         _telemetry.inc("ssm.scan_tokens_total", x.shape[0] * x.shape[1])
         _telemetry.inc("ssm.scan_chunks_total",
                        x.shape[0] * -(-x.shape[1] // chunk) * heads)
-    return _ssd_chunked(x, dt, a_head, b_mat, c_mat, d_skip, int(chunk))
+        if by_kernel:
+            _telemetry.inc("ssm.scan_kernel_calls_total")
+    if by_kernel:
+        # the scope round the custom_vjp call: its backward rule is traced
+        # under the caller's scopes, ``transpose(jvp(...))`` round them
+        with jax.named_scope("mx.ssm.scan"):
+            return _ssd_kernels(x, dt, a_head, b_mat, c_mat, d_skip, chunk)
+    return _ssd_chunked(x, dt, a_head, b_mat, c_mat, d_skip, chunk)
+
+
+def _ssd_kernels(x, dt, a_head, b_mat, c_mat, d_skip, chunk):
+    """``_ssd_chunked`` by ``ops/pallas/ssd_scan.py``, which takes its
+    operands channel-major, tokens along the lanes: the layout XLA gives
+    the mixer's arrays anyway, so each ``swapaxes`` is a layout to it.
+    What else XLA does here is a few MB a call: the pad, the log-decays
+    ``dt A``, ``D`` along a chunk's lanes; autodiff takes their
+    gradients back to ``dt``, ``A`` and ``D``."""
+    batch, seq, heads, dim = x.shape
+    groups, state = b_mat.shape[2:]
+    x, dt, b_mat, c_mat = _whole_chunks(chunk, x, dt, b_mat, c_mat)
+    total = x.shape[1]
+    steps = jnp.swapaxes(dt.astype(_F32), 1, 2)             # (b, H, s)
+    y = _scan_kernels(
+        jnp.swapaxes(x.reshape(batch, total, heads * dim), 1, 2),
+        jnp.swapaxes(b_mat.reshape(batch, total, groups * state), 1, 2),
+        jnp.swapaxes(c_mat.reshape(batch, total, groups * state), 1, 2),
+        steps, steps * a_head.astype(_F32)[:, None],
+        jnp.broadcast_to(d_skip.astype(_F32)[:, None],
+                         (batch, heads, chunk)), groups, chunk)
+    return jnp.swapaxes(y, 1, 2).reshape(batch, total, heads, dim)[:, :seq]
+
+
+def _kernel_pass(name, operands, groups, chunk, **static):
+    """``ops/pallas/ssd_scan.py``'s pass ``name`` on operands that all
+    have the batch first (``sparse_index._batch_over_dp``: a ``shard_map``
+    over 'dp' under a mesh, a group's heads whole on every device)."""
+    from .. import runtime
+    from .pallas import ssd_scan as kernels
+    from .sparse_index import _batch_over_dp
+
+    def kernel(*args):
+        return getattr(kernels, name)(
+            *args, groups, chunk, interpret=runtime.pallas_interpret(),
+            **static)
+
+    return _batch_over_dp(kernel, *operands)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _scan_kernels(x, b_mat, c_mat, dt, log_decay, d_rows, groups, chunk):
+    return _kernel_pass("scan_pass", (x, b_mat, c_mat, dt, log_decay, d_rows),
+                        groups, chunk, keep=False)
+
+
+def _scan_kernels_fwd(x, b_mat, c_mat, dt, log_decay, d_rows, groups, chunk):
+    operands = (x, b_mat, c_mat, dt, log_decay, d_rows)
+    y, entering = _kernel_pass("scan_pass", operands, groups, chunk,
+                               keep=True)
+    return y, operands + (entering,)
+
+
+def _scan_kernels_bwd(groups, chunk, res, dy):
+    # traced under the caller's scopes, ``transpose(jvp(...))`` round
+    # them, like any other backward operation
+    return _kernel_pass("scan_bwd_pass", res + (dy,), groups, chunk)
+
+
+_scan_kernels.defvjp(_scan_kernels_fwd, _scan_kernels_bwd)
 
 
 @functools.partial(jax.checkpoint, static_argnums=(3,))

@@ -242,6 +242,10 @@ declare_metric("ssm.scan_chunks_total", "counter",
                "chunks x heads of those calls: the (chunk, chunk) "
                "triangular products one call makes (a sequence the chunk "
                "does not divide counts its padded last chunk)")
+declare_metric("ssm.scan_kernel_calls_total", "counter",
+               "those calls that took the Pallas kernels "
+               "(ops/pallas/ssd_scan.py: a TPU and shapes its tiles fill) "
+               "and not the XLA composition, once per traced call")
 
 
 # -- switches ---------------------------------------------------------------

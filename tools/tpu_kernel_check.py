@@ -3,7 +3,7 @@
 Interpret-mode tests (the CPU suite) say a kernel's arithmetic is right;
 only libtpu's Mosaic compiler says whether it fits VMEM, whether its
 layouts and its int8/fp8 dots are accepted on this ``device_kind``.  This
-script compiles each of the eight kernels with ``interpret=False`` at the
+script compiles each of the kernels with ``interpret=False`` at the
 shapes the model zoo uses and the static blocks the device gets
 (``autotune.kernels._STATIC_DEFAULTS``), and checks numerics against a
 plain-jnp reference:
@@ -16,6 +16,8 @@ plain-jnp reference:
                            gradients, against the XLA composition
     dsa_select             the sparse indexer's top-k mask, against the
                            XLA bisection, with and without ties
+    ssd_scan               the state-space scan and its six gradients,
+                           against the XLA composition
     ln_residual            fwd + bwd, bf16 and fp32
     quantized_matmul       int8 x int8 -> int32
     fp8_matmul             e4m3 and e5m2
@@ -309,6 +311,43 @@ def _dsa_select_case(B, S, topk, ties):
     return f"dsa_select b{B}s{S} top{topk}{' ties' if ties else ''}", check
 
 
+def _ssd_scan_case(B, S, H, P, G, N, dtype):
+    """``ssd_scan`` as the chip takes it (``mx_ssd_fwd`` and
+    ``mx_ssd_bwd``) against the XLA composition it replaces (its oracle):
+    ``y`` and the gradients of ``x``, ``dt``, ``A``, ``B``, ``C``, ``D``."""
+    def check():
+        from mxnet_tpu.ops import ssm
+        from mxnet_tpu.ops.pallas import ssd_scan
+        assert ssd_scan.fits(S, H, P, G, N, 128, jnp.dtype(dtype).itemsize)
+        ks = jax.random.split(jax.random.PRNGKey(6), 7)
+        args = (jax.random.normal(ks[0], (B, S, H, P), dtype),
+                jax.random.uniform(ks[1], (B, S, H), jnp.float32, 0.001, 0.1),
+                -jax.random.uniform(ks[2], (H,), jnp.float32, 1.0, 16.0),
+                (0.3 * jax.random.normal(ks[3], (B, S, G, N))).astype(dtype),
+                (0.3 * jax.random.normal(ks[4], (B, S, G, N))).astype(dtype),
+                jax.random.normal(ks[5], (H,), jnp.float32))
+        g = jax.random.normal(ks[6], (B, S, H, P), dtype)
+
+        def both(f):
+            out, vjp = jax.vjp(lambda *a: f(*a, 128), *args)
+            return out, vjp(g)
+
+        got, grads = jax.jit(lambda: both(
+            lambda *a: ssm.ssd_scan(*a[:-1], chunk=a[-1])))()
+        # float32 operands: the kernels' products, like XLA's on the
+        # chip, run at the default precision (bf16 passes), so the oracle
+        # is the composition as the chip runs it and the tolerance bf16's
+        want, refs = jax.jit(lambda: both(ssm._ssd_chunked))()
+        res = {"y_relerr": _relerr([got], [want])}
+        for name, a, r in zip(("dx", "ddt", "dA", "dB", "dC", "dD"),
+                              grads, refs):
+            res[f"{name}_relerr"] = _relerr([a], [r])
+        assert all(v < 3e-2 for v in res.values()), res
+        return res
+    return (f"ssd_scan b{B}s{S}h{H}p{P}g{G}n{N} {jnp.dtype(dtype).name}",
+            check)
+
+
 def _conv_case(N, H, W, Cin, Cout):
     def check():
         from mxnet_tpu.ops.pallas_conv_bwd import (conv3x3_bn_relu_ref,
@@ -370,6 +409,10 @@ def cases():
     out.append(_dsa_select_case(2, 1024, 256, ties=False))
     out.append(_dsa_select_case(1, 8192, 2048, ties=True))
     out.append(_dsa_select_case(1, 2048, 512, ties=True))
+    # the state-space scan: a Nemotron-H mixer at the cell's length, and
+    # a batch of float32 rows whose last chunk is padded
+    out.append(_ssd_scan_case(1, 8192, 64, 64, 8, 128, bf16))
+    out.append(_ssd_scan_case(2, 1000, 8, 64, 1, 128, f32))
     for dtype in (bf16, f32):
         out.append(_ln_case(32 * 128, 768, dtype))       # BERT-base bs32
     out.append(_int8_case(1024, 3072, 768, "gelu"))      # GPT-2 FFN up
