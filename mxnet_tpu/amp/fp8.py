@@ -94,8 +94,14 @@ def current():
 
 
 def record(site, x_amax, w_amax):
+    """A site's forward amaxes into the active scope; a site that runs
+    more than once in a trace (layers looped on shared weights) keeps
+    the largest over its uses."""
     ctx = getattr(_tls, "ctx", None)
     if ctx is not None:
+        seen = ctx.amax.get(site)
+        if seen is not None:
+            x_amax = jnp.maximum(seen[0], x_amax)
         ctx.amax[site] = (x_amax, w_amax)
 
 

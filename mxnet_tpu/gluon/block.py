@@ -64,17 +64,17 @@ def resolve_remat_policy(remat):
     """Map a ``hybridize(remat=...)`` value onto a ``jax.checkpoint`` policy.
 
     ``False``/``None`` — off. ``True`` — full rematerialization (only the
-    inputs are saved; everything recomputes in the backward pass).
-    ``'dots'`` — selective: matmul/einsum outputs are saved, cheap
-    elementwise ops recompute (``jax.checkpoint_policies.dots_saveable``,
-    the usual sweet spot for transformer blocks).
+    inputs are saved). ``'dots'`` — matmul/einsum outputs are saved, cheap
+    elementwise ops recompute (``jax.checkpoint_policies.dots_saveable``).
     ``'dots_with_no_batch_dims'`` — save only weight-stationary matmuls.
-    A callable is used as the policy directly.
+    A list or tuple of names — ``save_these``. A callable is the policy.
     """
     if remat is None or remat is False:
         return _REMAT_OFF
     if remat is True:
         return None
+    if isinstance(remat, (list, tuple)):
+        return save_these(*remat)
     if callable(remat):
         return remat
     attr = _REMAT_POLICIES.get(remat)
@@ -378,6 +378,13 @@ class _CachedGraph:
 
 
     def _pure(self, trainable_raws, aux_raws, input_raws, rng_key, sig_key):
+        """The forward as a pure function of the parameters' raws, the
+        call's array arguments and an RNG key -> (output raws, {aux name:
+        the raw the forward rebound it to}).  The call's static leaves and
+        tree are ``_signatures[sig_key]``'s; the output's tree is left in
+        ``_out_trees[sig_key]``.  The parameters' storage holds the traced
+        raws while the forward runs; the trace itself is ``_trace_body``
+        (the file's end), which a boundary at a child block runs too."""
         if self._rw._readers:
             # tracing rebinds the shared Parameter buffers to tracers; doing
             # that while replays hold the read lock (including our own
@@ -388,7 +395,11 @@ class _CachedGraph:
         if sig is None:
             # evicted between registration and (re-)trace — caller retries
             raise _SignatureEvicted(sig_key)
-        treedef, static_leaves = sig
+
+        def run(*fargs, **fkwargs):
+            with autograd._RecordingStateScope(False, self.train_mode):
+                return self.block.forward(*fargs, **fkwargs)
+
         saved = {}
         try:
             for n in self.param_names:
@@ -396,22 +407,11 @@ class _CachedGraph:
                 saved[n] = p._data._data
                 p._data._data = (trainable_raws[n] if n in trainable_raws
                                  else aux_raws[n])
-            markers = {n: self.params[n]._data._data for n in self.aux}
-            leaves = list(static_leaves)
-            it = iter(input_raws)
-            for i, l in enumerate(leaves):
-                if l is _ARR:
-                    leaves[i] = _wrap(next(it))
-            fargs, fkwargs = jax.tree_util.tree_unflatten(treedef, leaves)
-            with autograd._RecordingStateScope(False, self.train_mode), \
-                    _random.trace_key_scope(rng_key):
-                out = self.block.forward(*fargs, **fkwargs)
-            out_leaves, out_tree = _flatten_args(out)
-            out_raws = [l._data if _is_nd(l) else l for l in out_leaves]
+            out_raws, out_tree, mutated = _trace_body(
+                run, {n: self.params[n] for n in self.aux}, sig, input_raws,
+                rng_key)
             with self._sig_lock:  # serialize vs cache-flush dict swaps
                 self._out_trees[sig_key] = out_tree
-            mutated = {n: self.params[n]._data._data for n in self.aux
-                       if self.params[n]._data._data is not markers[n]}
             return out_raws, mutated
         finally:
             for n, raw in saved.items():
@@ -726,10 +726,10 @@ class HybridBlock(Block):
         XLA buffer donation/compiled executables — both are automatic here;
         the flags are accepted for compatibility.
 
-        ``remat=`` selects activation rematerialization for the compiled
-        forward under autograd: True (full), 'dots' / another name from
-        ``resolve_remat_policy``, or a ``jax.checkpoint`` policy callable.
-        ``parallel.ShardedTrainStep`` honors the same flag.
+        ``remat=`` (``resolve_remat_policy``: True, 'dots', a list of names
+        to save, a policy callable) recomputes this block's forward in the
+        backward pass: the compiled forward under autograd, and each call
+        inside an enclosing trace (``_boundary_call``, the file's end).
         """
         resolve_remat_policy(kwargs.get("remat"))  # fail fast on bad values
         self._active = active
@@ -772,6 +772,8 @@ class HybridBlock(Block):
                                     for a in args]
         if not self._active:
             return super().__call__(*args, **kwargs)
+        if self._flags.get("remat") and _traced(args, kwargs):
+            return _boundary_call(self, args, kwargs)
         if self._ensure_init(*args):
             # first call: eager, triggers deferred init (the reference's
             # _build_cache also runs a traced forward first, block.py:1095)
@@ -969,3 +971,132 @@ class SymbolBlock(HybridBlock):
         raise MXNetError(
             f"{symbol_file} has no stablehlo graph artifact; re-export with "
             "HybridBlock.export (or pass allow_class_fallback=True)")
+
+
+# -- a recomputation boundary at a child block ------------------------------
+# (kept at the file's end: a line added above ``Block.__call__`` or
+# ``HybridBlock.__call__`` moves the call-stack locations a Mosaic kernel's
+# body records, and with them every compiled step's cache key)
+
+#: boundaries open on this thread: a flagged block inside one is part of it
+_boundary_tls = threading.local()
+
+
+def save_these(*names):
+    """The ``jax.checkpoint`` policy ``hybridize(remat=[names...])`` means:
+    a value is saved if ``jax.ad_checkpoint.checkpoint_name`` gave it one
+    of ``names``, or if the primitive that made it is called one of them
+    (``"pallas_call"``: what a Pallas kernel wrote, its residuals
+    included); everything else is made again in the backward pass."""
+    for n in names:
+        if not isinstance(n, str):
+            raise MXNetError(f"a remat name is a string, got {n!r}")
+    names = frozenset(names)
+    return jax.checkpoint_policies.save_from_both_policies(
+        jax.checkpoint_policies.save_only_these_names(*names),
+        lambda prim, *_, **__: prim.name in names)
+
+
+def _trace_body(run, aux, sig, input_raws, rng_key):
+    """What every traced forward of a block does, whichever transform
+    traces it (``_CachedGraph._pure`` under its own ``jit``,
+    ``_boundary_call`` under ``jax.checkpoint`` inside an enclosing
+    trace): the call's arguments are put together again from ``sig`` =
+    (tree, leaves with ``_ARR`` where an array was) and the traced
+    ``input_raws``; ``run`` is called on them with ``rng_key`` — an
+    argument of the traced function — as the stream ``_random._next_key``
+    splits, so no draw inside leaves a tracer of this trace in an outer
+    stream; and what the forward left in the ``aux`` parameters' storage
+    ({name: Parameter}) is gathered to leave the trace as a result.
+    -> (output raws, the output's tree, {name: raw} of the aux rebound).
+    The caller owns the storage: it binds what the forward is to read
+    before, and puts back what was there after."""
+    treedef, static_leaves = sig
+    markers = {n: p._data._data for n, p in aux.items()}
+    it = iter(input_raws)
+    leaves = [_wrap(next(it)) if l is _ARR else l for l in static_leaves]
+    fargs, fkwargs = jax.tree_util.tree_unflatten(treedef, leaves)
+    with _random.trace_key_scope(rng_key):
+        out = run(*fargs, **fkwargs)
+    out_leaves, out_tree = _flatten_args(out)
+    out_raws = [l._data if _is_nd(l) else l for l in out_leaves]
+    mutated = {n: p._data._data for n, p in aux.items()
+               if p._data._data is not markers[n]}
+    return out_raws, out_tree, mutated
+
+
+def _traced(args, kwargs):
+    """Is any array among the arguments a tracer, i.e. is the block being
+    called inside an enclosing trace (a train step's ``functional_call``,
+    a hybridized parent's compiled forward)?"""
+    return any(_is_nd(l) and isinstance(l._data, jax.core.Tracer)
+               for l in _flatten_args((args, kwargs))[0])
+
+
+def _aux_params(block):
+    """The untrained parameters that hold a value, of ``block`` and its
+    descendants — walked without ``collect_params``, which renames every
+    parameter by its path from the block it is called on (an fp8 step
+    finds a ``Dense``'s site by that name)."""
+    out = [p for p in block._reg_params.values()
+           if p.grad_req == "null" and p._data is not None]
+    for child in block._children.values():
+        out += _aux_params(child)
+    return out
+
+
+def _boundary_call(block, args, kwargs):
+    """``block`` carries ``hybridize(remat=...)`` and is called inside an
+    enclosing trace: its forward runs inline under ``jax.checkpoint`` with
+    the flag's policy, so the enclosing backward keeps only what the
+    policy saves of this call and makes the rest again when it reaches
+    it.  The outermost flagged block on a call path is the boundary; the
+    flagged blocks it calls run plainly inside it.  Parameters reach the
+    forward as they do without the flag (through their storage, as the
+    enclosing trace bound it).  Every side channel of a forward crosses
+    the region as an argument or a result, never as a tracer left
+    behind: the RNG key is drawn from the enclosing stream and handed in
+    (``_trace_body``); aux state the forward rebinds, and the amaxes an
+    fp8 step's ``Dense`` records in its scope, are handed out and put in
+    their place outside it."""
+    if getattr(_boundary_tls, "open", 0):
+        return Block.__call__(block, *args, **kwargs)
+    from ..amp import fp8 as _fp8
+    policy = resolve_remat_policy(block._flags["remat"])
+    leaves, treedef = _flatten_args((args, kwargs))
+    sig = (treedef, [_ARR if _is_nd(l) else l for l in leaves])
+    aux = dict(enumerate(_aux_params(block)))
+    scope = _fp8.current()
+    out_trees = []
+
+    def fn(input_raws, rng_key):
+        before = {n: p._data._data for n, p in aux.items()}
+        amax_before = dict(scope.amax) if scope is not None else {}
+        try:
+            out_raws, out_tree, mutated = _trace_body(
+                lambda *a, **k: Block.__call__(block, *a, **k), aux, sig,
+                input_raws, rng_key)
+            amax = {s: v for s, v in scope.amax.items()
+                    if v is not amax_before.get(s)} \
+                if scope is not None else {}
+        finally:
+            for n, raw in before.items():
+                aux[n]._data._data = raw
+            if scope is not None:
+                scope.amax.clear()
+                scope.amax.update(amax_before)
+        out_trees.append(out_tree)
+        return out_raws, mutated, amax
+
+    _boundary_tls.open = 1
+    try:
+        out_raws, mutated, amax = jax.checkpoint(fn, policy=policy)(
+            [l._data for l in leaves if _is_nd(l)], _random._next_key())
+    finally:
+        _boundary_tls.open = 0
+    for n, raw in mutated.items():
+        aux[n]._data._rebind(raw)
+    if scope is not None:
+        scope.amax.update(amax)
+    return jax.tree_util.tree_unflatten(
+        out_trees[-1], [_wrap(r) for r in out_raws])

@@ -6,4 +6,5 @@ from . import afmoe  # noqa: F401
 from . import keye  # noqa: F401
 from . import nemotron_h  # noqa: F401
 from . import glm4_moe_lite  # noqa: F401
+from . import ouro  # noqa: F401
 from . import model_store  # noqa: F401

@@ -138,10 +138,10 @@ class ShardedTrainStep:
     grad_accum: accumulate gradients over K lax.scan microbatches before
         ONE optimizer update (batch arrays gain a leading K axis).
         Distinct from steps_per_call, which applies an update every step.
-    remat: activation rematerialization for the fwd/bwd inside the step —
-        same values as ``HybridBlock.hybridize(remat=...)`` (True,
-        'dots', a policy callable); None inherits the block's hybridize
-        flag.
+    remat: recompute the whole fwd inside the step's bwd — the values of
+        ``HybridBlock.hybridize(remat=...)`` (True, 'dots', names, a
+        policy); None inherits the block's own flag.  A flag on a *child*
+        (``layer.hybridize(remat=...)``) bounds that child's call alone.
     precision: "fp32" (default) or "fp8" — fp8 runs eligible Dense
         matmuls e4m3-forward / e5m2-backward with per-tensor delayed
         scaling (mx.amp.fp8); the amax histories thread through the step

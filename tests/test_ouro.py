@@ -1,0 +1,618 @@
+"""The looped decoder family (gluon/model_zoo/ouro.py: a stack whose
+layers run ``total_ut_steps`` times on shared weights, an exit gate, a
+loss over every exit through the chunked head) against the benchmark's
+plain reference (chipbench/reference/ouro.py, importing nothing of the
+program), on seeded random weights at small sizes on the CPU; and the
+recomputation boundary at a child block (``layer.hybridize(remat=...)``
+inside ``ShardedTrainStep``, gluon/block.py) that the cell cannot load
+without.
+
+Tolerances: the suite computes float32 products exactly
+(``jax_default_matmul_precision`` float32) and the comparisons below ask
+the program for ``highest`` too, so program and reference differ by the
+order of float32 sums only: losses to 2e-5 of ~4.2, gradients to rtol
+2e-3 / atol 3e-6 (the zoo tests' own bounds).  A bf16 product anywhere
+moves a loss by 1e-3 and a gradient leaf by percents: neither passes.
+"""
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as onp
+import pytest
+
+import mxnet_tpu as mx
+from mxnet_tpu import functional
+from mxnet_tpu.gluon.block import save_these
+from mxnet_tpu.gluon.model_zoo import ouro as zoo
+from mxnet_tpu.ops.xent import sparse_softmax_xent
+from mxnet_tpu.parallel import MeshConfig, ShardedTrainStep
+
+from test_nemotron_h import _AS_BEFORE, _jaxpr_text
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _chipbench(kind):
+    path = os.path.join(_REPO, "chipbench", kind, "ouro.py")
+    spec = importlib.util.spec_from_file_location(
+        f"chipbench_{kind}_ouro", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+REF, FAMILY, FLOPS = (_chipbench(k) for k in ("reference", "families",
+                                              "flops"))
+
+CFG = {
+    "hidden_size": 64, "intermediate_size": 96, "num_attention_heads": 4,
+    "num_key_value_heads": 4, "head_dim": 16, "vocab_size": 64,
+    "num_hidden_layers": 2, "total_ut_steps": 3, "rms_norm_eps": 1e-6,
+    "rope_theta": 1e6, "entropy_beta": 0.1, "layer_remat": None,
+    "published": {"num_hidden_layers": 48},
+}
+SIZES = {
+    "small": CFG,
+    # two KV heads under four query heads, four passes over one layer
+    "grouped": dict(CFG, num_key_value_heads=2, num_hidden_layers=1,
+                    total_ut_steps=4),
+    "boundaries": dict(CFG, layer_remat=["attn.qkv", "attn.proj"]),
+}
+OPT = {"lr": 1e-3, "beta1": 0.9, "beta2": 0.999, "epsilon": 1e-8}
+
+
+def _tokens(cfg, batch=2, seq=24, seed=0):
+    t = onp.random.default_rng(seed).integers(
+        0, cfg["vocab_size"], (batch, seq + 1), dtype=onp.int32)
+    return t[:, :-1], t[:, 1:]
+
+
+def _reference_loss(cfg, weights, x, y):
+    def loss(p):
+        total, pdf = REF.batch_loss(p, jnp.asarray(x), jnp.asarray(y), cfg)
+        return total / x.size, pdf / x.size
+
+    return jax.jit(jax.value_and_grad(loss, has_aux=True))(dict(weights))
+
+
+def _program_loss(cfg, weights, x, y):
+    net = FAMILY.build_net(cfg, weights)
+    trainable, aux = functional.split_params(net)
+    assert list(aux) == ["exit.pdf"]
+
+    def loss(tr):
+        out, mutated = functional.functional_call(
+            net, {**tr, **aux}, x, train=True)
+        return FAMILY.loss_fn(out, y), mutated
+
+    with jax.default_matmul_precision("highest"):
+        return jax.value_and_grad(loss, has_aux=True)(trainable)
+
+
+# ---- the zoo model against the reference ---------------------------------
+
+@pytest.mark.parametrize("size", list(SIZES))
+def test_zoo_model_loss_gradients_and_exit_distribution(size):
+    cfg = SIZES[size]
+    weights = FAMILY.make_weights(cfg, 7)
+    x, y = _tokens(cfg)
+    (got, mutated), grads = _program_loss(cfg, weights, x, y)
+    (want, pdf), ref_grads = _reference_loss(cfg, weights, x, y)
+    assert abs(float(got) - float(want)) < 2e-5
+    stacked = FAMILY.stack_program_tree(grads, cfg["num_hidden_layers"])
+    assert set(stacked) == set(ref_grads)
+    for name, ref in ref_grads.items():
+        onp.testing.assert_allclose(stacked[name], ref, atol=3e-6,
+                                    rtol=2e-3, err_msg=name)
+    # the gate learns (from both terms), and every exit has its share
+    assert float(jnp.linalg.norm(ref_grads["gate.w"])) > 1e-4
+    onp.testing.assert_allclose(mutated["exit.pdf"], pdf, atol=1e-6)
+    assert abs(float(pdf.sum()) - 1.0) < 1e-6 and float(pdf.min()) > 0.05
+
+
+def test_a_shared_leafs_gradient_is_the_sum_over_its_uses():
+    """The reference's own statement of it: the loop written over T
+    *copies* of the stack gives one gradient a copy, and their sum is
+    the looped model's gradient of the shared leaf — in the reference by
+    autodiff of the plain loop, in the program through the parameter
+    swap."""
+    cfg = CFG
+    weights = dict(FAMILY.make_weights(cfg, 3))
+    x, y = _tokens(cfg, batch=1)
+    steps, n = cfg["total_ut_steps"], cfg["num_hidden_layers"]
+
+    def unshared(copies):
+        """The same computation over a stack of T x N layers' leaves that
+        happen to hold the same values: layer i of pass t reads row
+        t * N + i."""
+        p = dict(weights, **copies)
+        layer = jax.checkpoint(lambda h, q: REF._layer(h, q, cfg))
+        h, states = p["wte"][x[0]], []
+        for t in range(steps):
+            for i in range(n):
+                h = layer(h, {k: p[k][t * n + i] for k in REF.LAYER_LEAVES})
+            h = REF._rms(h, p["ln_f.g"], cfg["rms_norm_eps"])
+            states.append(h)
+        log_p = REF.exit_log_pdf(p, states)
+        ce = jnp.stack([REF.exit_xent(z, p["head.w"], jnp.asarray(y[0]))
+                        for z in states])
+        return jnp.mean(jnp.sum(jnp.exp(log_p) * (
+            ce + cfg["entropy_beta"] * log_p), axis=0))
+
+    copies = {k: jnp.concatenate([weights[k]] * steps)
+              for k in REF.LAYER_LEAVES}
+    with jax.default_matmul_precision("highest"):
+        per_use = jax.grad(unshared)(copies)
+    (_, _), ref_grads = _reference_loss(cfg, weights, x, y)
+    (_, _), grads = _program_loss(cfg, weights, x, y)
+    stacked = FAMILY.stack_program_tree(grads, n)
+    for k in REF.LAYER_LEAVES:
+        summed = per_use[k].reshape((steps, n) + weights[k].shape[1:]).sum(0)
+        for name, got in (("reference", ref_grads[k]), ("program",
+                                                        stacked[k])):
+            onp.testing.assert_allclose(got, summed, atol=3e-6, rtol=2e-3,
+                                        err_msg=f"{k} ({name})")
+        # and no single use is the whole of it
+        first = per_use[k][:n]
+        assert float(jnp.linalg.norm(first - summed)) \
+            > 0.05 * float(jnp.linalg.norm(summed)), k
+
+
+def test_one_pass_is_the_same_stack_unlooped_and_has_no_loop_residue():
+    """``total_ut_steps=1``: one exit that takes everything (p = 1, no
+    entropy), so the loss is the plain stack's mean cross-entropy, and
+    the trace holds no loop."""
+    cfg = dict(CFG, total_ut_steps=1)
+    weights = FAMILY.make_weights(cfg, 5)
+    x, y = _tokens(cfg)
+    net = FAMILY.build_net(cfg, weights)
+    params = functional.param_arrays(net)
+
+    def states(x_):
+        return functional.functional_call(net, params, x_, train=True)[0]
+
+    with jax.default_matmul_precision("highest"):
+        h, w, log_p = states(x)
+        text = str(jax.make_jaxpr(states)(x))
+    assert h.shape == (1, 2, 24, 64) and not float(jnp.abs(log_p).max())
+    assert "scan" not in text and "while" not in text
+    with jax.default_matmul_precision("highest"):
+        plain = jnp.mean(sparse_softmax_xent(
+            jnp.einsum("bsd,vd->bsv", h[0], w), y))
+        got = FAMILY.loss_fn((h, w, log_p), y)
+    (want, _), _ = _reference_loss(cfg, weights, x, y)
+    assert abs(float(got) - float(plain)) < 1e-6
+    assert abs(float(got) - float(want)) < 2e-5
+
+
+def test_the_exit_distribution_sums_to_one_and_beta_zero_leaves_the_expected_xent():
+    cfg = dict(CFG, total_ut_steps=4)
+    weights = dict(FAMILY.make_weights(cfg, 9))
+    # a gate far from its seeded 0.5, saturating on some tokens
+    weights["gate.w"] = weights["gate.w"] * 400.0
+    net = FAMILY.build_net(cfg, weights)
+    x, y = _tokens(cfg)
+    with jax.default_matmul_precision("highest"):
+        out, mutated = functional.functional_call(
+            net, functional.param_arrays(net), x, train=True)
+        h, w, log_p = out
+        p = jnp.exp(log_p)
+        onp.testing.assert_allclose(p.sum(0), 1.0, atol=2e-6)
+        assert float(p.max()) > 0.99 and float(p.min()) < 1e-3
+        onp.testing.assert_allclose(mutated["exit.pdf"], p.mean((1, 2)),
+                                    atol=1e-6)
+        ce = jnp.stack([sparse_softmax_xent(
+            jnp.einsum("bsd,vd->bsv", h[t], w), y) for t in range(4)])
+        expected = jnp.mean(jnp.sum(p * ce, axis=0))
+        got0 = zoo.looped_lm_loss(out, y, beta=0.0)
+        got = zoo.looped_lm_loss(out, y, beta=0.1)
+    assert abs(float(got0) - float(expected)) < 2e-6
+    entropy = -jnp.mean(jnp.sum(p * log_p, axis=0))
+    assert float(entropy) > 5e-3
+    assert abs(float(got) - float(expected - 0.1 * entropy)) < 2e-6
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-6), ("bfloat16", 0.0)])
+def test_the_stacked_chunked_head_against_four_dense_heads(dtype, tol):
+    """``chunked_lm_xent`` over the exits stacked to (T b s, units), in
+    vocabulary chunks that do not divide the vocabulary, against one
+    dense ``sparse_softmax_xent`` head an exit: values, and gradients to
+    the states, the head and the token weights.  In bfloat16 both take
+    bf16 operands and accumulate in float32, so the losses agree to the
+    last bit of the dense head's bf16 logits: compared at float32 after
+    rounding the dense logits as the dense head stores them."""
+    rng = onp.random.default_rng(3)
+    steps, tokens, units, vocab = 4, 48, 32, 100
+    h = jnp.asarray(rng.normal(size=(steps, tokens, units)), dtype)
+    w = jnp.asarray(rng.normal(size=(vocab, units)) * 0.3, dtype)
+    labels = jnp.asarray(rng.integers(0, vocab, tokens), jnp.int32)
+    log_p = jax.nn.log_softmax(
+        jnp.asarray(rng.normal(size=(steps, tokens)), jnp.float32), axis=0)
+
+    def stacked(h, w, log_p):
+        return zoo.looped_lm_loss((h[:, None], w, log_p[:, None]),
+                                  labels[None], beta=0.1, chunk=48)
+
+    def dense(h, w, log_p):
+        ce = jnp.stack([sparse_softmax_xent(
+            jnp.einsum("sd,vd->sv", h[t], w,
+                       preferred_element_type=jnp.float32), labels)
+            for t in range(steps)])
+        return jnp.mean(jnp.sum(jnp.exp(log_p) * (ce + 0.1 * log_p), axis=0))
+
+    with jax.default_matmul_precision("highest"):
+        got, g_got = jax.value_and_grad(stacked, (0, 1, 2))(h, w, log_p)
+        want, g_want = jax.value_and_grad(dense, (0, 1, 2))(h, w, log_p)
+    assert abs(float(got) - float(want)) <= (tol or 1e-6)
+    # bf16: dz and dW are bf16 sums of float32-accumulated products on
+    # both sides, a unit in the last place of bf16 (0.8 %) apart at most
+    rtol, atol = (1e-4, 1e-6) if dtype == "float32" else (1.6e-2, 2e-4)
+    for a, b in zip(g_got, g_want):
+        onp.testing.assert_allclose(onp.asarray(a, onp.float32),
+                                    onp.asarray(b, onp.float32),
+                                    rtol=rtol, atol=atol)
+
+
+def test_the_sharded_step_follows_the_reference_and_keeps_the_exit_pdf():
+    """Three updates through ``ShardedTrainStep``, the layers flagged as
+    the cell flags them: losses, the first gradient (from Adam's first
+    moment), the parameters' change and ``exit.pdf`` in the step's
+    ``aux`` against the reference's."""
+    cfg = dict(CFG, layer_remat=["attn.qkv"])
+    weights = FAMILY.make_weights(cfg, 11)
+    batches = [_tokens(cfg, seed=s) for s in (4, 5, 6)]
+    with jax.default_matmul_precision("highest"):
+        net = FAMILY.build_net(cfg, weights)
+        mesh = MeshConfig(dp=1)
+        step = ShardedTrainStep(
+            net, FAMILY.loss_fn, mx.optimizer.create(
+                "adam", learning_rate=OPT["lr"], beta1=OPT["beta1"],
+                beta2=OPT["beta2"], epsilon=OPT["epsilon"]), mesh,
+            batch_specs=mesh.batch_specs(2, 2), n_labels=1)
+        assert not step._remat_on          # the flag is the layers' alone
+        losses, first = [], None
+        for bx, by in batches:
+            losses.append(float(step(bx, by).asnumpy()))
+            if first is None:
+                first = {n: onp.asarray(s[0]) / (1 - OPT["beta1"])
+                         for n, s in step.states.items()}
+        change = jax.device_get(FAMILY.change_norms(cfg, 11, step.trainable))
+        ref = REF.train_reference(lambda: FAMILY.make_weights(cfg, 11),
+                                  batches, cfg, OPT)
+    onp.testing.assert_allclose(losses, ref["losses"], atol=2e-5)
+    n = cfg["num_hidden_layers"]
+    norms = {k: onp.sqrt((v.astype(onp.float64) ** 2).sum(
+        axis=tuple(range(1, v.ndim)) if k in REF.STACKED else None))
+        for k, v in FAMILY.stack_program_tree(first, n).items()}
+    g_gaps = REF.leaf_gaps({k: onp.atleast_1d(v) for k, v in norms.items()},
+                           ref["grad_norms"])
+    assert max(g_gaps.values()) < 1e-3, REF.worst_leaf(g_gaps)
+    c_gaps = REF.leaf_gaps(FAMILY.stack_program_tree(change, n),
+                           ref["change_norms"])
+    # Adam divides by sqrt(v): a float32 rounding of a small gradient
+    # entry moves its step by more than it moves the gradient's norm
+    assert max(c_gaps.values()) < 5e-3, REF.worst_leaf(c_gaps)
+    assert c_gaps["exit.pdf"] < 1e-5
+    assert FAMILY.last_pdf and abs(sum(FAMILY.last_pdf) - 1) < 1e-5
+    onp.testing.assert_allclose(step.aux["exit.pdf"],
+                                ref["change_norms"]["exit.pdf"], atol=1e-5)
+
+
+def test_needed_flops_against_hand_numbers():
+    """ISSUE 42's arithmetic at the published widths, four layers."""
+    cfg = dict(hidden_size=2048, intermediate_size=5632, head_dim=128,
+               num_attention_heads=16, num_key_value_heads=16,
+               vocab_size=49152, num_hidden_layers=4, total_ut_steps=4)
+    layer = 2 * 4 * 2048 * 2048 + 4 * 2048 * 4096 + 6 * 2048 * 5632
+    assert layer == 136_314_880 == FLOPS.layer_flops_per_token(cfg, 8192)
+    head = 2 * 2048 * 49152
+    assert head == 201_326_592 == FLOPS.head_flops_per_token(cfg)
+    want = 16 * layer + 4 * head + 3 * 2 * 2048
+    assert FLOPS.forward_flops_per_token(cfg, 8192) == want == 2_986_356_736
+    assert FLOPS.train_flops_per_token(cfg, 8192) == 8_959_070_208
+    assert round(100 * 16 * layer / want) == 73
+    assert round(100 * 4 * head / want) == 27
+    # one pass is a plain four-layer decoder
+    assert FLOPS.forward_flops_per_token(dict(cfg, total_ut_steps=1), 8192) \
+        == 4 * layer + head
+    assert FAMILY.n_params(dict(cfg)) == 406_884_353 \
+        == 4 * 51_388_416 + 2 * 100_663_296 + 2048 + 2049
+
+
+# ---- the recomputation boundary at a child block -------------------------
+
+def _step_of(cfg, seed=13):
+    with jax.default_matmul_precision("highest"):
+        net = FAMILY.build_net(cfg, FAMILY.make_weights(cfg, seed))
+        mesh = MeshConfig(dp=1)
+        return ShardedTrainStep(
+            net, FAMILY.loss_fn,
+            mx.optimizer.create("adam", learning_rate=1e-3), mesh,
+            batch_specs=mesh.batch_specs(2, 2), n_labels=1)
+
+
+@pytest.mark.parametrize("remat", [True, "dots", ["attn.qkv", "ffn.inner"],
+                                   ["dot_general"]])
+def test_a_flagged_child_inside_the_step_changes_no_value(remat):
+    """Three updates with every layer application a boundary against
+    three without: the losses equal to the last bit, the first gradient
+    (Adam's first moment after one update) and the parameters after
+    three to float32 rounding — the replayed forward is the forward's
+    own operations, but XLA groups them into other fusions the second
+    time, so a last bit of a sum may differ (seen here: 7e-8 on a
+    parameter, three updates of Adam after 4e-9 on a gradient entry, 1e-5
+    of the leaf's largest); a
+    forward replayed in another precision, or from other inputs, is
+    1e-3 away."""
+    x, y = _tokens(CFG, seed=2)
+    plain, flagged = _step_of(CFG), _step_of(dict(CFG, layer_remat=remat))
+    for i in range(3):
+        a, b = plain(x, y).asnumpy(), flagged(x, y).asnumpy()
+        assert a == b
+        if i == 0:
+            for n, s in plain.states.items():
+                m = onp.asarray(s[0])
+                onp.testing.assert_allclose(
+                    m, onp.asarray(flagged.states[n][0]), rtol=0,
+                    atol=1e-5 * onp.abs(m).max(), err_msg=n)
+    for n, w in plain.trainable.items():
+        onp.testing.assert_allclose(onp.asarray(w),
+                                    onp.asarray(flagged.trainable[n]),
+                                    rtol=0, atol=1e-5, err_msg=n)
+    onp.testing.assert_allclose(plain.aux["exit.pdf"],
+                                flagged.aux["exit.pdf"], atol=1e-7)
+
+
+def test_the_lowered_step_holds_one_region_a_flagged_application():
+    """One ``remat2`` region a layer application in the step's jaxpr
+    (T x N of them), none nested inside another although ``hybridize``
+    flags every descendant, and none without the flag; the lowered text
+    names the replayed forward ``rematted_computation``."""
+    x, y = _tokens(CFG, seed=2)
+    apps = CFG["num_hidden_layers"] * CFG["total_ut_steps"]
+
+    def regions(step):
+        net, params = step.block, {**step.trainable, **step.aux}
+
+        def loss(p):
+            out, _ = functional.functional_call(net, p, x, train=True)
+            return FAMILY.loss_fn(out, y)
+
+        return str(jax.make_jaxpr(loss)(params)).count("= remat2[")
+
+    flagged = _step_of(dict(CFG, layer_remat=["attn.qkv"]))
+    assert flagged.block.backbone.layer0.attention._flags["remat"]
+    assert regions(flagged) == apps
+    text = flagged.lower(x, y).as_text(debug_info=True)
+    assert "rematted_computation" in text
+    assert text.count("optimization_barrier") >= apps
+    plain = _step_of(CFG)
+    assert regions(plain) == 0
+    text = plain.lower(x, y).as_text(debug_info=True)
+    assert "rematted_computation" not in text and "checkpoint" not in text
+
+
+def test_an_unflagged_block_traces_what_it_traced():
+    """The boundary is opt-in: a block that was never ``hybridize``d, and
+    one hybridized without ``remat``, called inside a trace, give the
+    jaxpr the pinned files hold (written at the parents of the PRs that
+    pinned them)."""
+    name = "grouped_query_attention_plain"
+    with open(os.path.join(_REPO, "tests", "data",
+                           name + ".jaxpr.txt")) as f:
+        want = f.read()
+    assert _jaxpr_text(_AS_BEFORE[name](), (2, 8, 32)) == want
+    layer = _AS_BEFORE[name]()
+    layer.hybridize()
+    nested = _jaxpr_text(layer, (2, 8, 32))
+    assert "remat2" not in nested and "name=_pure" in nested
+
+
+def test_what_a_policy_of_names_saves():
+    """``save_these``: a value named with ``checkpoint_name``, or made by
+    a primitive of that name, is a residual of the boundary; nothing
+    else is."""
+    from jax.ad_checkpoint import checkpoint_name, print_saved_residuals
+
+    def f(x, w):
+        h = checkpoint_name(jnp.sin(x @ w), "kept")
+        return jnp.sum(jnp.tanh(checkpoint_name(jnp.cos(h), "dropped")))
+
+    x, w = jnp.ones((4, 8)), jnp.ones((8, 8))
+
+    def saved(policy):
+        import contextlib
+        import io
+        buf = io.StringIO()
+        with contextlib.redirect_stdout(buf):
+            print_saved_residuals(jax.checkpoint(f, policy=policy), x, w)
+        return buf.getvalue()
+
+    def made(text):
+        """Where the residuals that are no argument were made."""
+        return [l.split(" from ")[1] for l in text.splitlines()
+                if "from the argument" not in l]
+
+    kept, dropped = made(saved(save_these("kept"))), \
+        made(saved(save_these("dropped")))
+    assert len(kept) == len(dropped) == 1 and kept != dropped
+    by_prim = made(saved(save_these("dot_general")))
+    assert len(by_prim) == 1 and by_prim not in (kept, dropped)
+    assert made(saved(save_these())) == []
+    assert len(made(saved(save_these("kept", "dropped")))) == 2
+    with pytest.raises(mx.base.MXNetError):
+        save_these("kept", 3)
+    net = mx.gluon.nn.Dense(4)
+    net.hybridize(remat=["kept"])
+    assert net._flags["remat"] == ["kept"]
+
+
+def test_a_boundary_hands_aux_state_out_of_its_region():
+    """Aux state a flagged child rebinds inside its forward (the expert
+    layers' counts, BatchNorm's running statistics) reaches the
+    enclosing ``functional_call``'s ``mutated`` as without the flag."""
+    from mxnet_tpu.gluon import nn
+
+    def run(remat):
+        mx.random.seed(0)
+        net = nn.HybridSequential()
+        net.add(nn.Dense(8, in_units=6, flatten=False), nn.BatchNorm(axis=-1,
+                in_channels=8))
+        net.initialize()
+        if remat is not None:
+            net[1].hybridize(remat=remat)
+        params = functional.param_arrays(net)
+        x = jnp.asarray(onp.random.default_rng(0).normal(size=(5, 6)),
+                        jnp.float32)
+
+        def loss(p):
+            out, mutated = functional.functional_call(net, p, x, train=True)
+            return jnp.sum(out ** 2), mutated
+
+        return jax.jit(jax.value_and_grad(loss, has_aux=True))(params)
+
+    (a, mut_a), g_a = run(None)
+    (b, mut_b), g_b = run(True)
+    assert float(a) == float(b) and set(mut_a) == set(mut_b) and mut_a
+    for n in mut_a:
+        onp.testing.assert_array_equal(mut_a[n], mut_b[n])
+    for n in g_a:
+        onp.testing.assert_allclose(g_a[n], g_b[n], rtol=1e-6, atol=1e-7)
+
+
+class _DropCell(mx.gluon.nn.HybridBlock):
+    """x + dropout(dense(x)): a child that draws a random number."""
+
+    def __init__(self):
+        super().__init__()
+        self.dense = mx.gluon.nn.Dense(8, in_units=8, flatten=False)
+        self.drop = mx.gluon.nn.Dropout(0.5)
+
+    def forward(self, x):
+        return x + self.drop(self.dense(x))
+
+
+def _drop_net(remat, parent_hybridized=False, head=True):
+    from mxnet_tpu.gluon import nn
+    mx.random.seed(7)
+    net = nn.HybridSequential()
+    net.add(_DropCell(), _DropCell())
+    if head:
+        net.add(nn.Dense(4, in_units=8, flatten=False))
+    net.initialize()
+    if parent_hybridized:
+        net.hybridize()     # first: it sets every descendant's flags
+    for cell in (net[0], net[1]):
+        cell.hybridize(remat=remat)
+    return net
+
+
+def _keep_all(*_, **__):
+    """A policy under which a boundary saves everything: nothing is made
+    again, so no mask is drawn a second time."""
+    return True
+
+
+@pytest.mark.parametrize("where", ["sharded_step", "hybridized_parent"])
+def test_a_boundary_takes_its_rng_key_as_an_argument(where):
+    """Two flagged children that each hold a Dropout, inside
+    ``ShardedTrainStep`` and under a hybridized parent.  The key a
+    boundary's forward splits is drawn in the enclosing trace and handed
+    in as an argument, so the second child meets no tracer of the first's
+    region (an ``UnexpectedTracerError`` before).  The replayed forward
+    draws the mask the forward drew: losses, gradients and updated
+    parameters equal those of a boundary that keeps everything and
+    replays nothing, to float32 rounding (XLA groups the replay
+    otherwise; another mask would move half of a gradient's entries by
+    their own size).  A call draws a fresh mask."""
+    x = onp.random.default_rng(0).normal(size=(2, 6, 8)).astype("float32")
+    y = onp.zeros((2, 6, 4), "float32")
+
+    def run(remat, lr=0.1):
+        net = _drop_net(remat, where == "hybridized_parent")
+        if where == "sharded_step":
+            mesh = MeshConfig(dp=1)
+            step = ShardedTrainStep(
+                net, lambda out, t: jnp.mean((out - t) ** 2),
+                mx.optimizer.create("sgd", learning_rate=lr), mesh,
+                batch_specs=mesh.batch_specs(3, 3), n_labels=1)
+            losses = [float(step(x, y).asnumpy()) for _ in range(3)]
+            return losses, {n: onp.asarray(w)
+                            for n, w in step.trainable.items()}
+        losses = []
+        for _ in range(2):
+            with mx.autograd.record():
+                loss = ((net(mx.np.array(x)) - mx.np.array(y)) ** 2).mean()
+            loss.backward()
+            losses.append(float(loss.asnumpy()))
+        return losses, {n: p.grad().asnumpy()
+                        for n, p in net.collect_params().items()}
+
+    (replayed, a), (kept, b) = run(True), run(_keep_all)
+    onp.testing.assert_allclose(replayed, kept, rtol=1e-6)
+    for n in b:
+        onp.testing.assert_allclose(a[n], b[n], rtol=1e-5, atol=1e-7,
+                                    err_msg=n)
+    still = run(True, lr=0.0)[0]    # the same parameters at every call
+    assert still[0] != still[1]
+
+
+def test_two_boundaries_draw_two_masks_and_a_trace_takes_its_key():
+    """Two flagged cells ``x + dropout(x)`` (their Dense the identity)
+    scale an entry by 1 or 3 each: under one mask the product is 1 or 9,
+    under two it is 3 somewhere.  The keys come from the enclosing
+    trace's stream: the same key gives the same output, another key
+    another."""
+    net = _drop_net(True, head=False)
+    for cell in (net[0], net[1]):
+        cell.dense.weight.set_data(mx.np.array(onp.eye(8, dtype="float32")))
+        cell.dense.bias.set_data(mx.np.zeros((8,)))
+    params = functional.param_arrays(net)
+    x = jnp.ones((4, 16, 8), jnp.float32)
+
+    @jax.jit
+    def forward(key):
+        with mx.random.trace_key_scope(key):
+            out, _ = functional.functional_call(net, params, x, train=True)
+        return out
+
+    out = onp.asarray(forward(jax.random.PRNGKey(3)))
+    assert set(onp.unique(onp.round(out))) == {1.0, 3.0, 9.0}
+    onp.testing.assert_array_equal(out, forward(jax.random.PRNGKey(3)))
+    assert (out != onp.asarray(forward(jax.random.PRNGKey(4)))).any()
+
+
+def test_an_fp8_step_sees_through_a_boundary():
+    """``precision="fp8"`` with the layers flagged: every ``Dense`` inside
+    a boundary still finds its site (the boundary renames no parameter)
+    and the amaxes it records reach the step's histories — the largest
+    over a looped layer's uses — as they do without the flag."""
+    x, y = _tokens(CFG, seed=2)
+
+    def histories(remat):
+        with jax.default_matmul_precision("highest"):
+            net = FAMILY.build_net(dict(CFG, layer_remat=remat),
+                                   FAMILY.make_weights(CFG, 13))
+            mesh = MeshConfig(dp=1)
+            step = ShardedTrainStep(
+                net, FAMILY.loss_fn,
+                mx.optimizer.create("adam", learning_rate=1e-3), mesh,
+                batch_specs=mesh.batch_specs(2, 2), n_labels=1,
+                precision="fp8")
+            losses = [float(step(x, y).asnumpy()) for _ in range(2)]
+        return losses, {s: {k: float(v.max()) for k, v in h.items()}
+                        for s, h in step.extra["fp8"].items()}
+
+    plain_losses, plain = histories(None)
+    flagged_losses, flagged = histories(["attn.qkv"])
+    # seven products a layer; the embedding and the head are sites by
+    # their names and no ``Dense`` runs them (the loss reads the head)
+    layers = {s: h for s, h in plain.items() if ".layer" in s}
+    assert len(layers) == 2 * 7 and set(plain) == set(flagged)
+    onp.testing.assert_allclose(flagged_losses, plain_losses, atol=1e-5)
+    for site, h in layers.items():
+        assert min(h.values()) > 0, site
+        for k in h:
+            assert abs(flagged[site][k] - h[k]) <= 1e-4 * h[k], (site, k)
