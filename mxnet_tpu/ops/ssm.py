@@ -24,7 +24,14 @@ Raw ``jax`` arrays in, raw arrays out; ``nn.Mamba2Mixer`` composes them.
   ``lax.scan`` over ``seq / chunk`` steps; it is also the kernels'
   oracle.
 * ``causal_conv1d``: depthwise, ``kernel`` taps to the left with a bias,
-  as shifted multiply-adds (optionally through SiLU).
+  as shifted multiply-adds (optionally through SiLU).  The same choice
+  by the same two questions: on a TPU, at shapes
+  ``ops/pallas/ssm_conv.py::fits`` takes, the Pallas kernels
+  ``mx_ssm_conv_fwd`` and, backward, ``mx_ssm_conv_bwd`` — a tile of
+  ``x`` read once in its own type, the whole sequence of a block of
+  channels in VMEM, the taps as lane shifts of it there; everywhere else
+  the XLA composition ``_conv`` (float32 pads and shifted slices), their
+  oracle.
 * ``gated_rms_norm``: ``RMSNorm(y * silu(z)) * w`` over groups of
   channels: the gate first, then the norm.
 
@@ -35,17 +42,22 @@ The four products' operands (``C . B``, the triangular product, the
 chunk's state, the state's output) take the type ``x`` arrives in —
 bfloat16 under ``mx.amp``, as a ``Dense``'s do.
 
-**The backward pass** of the convolution, of the norm and of the
-composition ``_ssd_chunked`` is autodiff, each under ``jax.checkpoint``:
-nothing a function computes inside is kept for its backward pass — not
-the ``(heads, seq, chunk)`` decays, not the chunk states — only its
-arguments, and the function is made again when its gradient is taken (a
-third more scan time for ~0.7 GB a layer at 8192 tokens).  The kernels
-have their own (``jax.custom_vjp``): ``mx_ssd_bwd`` walks the chunks in
-reverse with the state's cotangent in its scratch and makes the decays
-again in VMEM; beside the operands it keeps the float32 state entering
-each chunk (134 MB a layer at 8192 tokens), and the forward is not run
-again.  ``docs/STATE_SPACE.md`` has the equations and the accounting.
+**The backward pass** of the norm and of the compositions ``_conv`` and
+``_ssd_chunked`` is autodiff, each under ``jax.checkpoint``: nothing a
+function computes inside is kept for its backward pass — not the
+``(heads, seq, chunk)`` decays, not the chunk states, not the
+convolution's float32 pad — only its arguments, and the function is made
+again when its gradient is taken (a third more scan time for ~0.7 GB a
+layer at 8192 tokens).  The kernels have their own (``jax.custom_vjp``)
+and no ``jax.checkpoint``: ``mx_ssd_bwd`` walks the chunks in reverse
+with the state's cotangent in its scratch and makes the decays again in
+VMEM; beside the operands it keeps the float32 state entering each chunk
+(134 MB a layer at 8192 tokens), and the forward is not run again.
+``mx_ssm_conv_bwd`` keeps what the checkpoint kept — ``x``, the weights,
+the bias —, walks the sequence's chunks in reverse with ``dy
+silu'(pre)``'s first tokens of the chunk after in registers and makes
+the pre-activation again in VMEM.
+``docs/STATE_SPACE.md`` has the equations and the accounting.
 
 A sequence ``chunk`` does not divide is padded at its end with ``dt = 0``
 and ``x = 0``: a padded step decays nothing and adds nothing, and its
@@ -188,31 +200,34 @@ def _ssd_kernels(x, dt, a_head, b_mat, c_mat, d_skip, chunk):
     return jnp.swapaxes(y, 1, 2).reshape(batch, total, heads, dim)[:, :seq]
 
 
-def _kernel_pass(name, operands, groups, chunk, **static):
-    """``ops/pallas/ssd_scan.py``'s pass ``name`` on operands that all
-    have the batch first (``sparse_index._batch_over_dp``: a ``shard_map``
-    over 'dp' under a mesh, a group's heads whole on every device)."""
+def _kernel_pass(name, operands, *static, **keywords):
+    """``ops/pallas/``'s pass ``name`` (``module.function``) on operands
+    that all have the batch first (``sparse_index._batch_over_dp``: a
+    ``shard_map`` over 'dp' under a mesh, a group's heads and a channel's
+    tokens whole on every device)."""
+    import importlib
     from .. import runtime
-    from .pallas import ssd_scan as kernels
     from .sparse_index import _batch_over_dp
+    module, function = name.split(".")
+    kernels = importlib.import_module(f"{__package__}.pallas.{module}")
 
     def kernel(*args):
-        return getattr(kernels, name)(
-            *args, groups, chunk, interpret=runtime.pallas_interpret(),
-            **static)
+        return getattr(kernels, function)(
+            *args, *static, interpret=runtime.pallas_interpret(), **keywords)
 
     return _batch_over_dp(kernel, *operands)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
 def _scan_kernels(x, b_mat, c_mat, dt, log_decay, d_rows, groups, chunk):
-    return _kernel_pass("scan_pass", (x, b_mat, c_mat, dt, log_decay, d_rows),
+    return _kernel_pass("ssd_scan.scan_pass",
+                        (x, b_mat, c_mat, dt, log_decay, d_rows),
                         groups, chunk, keep=False)
 
 
 def _scan_kernels_fwd(x, b_mat, c_mat, dt, log_decay, d_rows, groups, chunk):
     operands = (x, b_mat, c_mat, dt, log_decay, d_rows)
-    y, entering = _kernel_pass("scan_pass", operands, groups, chunk,
+    y, entering = _kernel_pass("ssd_scan.scan_pass", operands, groups, chunk,
                                keep=True)
     return y, operands + (entering,)
 
@@ -220,7 +235,8 @@ def _scan_kernels_fwd(x, b_mat, c_mat, dt, log_decay, d_rows, groups, chunk):
 def _scan_kernels_bwd(groups, chunk, res, dy):
     # traced under the caller's scopes, ``transpose(jvp(...))`` round
     # them, like any other backward operation
-    return _kernel_pass("scan_bwd_pass", res + (dy,), groups, chunk)
+    return _kernel_pass("ssd_scan.scan_bwd_pass", res + (dy,), groups,
+                        chunk)
 
 
 _scan_kernels.defvjp(_scan_kernels_fwd, _scan_kernels_bwd)
@@ -242,11 +258,66 @@ def causal_conv1d(x, weight, bias, activation=None):
     """``x (b, s, channels)``, ``weight (channels, kernel)``, ``bias
     (channels,)``: ``y_t = bias + sum_k weight[:, k] x_{t - (kernel-1)
     + k}`` with zeros before the sequence, through SiLU where
-    ``activation="silu"``; float32 inside, ``x``'s type out."""
+    ``activation="silu"``; float32 inside, ``x``'s type out.
+
+    Which pass runs is decided by what the call can see, as in
+    ``ssd_scan``: on a TPU, at shapes ``ops/pallas/ssm_conv.py``'s tiles
+    fill (``fits``), the Pallas kernels; everywhere else the XLA
+    composition ``_conv``, which is also their oracle."""
+    from .. import runtime
+    from .pallas import ssm_conv as kernels
     if activation not in (None, "silu"):
         raise ValueError(f"activation {activation!r} is neither None nor "
                          "'silu'")
+    by_kernel = runtime.on_tpu() and kernels.fits(
+        x.shape[1], x.shape[2], weight.shape[1], x.dtype.itemsize)
+    if _telemetry._active:
+        _telemetry.inc("ssm.conv_tokens_total", x.shape[0] * x.shape[1])
+        if by_kernel:
+            _telemetry.inc("ssm.conv_kernel_calls_total")
+    if by_kernel:
+        return _conv_by_kernels(x, weight, bias, activation == "silu")
     return _conv(x, weight, bias, activation == "silu")
+
+
+def _conv_by_kernels(x, weight, bias, silu):
+    """``_conv`` by ``ops/pallas/ssm_conv.py``, which takes ``x``
+    channel-major like the scan's kernels (each ``swapaxes`` a layout to
+    XLA) and the taps' weights with the bias as one float32 operand, a
+    channel's number along 128 lanes, the same every batch row; autodiff
+    takes that operand's gradient back to ``weight`` and ``bias``,
+    summing over the lanes and the batch."""
+    from .pallas.ssm_conv import _LANES
+    columns = jnp.concatenate([weight.astype(_F32).T, bias.astype(_F32)[None]])
+    columns = jnp.broadcast_to(columns[None, :, :, None],
+                               x.shape[:1] + columns.shape + (_LANES,))
+    x = jnp.swapaxes(x, 1, 2)
+    # the scope round the custom_vjp call, as round the scan's, and round
+    # nothing else: what cuts ``x`` out of the projection's output and
+    # joins the scan's three cotangents was outside ``_conv``'s too
+    with jax.named_scope("mx.ssm.conv"):
+        y = _conv_kernels(x, columns, silu)
+    return jnp.swapaxes(y, 1, 2)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _conv_kernels(x, columns, silu):
+    return _kernel_pass("ssm_conv.conv_pass", (x, columns), silu)
+
+
+def _conv_kernels_fwd(x, columns, silu):
+    # the pass itself and not ``_conv_kernels``: a custom_vjp called in
+    # its own forward rule is traced without the caller's scopes
+    return (_kernel_pass("ssm_conv.conv_pass", (x, columns), silu),
+            (x, columns))
+
+
+def _conv_kernels_bwd(silu, res, dy):
+    # traced under the caller's scopes, like the scan's
+    return _kernel_pass("ssm_conv.conv_bwd_pass", res + (dy,), silu)
+
+
+_conv_kernels.defvjp(_conv_kernels_fwd, _conv_kernels_bwd)
 
 
 @functools.partial(jax.checkpoint, static_argnums=(3, 4))

@@ -246,6 +246,13 @@ declare_metric("ssm.scan_kernel_calls_total", "counter",
                "those calls that took the Pallas kernels "
                "(ops/pallas/ssd_scan.py: a TPU and shapes its tiles fill) "
                "and not the XLA composition, once per traced call")
+declare_metric("ssm.conv_tokens_total", "counter",
+               "tokens (batch x sequence) handed to ops.ssm.causal_conv1d, "
+               "once per traced call")
+declare_metric("ssm.conv_kernel_calls_total", "counter",
+               "those calls that took the Pallas kernels "
+               "(ops/pallas/ssm_conv.py: a TPU and shapes its tiles fill) "
+               "and not the XLA composition, once per traced call")
 
 
 # -- switches ---------------------------------------------------------------

@@ -251,9 +251,10 @@ def test_on_the_tpus_route_ssd_scan_takes_the_kernels(monkeypatch):
 
 
 def test_a_steps_kernels_carry_the_scans_scope_both_ways(monkeypatch):
-    """A mixer that takes the kernels: lowered for the TPU, two Mosaic
-    calls, ``mx.ssm.scan`` on the forward one under ``jvp(mx.fwd)`` and on
-    the backward one under ``transpose(jvp(mx.fwd))`` — what
+    """A mixer that takes the kernels: lowered for the TPU, the scan's two
+    Mosaic calls (beside the convolution's two), ``mx.ssm.scan`` on the
+    forward one under ``jvp(mx.fwd)`` and on the backward one under
+    ``transpose(jvp(mx.fwd))`` — what
     ``ssm_scan_ms.train`` and ``bwd_ms.train`` read; and in a train step
     (its kernels interpreted) the scan is no loop, counted once."""
     from mxnet_tpu import functional
@@ -270,7 +271,7 @@ def test_a_steps_kernels_carry_the_scans_scope_both_ways(monkeypatch):
     text = jax.jit(jax.grad(loss)).trace(
         params, jnp.zeros((1, 256, 32), jnp.float32)).lower(
             lowering_platforms=("tpu",)).as_text(debug_info=True)
-    assert text.count("tpu_custom_call") == 2
+    assert text.count("tpu_custom_call") == 4
     assert '/jvp(mx.fwd)/mx.ssm/mx.ssm.scan/mx_ssd_fwd/' in text
     assert '/transpose(jvp(mx.fwd))/mx.ssm/mx.ssm.scan/mx_ssd_bwd/' in text
 

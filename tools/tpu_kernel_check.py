@@ -18,6 +18,8 @@ plain-jnp reference:
                            XLA bisection, with and without ties
     ssd_scan               the state-space scan and its six gradients,
                            against the XLA composition
+    ssm_conv               the mixers' causal convolution and its three
+                           gradients, against the XLA composition
     ln_residual            fwd + bwd, bf16 and fp32
     quantized_matmul       int8 x int8 -> int32
     fp8_matmul             e4m3 and e5m2
@@ -348,6 +350,40 @@ def _ssd_scan_case(B, S, H, P, G, N, dtype):
             check)
 
 
+def _ssm_conv_case(B, S, C, K, dtype):
+    """``causal_conv1d`` as the chip takes it (``mx_ssm_conv_fwd`` and
+    ``mx_ssm_conv_bwd``) against the XLA composition it replaces (its
+    oracle): ``y`` through SiLU and the gradients of ``x``, ``weight``,
+    ``bias``."""
+    def check():
+        from mxnet_tpu.ops import ssm
+        from mxnet_tpu.ops.pallas import ssm_conv
+        assert ssm_conv.fits(S, C, K, jnp.dtype(dtype).itemsize)
+        ks = jax.random.split(jax.random.PRNGKey(7), 4)
+        args = (jax.random.normal(ks[0], (B, S, C), dtype),
+                jax.random.uniform(ks[1], (C, K), jnp.float32, -0.5, 0.5),
+                0.1 * jax.random.normal(ks[2], (C,), jnp.float32))
+        g = jax.random.normal(ks[3], (B, S, C), dtype)
+
+        def both(f):
+            out, vjp = jax.vjp(f, *args)
+            return out, vjp(g)
+
+        got, grads = jax.jit(lambda: both(
+            lambda *a: ssm.causal_conv1d(*a, "silu")))()
+        want, refs = jax.jit(lambda: both(
+            lambda *a: ssm._conv(*a, True)))()
+        res = {"y_relerr": _relerr([got], [want])}
+        for name, a, r in zip(("dx", "dw", "dbias"), grads, refs):
+            res[f"{name}_relerr"] = _relerr([a], [r])
+        # both sides sum in float32 and round once: half a unit of bf16's
+        # last place, 1e-5 in float32
+        tol = 8e-3 if jnp.dtype(dtype) == jnp.bfloat16 else 1e-5
+        assert all(v < tol for v in res.values()), res
+        return res
+    return f"ssm_conv b{B}s{S}c{C}k{K} {jnp.dtype(dtype).name}", check
+
+
 def _conv_case(N, H, W, Cin, Cout):
     def check():
         from mxnet_tpu.ops.pallas_conv_bwd import (conv3x3_bn_relu_ref,
@@ -413,6 +449,10 @@ def cases():
     # a batch of float32 rows whose last chunk is padded
     out.append(_ssd_scan_case(1, 8192, 64, 64, 8, 128, bf16))
     out.append(_ssd_scan_case(2, 1000, 8, 64, 1, 128, f32))
+    # the mixers' convolution: Nemotron-H's at the cell's length, and a
+    # batch of float32 rows in three token blocks of 128
+    out.append(_ssm_conv_case(1, 8192, 6144, 4, bf16))
+    out.append(_ssm_conv_case(2, 384, 264, 4, f32))
     for dtype in (bf16, f32):
         out.append(_ln_case(32 * 128, 768, dtype))       # BERT-base bs32
     out.append(_int8_case(1024, 3072, 768, "gelu"))      # GPT-2 FFN up
