@@ -24,6 +24,7 @@ import os
 import re
 import threading
 import time
+import weakref
 
 import jax
 import jax.numpy as jnp
@@ -726,10 +727,13 @@ class HybridBlock(Block):
         XLA buffer donation/compiled executables — both are automatic here;
         the flags are accepted for compatibility.
 
-        ``remat=`` (``resolve_remat_policy``: True, 'dots', a list of names
-        to save, a policy callable) recomputes this block's forward in the
-        backward pass: the compiled forward under autograd, and each call
-        inside an enclosing trace (``_boundary_call``, the file's end).
+        ``remat=`` (``resolve_remat_policy``: True, 'dots', names to save, a
+        callable) recomputes this block's forward in the backward pass: the
+        compiled forward under autograd, and each call inside a trace (a
+        region: ``_boundary_call``).  Under names the flagged blocks a
+        region calls are regions inside it — a faster schedule for more
+        memory, +6 % to +41 % where it was read — and ``hybridize()`` on
+        the children afterwards takes their flags: one region again.
         """
         resolve_remat_policy(kwargs.get("remat"))  # fail fast on bad values
         self._active = active
@@ -978,7 +982,9 @@ class SymbolBlock(HybridBlock):
 # ``HybridBlock.__call__`` moves the call-stack locations a Mosaic kernel's
 # body records, and with them every compiled step's cache key)
 
-#: boundaries open on this thread: a flagged block inside one is part of it
+#: this thread's boundaries: ``open``, the flags of those open now,
+#: outermost first; ``regions``, how many regions what it traces holds;
+#: ``traced``, block -> the region it was last traced as (weakly keyed)
 _boundary_tls = threading.local()
 
 
@@ -1033,15 +1039,14 @@ def _traced(args, kwargs):
                for l in _flatten_args((args, kwargs))[0])
 
 
-def _aux_params(block):
-    """The untrained parameters that hold a value, of ``block`` and its
+def _held_params(block):
+    """The parameters that hold a value, of ``block`` and its
     descendants — walked without ``collect_params``, which renames every
     parameter by its path from the block it is called on (an fp8 step
     finds a ``Dense``'s site by that name)."""
-    out = [p for p in block._reg_params.values()
-           if p.grad_req == "null" and p._data is not None]
+    out = [p for p in block._reg_params.values() if p._data is not None]
     for child in block._children.values():
-        out += _aux_params(child)
+        out += _held_params(child)
     return out
 
 
@@ -1050,53 +1055,133 @@ def _boundary_call(block, args, kwargs):
     enclosing trace: its forward runs inline under ``jax.checkpoint`` with
     the flag's policy, so the enclosing backward keeps only what the
     policy saves of this call and makes the rest again when it reaches
-    it.  The outermost flagged block on a call path is the boundary; the
-    flagged blocks it calls run plainly inside it.  Parameters reach the
-    forward as they do without the flag (through their storage, as the
-    enclosing trace bound it).  Every side channel of a forward crosses
-    the region as an argument or a result, never as a tracer left
-    behind: the RNG key is drawn from the enclosing stream and handed in
-    (``_trace_body``); aux state the forward rebinds, and the amaxes an
-    fp8 step's ``Dense`` records in its scope, are handed out and put in
-    their place outside it."""
-    if getattr(_boundary_tls, "open", 0):
-        return Block.__call__(block, *args, **kwargs)
+    it.  The outermost flagged block on a call path is the boundary.
+
+    *Regions inside it.*  Where every boundary open round a flagged block
+    has a policy of names, the block opens a region of its own, with its
+    own flag's policy: what such a region hands on is saved by name, so
+    the outer replay reaches the next region without running this one's
+    body, and the inner work is made again once (XLA schedules round the
+    regions' barriers what it does not round one: 3 % of
+    ``ouro-train-8k``'s update, PERF.md section 6, PR 44).  Under every
+    other policy (``True``, ``'dots'``, a callable) nothing cuts the
+    chain and every level would replay what is inside it: there the
+    flagged blocks a boundary calls run plainly inside it.  The flags
+    decide, so the way back to one region is to flag the boundary alone
+    (``hybridize()`` on its children afterwards takes theirs): regions
+    inside a region cost memory, +6 % on ``ouro-train-8k`` and +41 % on
+    a stack of kernel products (tests/test_ouro.py).  An fp8 step keeps
+    one region whatever the flags say: with regions inside it
+    ``ouro-train-8k``'s fp8 control compiled to 18.06 GB against 15.38 of
+    16.9 and did not load — the backward's regions are handed the same
+    values either way, XLA keeps more of them live round the barriers
+    (PERF.md section 7).
+
+    *Side channels.*  Every one crosses the region as an argument or a
+    result, never as a tracer left behind: the parameters' values are
+    read from their storage, as the enclosing trace bound it, handed in
+    and bound for the length of the forward, which finds them where it
+    does without the flag (by no other name: an fp8 step finds a
+    ``Dense``'s site by it); the RNG key is drawn from the enclosing
+    stream and handed in (``_trace_body``); what the forward rebinds of
+    the aux state, and the amaxes an fp8 step's ``Dense`` records in its
+    scope, are handed out and put in their place outside — for a region
+    inside a region that place is the outer region's trace, which hands
+    them on in turn.
+
+    *One trace a block and enclosing trace.*  The traced function holds
+    no value of the enclosing trace, so a block called again in that
+    trace with the same signature (a stack looped on shared weights, or
+    not) hands ``jax.checkpoint`` the function it handed it the first
+    time, and JAX finds its jaxpr, and the regions inside it, by it: the
+    forward's Python runs once, as under ``jit``, and what it reads that
+    is no argument, parameter or part of the key below (a Python
+    attribute, a global) is what it was at that first call.  (Tracing
+    regions inside regions anew each pass took 13 s on the chip's host
+    where one region an application took 3.5.)  The function is kept
+    with the block, for as long as the block lives, and used only in
+    the trace it was made in."""
+    from .. import amp as _amp
     from ..amp import fp8 as _fp8
-    policy = resolve_remat_policy(block._flags["remat"])
+    open_, scope = getattr(_boundary_tls, "open", ()), _fp8.current()
+    if open_ and not (scope is None and all(
+            isinstance(f, (list, tuple)) for f in open_)):
+        return Block.__call__(block, *args, **kwargs)
+    flag = block._flags["remat"]
     leaves, treedef = _flatten_args((args, kwargs))
     sig = (treedef, [_ARR if _is_nd(l) else l for l in leaves])
-    aux = dict(enumerate(_aux_params(block)))
-    scope = _fp8.current()
-    out_trees = []
+    held = _held_params(block)
+    key = (jax.core.get_opaque_trace_state(), treedef,
+           tuple((l.shape, str(l.dtype)) if _is_nd(l) else _static_repr(l)
+                 for l in leaves), tuple(map(id, held)), open_, id(scope),
+           autograd.is_training(), _amp.is_active(),
+           str(_amp.target_dtype()))
+    traced = _boundary_tls.__dict__.setdefault(
+        "traced", weakref.WeakKeyDictionary())
+    seen = traced.get(block)
+    if seen is None or seen["key"] != key:
+        seen = traced[block] = _region(weakref.ref(block), sig, held)
+        seen["key"], seen["policy"] = key, resolve_remat_policy(flag)
 
-    def fn(input_raws, rng_key):
-        before = {n: p._data._data for n, p in aux.items()}
+    # ``regions``: this thread's count of regions in what it traces
+    counted = getattr(_boundary_tls, "regions", 0)
+    _boundary_tls.regions = counted + 1
+    _boundary_tls.open = open_ + (flag,)
+    try:
+        out_raws, mutated, amax = jax.checkpoint(
+            seen["fn"], policy=seen["policy"])(
+            [l._data for l in leaves if _is_nd(l)], _random._next_key(),
+            [p._data._data for p in held])
+    finally:
+        _boundary_tls.open = open_
+    # the regions inside counted themselves while this one was traced;
+    # where its trace is used again they are there again, uncounted
+    if seen.pop("ran", False):
+        seen["inside"], again = _boundary_tls.regions - counted - 1, 0
+    else:
+        again = seen["inside"]
+        _boundary_tls.regions += again
+    _telemetry.inc("block.boundary_regions_total", 1 + again)
+    if again or open_:
+        _telemetry.inc("block.boundary_nested_total", again + bool(open_))
+    for n, raw in mutated.items():
+        held[n]._data._rebind(raw)
+    for site, v in amax.items():
+        _fp8.record(site, *v)
+    return jax.tree_util.tree_unflatten(
+        seen["out_tree"], [_wrap(r) for r in out_raws])
+
+
+def _region(block, sig, held):
+    """What ``_boundary_call`` hands ``jax.checkpoint`` for ``block`` (a
+    weak reference) called as ``sig``: a function of the call's arrays,
+    its RNG key and the values of ``held``, its parameters, that closes
+    over nothing of a trace.  -> {"fn"}; running ``fn`` leaves "ran" and
+    the output's tree there."""
+    from ..amp import fp8 as _fp8
+    seen = {}
+    aux = {n: p for n, p in enumerate(held) if p.grad_req == "null"}
+
+    def fn(input_raws, rng_key, held_raws):
+        seen["ran"], scope = True, _fp8.current()
+        before = [p._data._data for p in held]
         amax_before = dict(scope.amax) if scope is not None else {}
         try:
-            out_raws, out_tree, mutated = _trace_body(
-                lambda *a, **k: Block.__call__(block, *a, **k), aux, sig,
+            for p, raw in zip(held, held_raws):
+                p._data._data = raw
+            if scope is not None:
+                scope.amax.clear()
+            out_raws, seen["out_tree"], mutated = _trace_body(
+                lambda *a, **k: Block.__call__(block(), *a, **k), aux, sig,
                 input_raws, rng_key)
-            amax = {s: v for s, v in scope.amax.items()
-                    if v is not amax_before.get(s)} \
-                if scope is not None else {}
+            amax = dict(scope.amax) if scope is not None else {}
         finally:
-            for n, raw in before.items():
-                aux[n]._data._data = raw
+            for p, raw in zip(held, before):
+                p._data._data = raw
             if scope is not None:
                 scope.amax.clear()
                 scope.amax.update(amax_before)
-        out_trees.append(out_tree)
         return out_raws, mutated, amax
 
-    _boundary_tls.open = 1
-    try:
-        out_raws, mutated, amax = jax.checkpoint(fn, policy=policy)(
-            [l._data for l in leaves if _is_nd(l)], _random._next_key())
-    finally:
-        _boundary_tls.open = 0
-    for n, raw in mutated.items():
-        aux[n]._data._rebind(raw)
-    if scope is not None:
-        scope.amax.update(amax)
-    return jax.tree_util.tree_unflatten(
-        out_trees[-1], [_wrap(r) for r in out_raws])
+    seen["fn"] = fn
+    return seen

@@ -230,10 +230,17 @@ class Trainer:
         return (getattr(self, "_amp_loss_scaler", None) is not None
                 or bool(config.get("trainer.skip_nonfinite")))
 
+    def _grad_raws(self):
+        """Every gradient's raw array; of a row-sparse one the rows it
+        holds (they carry its norm, and whether it is finite)."""
+        grads = [p.grad() for p in self._params
+                 if p.grad_req != "null" and p._data is not None]
+        return [(g.data if hasattr(g, "indices") else g)._data
+                for g in grads]
+
     def _grads_finite(self):
         """One fused XLA reduction over every gradient -> scalar bool."""
-        raws = [p.grad()._data for p in self._params
-                if p.grad_req != "null" and p._data is not None]
+        raws = self._grad_raws()
         if not raws:
             return True
         if self._finite_check is None:
@@ -248,8 +255,7 @@ class Trainer:
         """Global gradient L2 norm as ONE fused XLA reduction, returned as
         an UNFETCHED device scalar so callers choose when (if ever) to pay
         the host sync."""
-        raws = [p.grad()._data for p in self._params
-                if p.grad_req != "null" and p._data is not None]
+        raws = self._grad_raws()
         if not raws:
             return None
         if self._grad_norm_fn is None:

@@ -246,6 +246,12 @@ def _kv_row(group):
     return lambda i: _div(i, group)
 
 
+# ``_fwd`` and ``_bwd_once`` are traced once a signature and their
+# equations inlined where they are called: a kernel's body is 25 ms of
+# Python to trace (130 inside a recomputation region's transposition on
+# the chip's host) and a step calls each once a layer (PERF.md section
+# 6, PR 44).  The tiles are counted outside them, a call.
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8), inline=True)
 def _fwd(q, k, v, causal, scale, block_q, block_k, interpret, window=None,
          sel=None):
     bh, sq, d = q.shape
@@ -440,6 +446,20 @@ def _bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, *refs,
 
 def _flash_bwd(causal, scale, block_q, block_k, bwd_block_q, bwd_block_k,
                interpret, window, stats, res, do):
+    """The backward of ``_flash``: its tiles counted a call, its kernels
+    traced once a signature (``_bwd_once``)."""
+    (bh, sq, _), sk = res[0].shape, res[1].shape[1]
+    if _telemetry._active:
+        _count_tiles(("bwd_dkv", "bwd_dq"), bh, sq, sk, min(bwd_block_q, sq),
+                     min(bwd_block_k, sk), causal, window)
+    return _bwd_once(causal, scale, block_q, block_k, bwd_block_q,
+                     bwd_block_k, interpret, window, stats, res, do)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5, 6, 7, 8),
+                   inline=True)
+def _bwd_once(causal, scale, block_q, block_k, bwd_block_q, bwd_block_k,
+              interpret, window, stats, res, do):
     """Blocked Pallas backward (flash-style residuals: out + logsumexp).
 
     Memory is O(seq): P is rebuilt per (q-block, k-block) tile in VMEM from
@@ -457,9 +477,6 @@ def _flash_bwd(causal, scale, block_q, block_k, bwd_block_q, bwd_block_k,
     group = bh // bkv
     bq = min(bwd_block_q, sq)
     bk = min(bwd_block_k, sk)
-    if _telemetry._active:
-        _count_tiles(("bwd_dkv", "bwd_dq"), bh, sq, sk, bq, bk, causal,
-                     window)
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)[:, None, :]
 

@@ -253,6 +253,14 @@ declare_metric("ssm.conv_kernel_calls_total", "counter",
                "those calls that took the Pallas kernels "
                "(ops/pallas/ssm_conv.py: a TPU and shapes its tiles fill) "
                "and not the XLA composition, once per traced call")
+declare_metric("block.boundary_regions_total", "counter",
+               "jax.checkpoint regions at blocks flagged "
+               "hybridize(remat=...) and called inside a trace "
+               "(gluon/block.py::_boundary_call), one per such call in "
+               "what is traced")
+declare_metric("block.boundary_nested_total", "counter",
+               "those regions opened inside another: flagged blocks "
+               "called by a boundary whose policy is a list of names")
 
 
 # -- switches ---------------------------------------------------------------

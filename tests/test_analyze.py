@@ -20,6 +20,16 @@ from mxnet_tpu.analyze import core
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
+@pytest.fixture(autouse=True)
+def _telemetry_as_found():
+    """``TrainingTelemetry(...)`` turns telemetry on for the process: put
+    it back, or whatever file this worker runs next counts and takes
+    gradient norms it did not ask for."""
+    was = telemetry.active()
+    yield
+    telemetry.enable(was)
+
+
 def _run(tmp_path, tree, paths=None, rules=None):
     """Write a fixture tree and run the suite over it."""
     for rel, src in tree.items():

@@ -14,6 +14,7 @@ order of float32 sums only: losses to 2e-5 of ~4.2, gradients to rtol
 2e-3 / atol 3e-6 (the zoo tests' own bounds).  A bf16 product anywhere
 moves a loss by 1e-3 and a gradient leaf by percents: neither passes.
 """
+import gc
 import importlib.util
 import os
 
@@ -23,7 +24,8 @@ import numpy as onp
 import pytest
 
 import mxnet_tpu as mx
-from mxnet_tpu import functional
+from mxnet_tpu import functional, telemetry
+from mxnet_tpu.gluon import block as gluon_block
 from mxnet_tpu.gluon.block import save_these
 from mxnet_tpu.gluon.model_zoo import ouro as zoo
 from mxnet_tpu.ops.xent import sparse_softmax_xent
@@ -333,11 +335,52 @@ def _step_of(cfg, seed=13):
             batch_specs=mesh.batch_specs(2, 2), n_labels=1)
 
 
+def _counted(f, *args):
+    """``f(*args)`` and the ``block.boundary_*`` counters of what it
+    traced."""
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        return f(*args), telemetry.counters("block.boundary")
+    finally:
+        telemetry.enable(False)
+        telemetry.reset()
+
+
+def _flagged_below(block, depth=float("inf")):
+    """Flagged blocks under ``block`` — those that open a region inside
+    its own when its policy is a list of names; ``depth`` 1: those it
+    calls itself."""
+    if depth < 1:
+        return 0
+    return sum(1 + _flagged_below(c, depth - 1)
+               for c in block._children.values()
+               if getattr(c, "_flags", {}).get("remat"))
+
+
+def _regions(jaxpr, inside=False):
+    """(``remat2`` regions in a jaxpr, those of them inside another),
+    every sub-jaxpr walked."""
+    total = nested = 0
+    for eqn in jaxpr.eqns:
+        region = eqn.primitive.name == "remat2"
+        total, nested = total + region, nested + (region and inside)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            t, n = _regions(sub, inside or region)
+            total, nested = total + t, nested + n
+    return total, nested
+
+
+CELL_NAMES = ["pallas_call", "attn.qkv", "attn.proj", "ffn.down"]
+
+
 @pytest.mark.parametrize("remat", [True, "dots", ["attn.qkv", "ffn.inner"],
-                                   ["dot_general"]])
+                                   ["dot_general"], CELL_NAMES])
 def test_a_flagged_child_inside_the_step_changes_no_value(remat):
     """Three updates with every layer application a boundary against
-    three without: the losses equal to the last bit, the first gradient
+    three without — under a list of names every flagged child of the
+    layer a region inside the layer's (the counter says so): the first
+    gradient
     (Adam's first moment after one update) and the parameters after
     three to float32 rounding — the replayed forward is the forward's
     own operations, but XLA groups them into other fusions the second
@@ -345,13 +388,19 @@ def test_a_flagged_child_inside_the_step_changes_no_value(remat):
     parameter, three updates of Adam after 4e-9 on a gradient entry, 1e-5
     of the leaf's largest); a
     forward replayed in another precision, or from other inputs, is
-    1e-3 away."""
+    1e-3 away.  The losses are equal to the last bit behind one region an
+    application; regions inside it cut the forward's fusions too, and a
+    loss may then differ in its last place (seen: one unit, 2.4e-7 of
+    3.7)."""
     x, y = _tokens(CFG, seed=2)
+    names = isinstance(remat, list)
     plain, flagged = _step_of(CFG), _step_of(dict(CFG, layer_remat=remat))
     for i in range(3):
-        a, b = plain(x, y).asnumpy(), flagged(x, y).asnumpy()
-        assert a == b
+        a = plain(x, y).asnumpy()
+        b, counts = _counted(lambda: flagged(x, y).asnumpy())
+        assert abs(a - b) <= (4 * onp.spacing(a) if names else 0)
         if i == 0:
+            assert (counts.get("block.boundary_nested_total", 0) > 0) == names
             for n, s in plain.states.items():
                 m = onp.asarray(s[0])
                 onp.testing.assert_allclose(
@@ -365,33 +414,56 @@ def test_a_flagged_child_inside_the_step_changes_no_value(remat):
                                 flagged.aux["exit.pdf"], atol=1e-7)
 
 
-def test_the_lowered_step_holds_one_region_a_flagged_application():
-    """One ``remat2`` region a layer application in the step's jaxpr
-    (T x N of them), none nested inside another although ``hybridize``
-    flags every descendant, and none without the flag; the lowered text
-    names the replayed forward ``rematted_computation``."""
+@pytest.mark.parametrize("remat,alone", [
+    (["attn.qkv"], False), (True, False), ("dots", False), (None, False),
+    (["attn.qkv"], True)],
+    ids=["names", "true", "dots", "unflagged", "names_on_the_layer_alone"])
+def test_the_lowered_step_holds_one_region_a_flagged_application(remat,
+                                                                 alone):
+    """``remat2`` regions in the step's jaxpr, counted by walking it.
+    Under ``True`` and ``'dots'``: one a layer application (T x N of
+    them), none nested inside another although ``hybridize`` flags every
+    descendant.  Under a list of names: the application's region and,
+    inside it, one for each flagged descendant (attention, feed-forward
+    and the four norms, and inside the first two their ``Dense``s and
+    activation), and at least as many ``optimization_barrier``s in the
+    lowered text.
+    None without the flag.  The lowered text names a replayed forward
+    ``rematted_computation``; the counters read what the jaxpr holds."""
     x, y = _tokens(CFG, seed=2)
     apps = CFG["num_hidden_layers"] * CFG["total_ut_steps"]
+    step = _step_of(dict(CFG, layer_remat=remat))
+    net, params = step.block, {**step.trainable, **step.aux}
+    layer = net.backbone.layer0
+    if alone:       # the way back: the flags decide, so take the children's
+        for each in net.backbone.layers:
+            for child in each._children.values():
+                child.hybridize()
+        assert _flagged_below(layer) == 0
+        remat = True        # what is counted below: one region, none inside
 
-    def regions(step):
-        net, params = step.block, {**step.trainable, **step.aux}
+    def loss(p):
+        out, _ = functional.functional_call(net, p, x, train=True)
+        return FAMILY.loss_fn(out, y)
 
-        def loss(p):
-            out, _ = functional.functional_call(net, p, x, train=True)
-            return FAMILY.loss_fn(out, y)
-
-        return str(jax.make_jaxpr(loss)(params)).count("= remat2[")
-
-    flagged = _step_of(dict(CFG, layer_remat=["attn.qkv"]))
-    assert flagged.block.backbone.layer0.attention._flags["remat"]
-    assert regions(flagged) == apps
-    text = flagged.lower(x, y).as_text(debug_info=True)
+    jaxpr, counts = _counted(jax.make_jaxpr(loss), params)
+    text = step.lower(x, y).as_text(debug_info=True)
+    if remat is None:
+        assert _regions(jaxpr.jaxpr) == (0, 0) and counts == {}
+        assert "rematted_computation" not in text and "checkpoint" not in text
+        return
+    if not alone:
+        assert layer.attention._flags["remat"] == remat
+        assert layer.attention.query_proj._flags["remat"] == remat
+    inner = _flagged_below(layer) if isinstance(remat, list) else 0
+    if isinstance(remat, list):
+        assert inner == 15 and _flagged_below(layer, 1) == 6
+    assert _regions(jaxpr.jaxpr) == (apps * (1 + inner), apps * inner)
+    assert counts == {k: v for k, v in (
+        ("block.boundary_regions_total", apps * (1 + inner)),
+        ("block.boundary_nested_total", apps * inner)) if v}
     assert "rematted_computation" in text
-    assert text.count("optimization_barrier") >= apps
-    plain = _step_of(CFG)
-    assert regions(plain) == 0
-    text = plain.lower(x, y).as_text(debug_info=True)
-    assert "rematted_computation" not in text and "checkpoint" not in text
+    assert text.count("optimization_barrier") >= apps * (1 + inner)
 
 
 def test_an_unflagged_block_traces_what_it_traced():
@@ -449,20 +521,31 @@ def test_what_a_policy_of_names_saves():
     assert net._flags["remat"] == ["kept"]
 
 
-def test_a_boundary_hands_aux_state_out_of_its_region():
+@pytest.mark.parametrize("where", ["one_region", "two_regions_deep"])
+def test_a_boundary_hands_aux_state_out_of_its_region(where):
     """Aux state a flagged child rebinds inside its forward (the expert
     layers' counts, BatchNorm's running statistics) reaches the
-    enclosing ``functional_call``'s ``mutated`` as without the flag."""
+    enclosing ``functional_call``'s ``mutated`` as without the flag —
+    also where the child's region lies inside its flagged parent's
+    (a policy of names): the inner region hands the statistics to the
+    outer's trace, the outer hands them on, and what the outer puts back
+    in the storage after its trace does not undo it."""
     from mxnet_tpu.gluon import nn
 
     def run(remat):
         mx.random.seed(0)
         net = nn.HybridSequential()
-        net.add(nn.Dense(8, in_units=6, flatten=False), nn.BatchNorm(axis=-1,
-                in_channels=8))
+        norms = nn.HybridSequential()
+        norms.add(nn.BatchNorm(axis=-1, in_channels=8),
+                  nn.Dense(8, in_units=8, flatten=False),
+                  nn.BatchNorm(axis=-1, in_channels=8))
+        net.add(nn.Dense(8, in_units=6, flatten=False), norms)
         net.initialize()
-        if remat is not None:
-            net[1].hybridize(remat=remat)
+        if remat is True:
+            for norm in (norms[0], norms[2]):
+                norm.hybridize(remat=True)
+        elif remat is not None:
+            norms.hybridize(remat=remat)
         params = functional.param_arrays(net)
         x = jnp.asarray(onp.random.default_rng(0).normal(size=(5, 6)),
                         jnp.float32)
@@ -471,15 +554,22 @@ def test_a_boundary_hands_aux_state_out_of_its_region():
             out, mutated = functional.functional_call(net, p, x, train=True)
             return jnp.sum(out ** 2), mutated
 
-        return jax.jit(jax.value_and_grad(loss, has_aux=True))(params)
+        return _counted(jax.jit(jax.value_and_grad(loss, has_aux=True)),
+                        params)
 
-    (a, mut_a), g_a = run(None)
-    (b, mut_b), g_b = run(True)
-    assert float(a) == float(b) and set(mut_a) == set(mut_b) and mut_a
+    ((a, mut_a), g_a), _ = run(None)
+    ((b, mut_b), g_b), counts = run(
+        True if where == "one_region" else ["nothing"])
+    assert counts == ({"block.boundary_regions_total": 2}
+                      if where == "one_region" else
+                      {"block.boundary_regions_total": 4,
+                       "block.boundary_nested_total": 3})
+    assert set(mut_a) == set(mut_b) and len(mut_a) == 4
+    assert float(a) == float(b)
     for n in mut_a:
         onp.testing.assert_array_equal(mut_a[n], mut_b[n])
     for n in g_a:
-        onp.testing.assert_allclose(g_a[n], g_b[n], rtol=1e-6, atol=1e-7)
+        onp.testing.assert_allclose(g_a[n], g_b[n], rtol=1e-5, atol=1e-6)
 
 
 class _DropCell(mx.gluon.nn.HybridBlock):
@@ -584,11 +674,233 @@ def test_two_boundaries_draw_two_masks_and_a_trace_takes_its_key():
     assert (out != onp.asarray(forward(jax.random.PRNGKey(4)))).any()
 
 
-def test_an_fp8_step_sees_through_a_boundary():
+@pytest.mark.parametrize("outer,inner,want", [
+    (["kept"], None, (6, 5)),          # the flag recursed: names all through
+    (["kept"], True, (3, 2)),          # a child flagged otherwise: its policy
+    (True, None, (1, 0)),
+    ("dots", None, (1, 0)),
+    (_keep_all, None, (1, 0)),
+    (True, ["kept"], (1, 0)),          # names below another policy: plain
+], ids=["names", "names_over_true", "true", "dots", "callable",
+        "true_over_names"])
+def test_which_flagged_blocks_open_a_region(outer, inner, want):
+    """``a(b(dense, dense), dense)`` with ``a`` flagged ``outer`` (which
+    flags everything below it) and then ``b`` flagged ``inner``, called
+    inside a trace: (regions, those inside another) in the jaxpr and in
+    the two counters.  Only under a list of names do the flagged blocks
+    a boundary calls open regions, each with its own flag's policy: all
+    of them where the names go all the way down (``b``, its two
+    ``Dense``s, the first one's activation, ``a``'s own ``Dense``); where
+    ``b`` is flagged ``True``, ``b`` and ``a``'s ``Dense`` but nothing
+    inside ``b``.  The gradients are the unflagged net's."""
+    from mxnet_tpu.gluon import nn
+
+    def build(flag):
+        mx.random.seed(3)
+        net, a, b = (nn.HybridSequential() for _ in range(3))
+        b.add(nn.Dense(8, in_units=8, flatten=False, activation="tanh"),
+              nn.Dense(8, in_units=8, flatten=False))
+        a.add(b, nn.Dense(8, in_units=8, flatten=False))
+        net.add(a)      # ``functional_call`` runs the root's forward itself
+        net.initialize()
+        if flag:
+            a.hybridize(remat=outer)
+            if inner is not None:
+                b.hybridize(remat=inner)
+        return net
+
+    x = jnp.asarray(onp.random.default_rng(0).normal(size=(3, 8)),
+                    jnp.float32)
+
+    def grad_of(net):
+        def loss(p, x):
+            return jnp.sum(functional.functional_call(
+                net, p, x, train=True)[0] ** 2)
+        return jax.grad(loss), functional.param_arrays(net)
+
+    g, params = grad_of(build(True))
+    jaxpr, counts = _counted(jax.make_jaxpr(g), params, x)
+    assert _regions(jaxpr.jaxpr)[0] >= want[0]   # the backward holds them too
+    fwd = jax.make_jaxpr(
+        lambda p, x: functional.functional_call(build(True), p, x,
+                                                train=True)[0])(params, x)
+    assert _regions(fwd.jaxpr) == want
+    assert (counts.get("block.boundary_regions_total", 0),
+            counts.get("block.boundary_nested_total", 0)) == want
+    policies = [e.params["policy"] for e in _region_eqns(fwd.jaxpr)]
+    if outer == ["kept"]:
+        # the outer region first, then b's, then the Dense's
+        assert policies[0] is not None and policies[2] is not None
+        assert (policies[1] is None) == (inner is True)
+    g0, params0 = grad_of(build(False))
+    want_g, got_g = g0(params0, x), g(params, x)
+    for n in want_g:
+        onp.testing.assert_allclose(got_g[n], want_g[n], rtol=1e-5,
+                                    atol=1e-6, err_msg=n)
+
+
+def _region_eqns(jaxpr):
+    """The ``remat2`` equations of a jaxpr, outermost first."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "remat2":
+            out.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out += _region_eqns(sub)
+    return out
+
+
+class _CountedCell(mx.gluon.nn.HybridBlock):
+    """batchnorm(dense(x)), counting how often its forward's Python runs."""
+
+    def __init__(self):
+        super().__init__()
+        self.dense = mx.gluon.nn.Dense(8, in_units=8, flatten=False)
+        self.norm = mx.gluon.nn.BatchNorm(axis=-1, in_channels=8)
+        self.ran = 0
+
+    def forward(self, x):
+        self.ran += 1
+        return self.norm(self.dense(x))
+
+
+@pytest.mark.parametrize("remat", [["nothing"], True],
+                         ids=["names", "true"])
+def test_a_looped_block_is_traced_once_and_reads_what_was_rebound(remat):
+    """A flagged cell applied three times on shared weights inside one
+    trace: its forward's Python runs once (the second and third
+    application use the first's trace, regions inside it and all — the
+    counters still read every region of the program), the running
+    statistics the first application rebinds are what the second reads
+    (aux state is an argument of the region), and loss, gradients and
+    statistics are the unflagged loop's.  No tracer outlives the trace
+    (JAX's own leak check), and nothing kept for the cell outlives it."""
+    x = jnp.asarray(onp.random.default_rng(0).normal(size=(5, 8)),
+                    jnp.float32)
+
+    def run(flag):
+        mx.random.seed(5)
+        net = mx.gluon.nn.HybridSequential()
+        cell = _CountedCell()
+        net.add(cell)
+        net.initialize()
+        if flag:
+            cell.hybridize(remat=remat)
+        params = functional.param_arrays(net)
+
+        def loss(p, x):
+            def fwd(x):
+                for _ in range(3):
+                    x = cell(x)
+                return x
+            net.forward = fwd
+            out, mutated = functional.functional_call(net, p, x, train=True)
+            return jnp.sum(out ** 2), mutated
+
+        with jax.checking_leaks():      # what a region keeps holds no tracer
+            out, counts = _counted(
+                jax.jit(jax.value_and_grad(loss, has_aux=True)), params, x)
+        return out, counts, cell.ran
+
+    ((a, mut_a), g_a), _, ran_plain = run(False)
+    ((b, mut_b), g_b), counts, ran = run(True)
+    assert (ran_plain, ran) == (3, 1)
+    inner = 2 if isinstance(remat, list) else 0
+    assert counts == {k: v for k, v in (
+        ("block.boundary_regions_total", 3 * (1 + inner)),
+        ("block.boundary_nested_total", 3 * inner)) if v}
+    gc.collect()        # the function kept for a block goes with the block
+    assert not len(gluon_block._boundary_tls.traced)
+    onp.testing.assert_allclose(float(a), float(b), rtol=1e-6)
+    assert set(mut_a) == set(mut_b) and len(mut_a) == 2
+    for n in mut_a:
+        onp.testing.assert_allclose(mut_a[n], mut_b[n], rtol=1e-6, atol=1e-7)
+    for n in g_a:
+        onp.testing.assert_allclose(g_a[n], g_b[n], rtol=1e-5, atol=1e-6,
+                                    err_msg=n)
+
+
+def _primitives(jaxpr):
+    """The names of every primitive in a jaxpr and its sub-jaxprs."""
+    names = set()
+    for eqn in jaxpr.eqns:
+        names.add(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names |= _primitives(sub)
+    return names
+
+
+def test_a_dropout_two_regions_deep_draws_from_a_key_handed_down():
+    """A flagged pair of cells, each ``x + dropout(dense(x))``, inside
+    ``ShardedTrainStep`` under a policy of names: the pair is a region,
+    each cell a region inside it, and the cell's ``Dense`` and
+    ``Dropout`` regions inside the cell's.  A region's key is drawn in
+    the trace round it from the key that trace was handed, so every
+    replay (of the Dropout inside the cell's, of the cell inside the
+    pair's, of the pair) draws the mask the forward drew — a policy that names nothing and makes everything again gives
+    the losses and parameters of one that names every primitive and
+    makes nothing again; a call draws a fresh mask; and the Dropout
+    after the pair, which splits the step's own stream, meets no tracer
+    of either region."""
+    from mxnet_tpu.gluon import nn
+    x = onp.random.default_rng(0).normal(size=(2, 6, 8)).astype("float32")
+    y = onp.zeros((2, 6, 4), "float32")
+
+    def build(remat):
+        mx.random.seed(7)
+        net, pair = nn.HybridSequential(), nn.HybridSequential()
+        pair.add(_DropCell(), _DropCell())
+        net.add(pair, nn.Dropout(0.25),
+                nn.Dense(4, in_units=8, flatten=False))
+        net.initialize()
+        if remat is not None:
+            pair.hybridize(remat=remat)
+        return net
+
+    def traced(key):
+        net = build(None)
+        with mx.random.trace_key_scope(key):
+            return functional.functional_call(
+                net, functional.param_arrays(net), x, train=True)[0]
+
+    everything = sorted(_primitives(
+        jax.make_jaxpr(traced)(jax.random.PRNGKey(0)).jaxpr))
+    assert "dot_general" in everything and any(
+        "random" in n or "threefry" in n for n in everything)
+
+    def run(remat, lr=0.1):
+        mesh = MeshConfig(dp=1)
+        step = ShardedTrainStep(
+            build(remat), lambda out, t: jnp.mean((out - t) ** 2),
+            mx.optimizer.create("sgd", learning_rate=lr), mesh,
+            batch_specs=mesh.batch_specs(3, 3), n_labels=1)
+        first, counts = _counted(lambda: float(step(x, y).asnumpy()))
+        assert counts == {"block.boundary_regions_total": 7,
+                          "block.boundary_nested_total": 6}
+        losses = [first] + [float(step(x, y).asnumpy()) for _ in range(2)]
+        return losses, {n: onp.asarray(w) for n, w in step.trainable.items()}
+
+    (replayed, a), (kept, b) = run(["nothing"]), run(everything)
+    onp.testing.assert_allclose(replayed, kept, rtol=1e-6)
+    for n in b:
+        onp.testing.assert_allclose(a[n], b[n], rtol=1e-5, atol=1e-7,
+                                    err_msg=n)
+    still = run(["nothing"], lr=0.0)[0]   # the same parameters at every call
+    assert len(set(still)) == 3
+    assert mx.np.random.uniform(size=(2,)).asnumpy().shape == (2,)
+
+
+@pytest.mark.parametrize("remat", [["attn.qkv"], True],
+                         ids=["names", "true"])
+def test_an_fp8_step_sees_through_a_boundary(remat):
     """``precision="fp8"`` with the layers flagged: every ``Dense`` inside
     a boundary still finds its site (the boundary renames no parameter)
     and the amaxes it records reach the step's histories — the largest
-    over a looped layer's uses — as they do without the flag."""
+    over a looped layer's uses, although the second to last application
+    reuse the first's trace — as they do without the flag.  An fp8 step
+    keeps one region an application under a list of names too (regions
+    inside it cost it 2.7 GB at the cell's size: the control would not
+    load)."""
     x, y = _tokens(CFG, seed=2)
 
     def histories(remat):
@@ -601,12 +913,15 @@ def test_an_fp8_step_sees_through_a_boundary():
                 mx.optimizer.create("adam", learning_rate=1e-3), mesh,
                 batch_specs=mesh.batch_specs(2, 2), n_labels=1,
                 precision="fp8")
-            losses = [float(step(x, y).asnumpy()) for _ in range(2)]
+            first, counts = _counted(lambda: float(step(x, y).asnumpy()))
+            losses = [first, float(step(x, y).asnumpy())]
         return losses, {s: {k: float(v.max()) for k, v in h.items()}
-                        for s, h in step.extra["fp8"].items()}
+                        for s, h in step.extra["fp8"].items()}, counts
 
-    plain_losses, plain = histories(None)
-    flagged_losses, flagged = histories(["attn.qkv"])
+    plain_losses, plain, _ = histories(None)
+    flagged_losses, flagged, counts = histories(remat)
+    apps = CFG["num_hidden_layers"] * CFG["total_ut_steps"]
+    assert counts == {"block.boundary_regions_total": apps}
     # seven products a layer; the embedding and the head are sites by
     # their names and no ``Dense`` runs them (the loss reads the head)
     layers = {s: h for s, h in plain.items() if ".layer" in s}
@@ -616,3 +931,111 @@ def test_an_fp8_step_sees_through_a_boundary():
         assert min(h.values()) > 0, site
         for k in h:
             assert abs(flagged[site][k] - h[k]) <= 1e-4 * h[k], (site, k)
+
+
+# ---- what regions inside a region cost in memory ---------------------------
+
+from test_flash_tiles import one_v5e  # noqa: E402,F401  (a fixture)
+
+
+def _kernel_product(x, w):
+    """``x @ w`` by a Pallas kernel, in ``x``'s type."""
+    from jax.experimental import pallas as pl
+
+    def body(x_ref, w_ref, o_ref):
+        o_ref[...] = jnp.dot(x_ref[...], w_ref[...], precision="default",
+                             preferred_element_type=jnp.float32
+                             ).astype(o_ref.dtype)
+    (m, k), n = x.shape, w.shape[1]
+    return pl.pallas_call(
+        body, grid=(m // 256, n // 256),
+        in_specs=[pl.BlockSpec((256, k), lambda i, j: (i, 0)),
+                  pl.BlockSpec((k, 256), lambda i, j: (0, j))],
+        out_specs=pl.BlockSpec((256, 256), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((m, n), x.dtype))(x, w)
+
+
+@jax.custom_vjp
+def _kernel_dense(x, w):
+    return _kernel_product(x, w)
+
+
+_kernel_dense.defvjp(lambda x, w: (_kernel_product(x, w), (x, w)),
+                     lambda res, g: (g @ res[1].T, res[0].T @ g))
+
+
+class _KernelDense(mx.gluon.nn.HybridBlock):
+    """A ``Dense`` whose product is a Pallas kernel."""
+
+    def __init__(self, units):
+        super().__init__()
+        self.weight = mx.gluon.Parameter("weight", shape=(units, units),
+                                         dtype="bfloat16")
+
+    def forward(self, x):
+        return mx.np.array(_kernel_dense(x._data, self.weight.data()._data))
+
+
+class _KernelLayer(mx.gluon.nn.HybridBlock):
+    def __init__(self, units):
+        super().__init__()
+        self.up, self.down = _KernelDense(units), _KernelDense(units)
+
+    def forward(self, x):
+        h = self.up(x)
+        return x + self.down(h * mx.npx.sigmoid(h))
+
+
+def test_regions_inside_a_region_cost_memory_and_the_flags_take_them_back(
+        one_v5e):
+    """Eight bf16 layers of two kernel products, each layer flagged with a
+    list that keeps what a ``pallas_call`` wrote, compiled for a described
+    v5e.  With the products' blocks flagged too they are regions inside
+    the layer's, and the program holds more: +41 % here (41.1 -> 57.9 MB;
+    +6 % on ``ouro-train-8k``, +17 % on its fp8 control, PERF.md section
+    6, PR 44).  The flags decide, so taking the children's gives one
+    region a layer again, and its memory.  No form runs a kernel twice:
+    the list keeps what a kernel wrote."""
+    from jax.experimental.compilation_cache import compilation_cache
+    layers, tokens, units = 8, 4096, 1024
+
+    def compiled(flag, children):
+        net = mx.gluon.nn.HybridSequential()
+        for _ in range(layers):
+            net.add(_KernelLayer(units))
+        net.initialize()
+        for layer in net if flag else ():
+            layer.hybridize(remat=["pallas_call"])
+            for child in () if children else layer._children.values():
+                child.hybridize()
+        params = functional.param_arrays(net)
+
+        def loss(p, x):
+            out, _ = functional.functional_call(net, p, x, train=True)
+            return jnp.sum(out.astype(jnp.float32) ** 2)
+
+        spec = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                              sharding=one_v5e)
+        lowered, counts = _counted(
+            jax.jit(jax.grad(loss)).lower,
+            jax.tree_util.tree_map(spec, params),
+            jax.ShapeDtypeStruct((tokens, units), jnp.bfloat16,
+                                 sharding=one_v5e))
+        exe = lowered.compile()
+        assert exe.as_text().count("tpu_custom_call") == 2 * layers
+        return exe.memory_analysis().temp_size_in_bytes, counts
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        kept, none = compiled(False, False)
+        one, counts_one = compiled(True, False)
+        nested, counts_nested = compiled(True, True)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    assert none == {} and counts_one == {
+        "block.boundary_regions_total": layers}
+    assert counts_nested == {"block.boundary_regions_total": 3 * layers,
+                             "block.boundary_nested_total": 2 * layers}
+    assert one <= kept      # the list keeps the products: little to save
+    assert 1.2 * one < nested < 1.6 * one  # what regions inside it cost

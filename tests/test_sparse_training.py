@@ -99,6 +99,30 @@ def test_sparse_vs_dense_training_converges_identically(optname, kw):
     assert l_sparse == pytest.approx(l_dense, rel=1e-4)
 
 
+def test_a_sparse_step_with_telemetry_on_notes_its_gradient_norm():
+    """``Trainer.step`` with telemetry on takes the global gradient norm:
+    of a row-sparse gradient, the norm of the rows it holds (it raised
+    on the sparse array's missing ``_data`` whenever an earlier test in
+    the process had left telemetry on)."""
+    from mxnet_tpu import telemetry
+    net = _embed_net(sparse_grad=True)
+    trainer = gluon.Trainer(net.collect_params(), opt.create(
+        "sgd", learning_rate=0.1, lazy_update=True, wd=0.0))
+    x = mx.np.array(onp.array([[0, 1, 0], [2, 3, 1]], dtype="int32"))
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        with autograd.record():
+            loss = (net(x).sum(axis=(1, 2)) ** 2).mean()
+        loss.backward()
+        want = float(onp.sqrt((net.weight.grad().data.asnumpy() ** 2).sum()))
+        assert trainer._grad_norm() == pytest.approx(want, rel=1e-6)
+        trainer.step(1)
+    finally:
+        telemetry.enable(False)
+        telemetry.reset()
+
+
 def test_lazy_update_touches_only_nnz_rows():
     """O(nnz) assertion: jaxpr of the lazy SGD step must contain no
     elementwise math over the full (VOCAB, DIM) table — only gather,
