@@ -10,8 +10,9 @@ import jax.numpy as jnp
 import numpy as onp
 import pytest
 
+import family_harness as H
 import mxnet_tpu as mx
-from mxnet_tpu import runtime, telemetry
+from mxnet_tpu import runtime
 from mxnet_tpu.gluon import nn
 from mxnet_tpu.ops import attention, sparse_index
 from mxnet_tpu.ops.pallas import dsa_align
@@ -25,7 +26,8 @@ def _operands(b, s, h, hk, d, topk, seed=0, dtype=jnp.float32):
     q = jnp.asarray(rs.randn(b, s, h * d), dtype)
     k = jnp.asarray(rs.randn(b, s, hk * d), dtype)
     scores = jnp.asarray(rs.randn(b, s, s), jnp.float32)
-    return scores, sparse_index.select_topk(scores, topk), q, k
+    return scores, jax.jit(
+        lambda i: sparse_index.select_topk(i, topk))(scores), q, k
 
 
 def _split(t, n):
@@ -50,6 +52,22 @@ def _plain_lse(sel, q, k, h, hk):
     return jax.nn.logsumexp(jnp.where((sel != 0)[:, None], att, -1e30), -1)
 
 
+def _both(scores, sel, q, k, h, hk, block, precise=True):
+    """(the composition's loss and ``d_scores``, the kernel's row sums
+    and ``d_scores`` given the plainly written statistics): one program
+    a side."""
+    b, s = scores.shape[:2]
+
+    def kernel(scores, sel, q, k):
+        return _align_pass(
+            scores, sel, _split(q, h), _split(k, hk),
+            _plain_lse(sel, q, k, h, hk), b * s, interpret=True, block=block)
+
+    run = H.traced if precise else (lambda f, *a: jax.jit(f)(*a))
+    return (run(lambda *a: sparse_index._align_pass(*a, h, hk),
+                scores, sel, q, k), run(kernel, scores, sel, q, k))
+
+
 # -- the kernel against the composition -------------------------------------
 
 @pytest.mark.parametrize("b,s,h,hk,d,topk,block", [
@@ -68,12 +86,7 @@ def test_kernel_is_the_composition(b, s, h, hk, d, topk, block):
     chosen = onp.asarray(sel) != 0
     assert sorted(set(chosen.sum(-1).ravel())) == sorted(
         set(min(t + 1, topk) for t in range(s)))
-    with jax.default_matmul_precision("highest"):
-        want, want_d = sparse_index._align_pass(scores, sel, q, k, h, hk)
-        kl, got_d = _align_pass(
-            scores, sel, _split(q, h), _split(k, hk),
-            _plain_lse(sel, q, k, h, hk), b * s, interpret=True,
-            block=block)
+    (want, want_d), (kl, got_d) = _both(scores, sel, q, k, h, hk, block)
     assert kl.shape == (b, 1, s) and got_d.shape == (b, s, s)
     onp.testing.assert_allclose(jnp.sum(kl) / (b * s), want, rtol=2e-6)
     onp.testing.assert_allclose(got_d, want_d, atol=2e-9, rtol=2e-5)
@@ -86,10 +99,8 @@ def test_bf16_operands_take_one_mxu_product_a_head():
     they are, accumulates in float32, and agrees with the composition on
     the same operands."""
     scores, sel, q, k = _operands(2, 64, 8, 2, 16, 24, dtype=jnp.bfloat16)
-    want, want_d = sparse_index._align_pass(scores, sel, q, k, 8, 2)
-    kl, got_d = _align_pass(
-        scores, sel, _split(q, 8), _split(k, 2),
-        _plain_lse(sel, q, k, 8, 2), 128, interpret=True, block=16)
+    (want, want_d), (kl, got_d) = _both(scores, sel, q, k, 8, 2, 16,
+                                        precise=False)
     onp.testing.assert_allclose(jnp.sum(kl) / 128, want, rtol=1e-5)
     onp.testing.assert_allclose(got_d, want_d, atol=1e-8, rtol=1e-4)
 
@@ -125,12 +136,12 @@ def test_flash_forward_hands_out_its_lse(s, d, block):
         out, lse = F.flash_attention(q, k, v, return_lse=True, **kw)
         return jnp.sum(jnp.sin(out)) + jnp.sum(lse), (out, lse)
 
-    with jax.default_matmul_precision("highest"):
-        want = F.flash_attention(qh, kh, vh, **kw)
-        g_want = jax.grad(plain, (0, 1, 2))(qh, kh, vh)
-        (_, (out, lse)), g_got = jax.value_and_grad(
-            asking, (0, 1, 2), has_aux=True)(qh, kh, vh)
-        lse_want = _plain_lse(sel, q, k, h, hk)
+    want, g_want = H.traced(lambda *a: (
+        F.flash_attention(*a, **kw), jax.grad(plain, (0, 1, 2))(*a)),
+        qh, kh, vh)
+    (_, (out, lse)), g_got = H.traced(jax.value_and_grad(
+        asking, (0, 1, 2), has_aux=True), qh, kh, vh)
+    lse_want = H.traced(lambda *a: _plain_lse(*a, h, hk), sel, q, k)
     assert lse.shape == (b, h, s) and lse.dtype == jnp.float32
     onp.testing.assert_array_equal(out, want)
     onp.testing.assert_allclose(lse, lse_want, atol=2e-6)
@@ -164,7 +175,7 @@ def test_align_loss_with_the_statistics_is_the_kernel(monkeypatch):
     monkeypatch.setattr(dsa_align, "BLOCK", 16)
     b, s, h, hk, d, topk = 2, 64, 8, 2, 16, 24
     scores, sel, q, k = _operands(b, s, h, hk, d, topk, seed=3)
-    lse = _plain_lse(sel, q, k, h, hk)
+    lse = jax.jit(lambda *a: _plain_lse(*a, h, hk))(sel, q, k)
 
     def composed(i, q, k):
         return sparse_index.align_loss(i, sel, q, k, h, hk)
@@ -173,22 +184,15 @@ def test_align_loss_with_the_statistics_is_the_kernel(monkeypatch):
         return sparse_index.align_loss(i, sel, q, k, h, hk, lse)
 
     def names(f, *args):
-        return [e.params.get("name") for e in _pallas_calls(
-            jax.make_jaxpr(jax.grad(f))(*args).jaxpr)]
+        return H.pallas_names(jax.grad(f), *args)
 
-    telemetry.reset()
-    telemetry.enable()
-    try:
-        with jax.default_matmul_precision("highest"):
-            want, g_want = jax.value_and_grad(composed)(scores, q, k)
-            got, grads = jax.value_and_grad(kernel, (0, 1, 2, 3))(
-                scores, q, k, lse)
-        tiles = {key.split('kind="')[1].rstrip('"}'): n for key, n in
-                 telemetry.counters("kernel.flash_tiles_total").items()
-                 if 'kernel="dsa_align"' in key}
-    finally:
-        telemetry.disable()
-        telemetry.reset()
+    def both():
+        return (H.traced(jax.value_and_grad(composed), scores, q, k),
+                H.traced(jax.value_and_grad(kernel, (0, 1, 2, 3)),
+                         scores, q, k, lse))
+
+    ((want, g_want), (got, grads)), tiles = H.kernel_tiles(both)
+    tiles = tiles["dsa_align"]
     onp.testing.assert_allclose(got, want, rtol=2e-6)
     onp.testing.assert_allclose(grads[0], g_want, atol=2e-9, rtol=2e-5)
     assert not any(onp.asarray(g).any() for g in grads[1:])
@@ -200,15 +204,7 @@ def test_align_loss_with_the_statistics_is_the_kernel(monkeypatch):
     ragged = _operands(1, 40, h, hk, d, 9)
     assert names(lambda i, q, k, l: sparse_index.align_loss(
         i, ragged[1], q, k, h, hk, l), ragged[0], *ragged[2:],
-        _plain_lse(ragged[1], *ragged[2:], h, hk)) == []
-
-
-def _pallas_calls(jaxpr):
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            yield eqn
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _pallas_calls(sub)
+        jax.ShapeDtypeStruct((1, h, 40), jnp.float32)) == []
 
 
 def _on_the_kernels(monkeypatch, block):
@@ -251,14 +247,14 @@ def test_indexed_attention_takes_the_kernel_where_the_core_took_its_own(
             net, {**p, **aux}, x, train=True)
         return jnp.sum(jnp.sin(out)) + l_i, l_i
 
+    (want, want_li), g_want = H.traced(
+        jax.value_and_grad(loss, has_aux=True), params)
+    _on_the_kernels(monkeypatch, 16)
     with jax.default_matmul_precision("highest"):
-        (want, want_li), g_want = jax.value_and_grad(loss, has_aux=True)(
-            params)
-        _on_the_kernels(monkeypatch, 16)
-        names = [e.params["name"] for e in _pallas_calls(
-            jax.make_jaxpr(jax.grad(lambda p: loss(p)[0]))(params).jaxpr)]
-        (got, got_li), g_got = jax.value_and_grad(loss, has_aux=True)(params)
-    assert sorted(names) == ["mx_dsa_align", "mx_dsa_scores",
+        names = H.pallas_names(jax.grad(lambda p: loss(p)[0]), params)
+    (got, got_li), g_got = H.traced(
+        jax.value_and_grad(loss, has_aux=True), params)
+    assert names == ["mx_dsa_align", "mx_dsa_scores",
                              "mx_dsa_scores_bwd", "mx_flash_bwd_dkv",
                              "mx_flash_bwd_dq", "mx_flash_fwd"]
     assert float(want_li) > 0
@@ -284,14 +280,14 @@ def test_under_a_mesh_the_kernel_sits_in_a_shard_map(monkeypatch):
     monkeypatch.setattr(dsa_align, "align_pass", align_pass)
     b, s, h, hk, d, topk = 4, 32, 4, 2, 8, 6
     scores, sel, q, k = _operands(b, s, h, hk, d, topk, seed=5)
-    lse = _plain_lse(sel, q, k, h, hk)
+    lse = jax.jit(lambda *a: _plain_lse(*a, h, hk))(sel, q, k)
 
     def loss(i, q, k, lse):
         return sparse_index.align_loss(i, sel, q, k, h, hk, lse)
 
     with jax.default_matmul_precision("highest"):
-        want, g_want = jax.value_and_grad(
-            lambda i: sparse_index.align_loss(i, sel, q, k, h, hk))(scores)
+        want, g_want = jax.jit(jax.value_and_grad(
+            lambda i: sparse_index.align_loss(i, sel, q, k, h, hk)))(scores)
         mesh = MeshConfig(dp=2, tp=2).build(jax.devices()[:4])
         with activation_sharding(mesh):
             got, g_got = jax.jit(jax.value_and_grad(loss))(scores, q, k, lse)

@@ -11,13 +11,16 @@ import jax.numpy as jnp
 import numpy as onp
 import pytest
 
+import family_harness as H
 import mxnet_tpu as mx
-from mxnet_tpu import runtime, telemetry
+from mxnet_tpu import runtime
 from mxnet_tpu.gluon import nn
 from mxnet_tpu.ops import sparse_index
 from mxnet_tpu.ops.pallas import dsa_align, dsa_scores
 from mxnet_tpu.parallel import MeshConfig
 from mxnet_tpu.parallel.mesh import activation_sharding
+from family_harness import eqns as _eqns, kernel_tiles as _counted, \
+    pallas_calls as _pallas_calls, pallas_names as _names
 
 
 def _operands(b, s, heads, d, dtype=jnp.float32, seed=0):
@@ -47,21 +50,6 @@ def _kernels(q, k, w, g, block):
     return scores, (first(dq), dk, first(dw))
 
 
-def _pallas_calls(jaxpr):
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            yield eqn
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _pallas_calls(sub)
-
-
-def _eqns(jaxpr):
-    for eqn in jaxpr.eqns:
-        yield eqn
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _eqns(sub)
-
-
 # -- the kernels against the composition ------------------------------------
 
 SHAPES = [
@@ -82,9 +70,9 @@ def test_scores_under_the_diagonal_are_the_composition(b, s, heads, d, block,
     """Every causal pair's score; the tiles wholly above the diagonal
     are zeros (the composition computes them, nobody reads them)."""
     q, k, w, g = _operands(b, s, heads, d, dtype, seed=s + heads)
-    with jax.default_matmul_precision("highest"):
-        want = onp.asarray(sparse_index._composed_scores(q, k, w))
-        got = onp.asarray(_kernels(q, k, w, g, block)[0])
+    want = onp.asarray(H.traced(sparse_index._composed_scores, q, k, w))
+    got = onp.asarray(H.traced(
+        lambda *a: _kernels(*a, block)[0], q, k, w, g))
     assert got.shape == (b, s, s) and got.dtype == onp.float32
     causal = _causal(s)
     onp.testing.assert_allclose(got[:, causal], want[:, causal],
@@ -105,9 +93,8 @@ def test_gradients_are_the_compositions(b, s, heads, d, block, dtype, tol):
     the composition's float32 ``dP`` (one bf16 pass at default
     precision) and the CPU does not."""
     q, k, w, g = _operands(b, s, heads, d, dtype, seed=s + d)
-    with jax.default_matmul_precision("highest"):
-        want = jax.vjp(sparse_index._composed_scores, q, k, w)[1](g)
-        got = _kernels(q, k, w, g, block)[1]
+    want = H.out_and_vjp(sparse_index._composed_scores, g, q, k, w)[1]
+    got = H.traced(lambda *a: _kernels(*a, block)[1], q, k, w, g)
     for name, a, r in zip(("dq", "dk", "dw"), got, want):
         a, r = (onp.asarray(t, onp.float32) for t in (a, r))
         assert a.shape == r.shape, name
@@ -162,28 +149,6 @@ def _on_the_kernels(monkeypatch, block):
     monkeypatch.setattr(dsa_align, "BLOCK", block)
 
 
-def _names(f, *args):
-    return sorted(e.params["name"] for e in _pallas_calls(
-        jax.make_jaxpr(f)(*args).jaxpr))
-
-
-def _counted(f, *args):
-    """``kernel.flash_tiles_total`` of one traced call, by kernel."""
-    telemetry.reset()
-    telemetry.enable()
-    try:
-        out = f(*args)
-        tiles = {}
-        for key, n in telemetry.counters("kernel.flash_tiles_total").items():
-            kernel = key.split('kernel="')[1].split('"')[0]
-            kind = key.split('kind="')[1].split('"')[0]
-            tiles.setdefault(kernel, {})[kind] = n
-    finally:
-        telemetry.disable()
-        telemetry.reset()
-    return out, tiles
-
-
 def _loss(g):
     return lambda q, k, w: jnp.sum(sparse_index.index_scores(q, k, w) * g)
 
@@ -193,8 +158,8 @@ def test_off_the_tpu_index_scores_is_the_composition(dtype):
     """On the CPU no kernel is traced, forward or backward, and no tile
     is counted, whatever the sequence."""
     q, k, w, g = _operands(2, 64, 4, 16, dtype)
-    (_, grads), tiles = _counted(jax.value_and_grad(_loss(g), (0, 1, 2)),
-                                 q, k, w)
+    (_, grads), tiles = _counted(
+        jax.jit(jax.value_and_grad(_loss(g), (0, 1, 2))), q, k, w)
     assert tiles == {}
     assert _names(jax.grad(_loss(g), (0, 1, 2)), q, k, w) == []
     assert [t.dtype for t in grads] == [q.dtype, k.dtype, w.dtype]
@@ -206,8 +171,10 @@ def test_a_ragged_sequence_falls_to_the_composition(monkeypatch, s):
     composition: no kernel, no tile, the composition's values."""
     _on_the_kernels(monkeypatch, 16)
     q, k, w, g = _operands(1, s, 2, 8)
-    want = sparse_index._composed_scores(q, k, w * (1.0 / 8 ** 0.5))
-    got, tiles = _counted(sparse_index.index_scores, q, k, w)
+    want = jax.jit(lambda q, k, w: sparse_index._composed_scores(
+        q, k, w * (1.0 / 8 ** 0.5)))(q, k, w)
+    got, tiles = _counted(jax.jit(
+        lambda *a: sparse_index.index_scores(*a)), q, k, w)
     assert tiles == {}
     assert _names(jax.grad(_loss(g), (0, 1, 2)), q, k, w) == []
     onp.testing.assert_array_equal(got, want)
@@ -242,11 +209,10 @@ def test_on_the_tpu_index_scores_is_the_kernels(monkeypatch, dtype, tol):
         return jnp.sum(jnp.where(causal, sparse_index.index_scores(q, k, w),
                                  0.0) * g)
 
-    with jax.default_matmul_precision("highest"):
-        want, g_want = jax.value_and_grad(loss, (0, 1, 2))(q, k, w)
-        _on_the_kernels(monkeypatch, 16)
-        (got, g_got), tiles = _counted(
-            jax.value_and_grad(loss, (0, 1, 2)), q, k, w)
+    want, g_want = H.traced(jax.value_and_grad(loss, (0, 1, 2)), q, k, w)
+    _on_the_kernels(monkeypatch, 16)
+    (got, g_got), tiles = _counted(
+        H.traced, jax.value_and_grad(loss, (0, 1, 2)), q, k, w)
     # 4 x 4 tiles a batch row: 6 under the diagonal, 4 on it, 6 above
     one = {"computed": 6 * b, "masked": 4 * b, "skipped": 6 * b}
     assert tiles == {"dsa_scores": one, "dsa_scores_bwd": one}
@@ -330,9 +296,9 @@ def test_under_a_mesh_the_kernels_sit_in_a_shard_map(monkeypatch):
                                  0.0) * g)
 
     with jax.default_matmul_precision("highest"):
-        want, g_want = jax.value_and_grad(
+        want, g_want = jax.jit(jax.value_and_grad(
             lambda q, k, w: jnp.sum(sparse_index._composed_scores(
-                q, k, w / d ** 0.5) * g), (0, 1, 2))(q, k, w)
+                q, k, w / d ** 0.5) * g), (0, 1, 2)))(q, k, w)
         mesh = MeshConfig(dp=2, tp=2).build(jax.devices()[:4])
         with activation_sharding(mesh):
             got, g_got = jax.jit(jax.value_and_grad(loss, (0, 1, 2)))(q, k, w)
@@ -368,6 +334,14 @@ def _indexer(seed=0):
     return net
 
 
+def _indexed(net, x):
+    """The indexer's inference forward (scores, selection) as one
+    program."""
+    from mxnet_tpu import functional
+    return H.traced(lambda p, x_: functional.functional_call(
+        net, p, x_)[0], functional.param_arrays(net), x._data)
+
+
 @pytest.mark.parametrize("seed", [0, 1, 2])
 def test_sparse_indexer_selects_the_same_either_way(monkeypatch, seed):
     """``nn.SparseIndexer`` on a seeded input: the TPU's route (kernels
@@ -376,17 +350,17 @@ def test_sparse_indexer_selects_the_same_either_way(monkeypatch, seed):
     net = _indexer(seed)
     x = mx.np.array(onp.random.RandomState(seed).randn(2, 48, 32).astype(
         "float32"))
-    with jax.default_matmul_precision("highest"):
-        want_i, want_sel = net(x)
-        _on_the_kernels(monkeypatch, 16)
-        got_i, got_sel = net(x)
+    net.infer_shape(x[:1, :1])
+    want_i, want_sel = _indexed(net, x)
+    _on_the_kernels(monkeypatch, 16)
+    got_i, got_sel = _indexed(net, x)
     causal = _causal(48)
-    onp.testing.assert_allclose(got_i.asnumpy()[:, causal],
-                                want_i.asnumpy()[:, causal], atol=1e-5,
+    onp.testing.assert_allclose(onp.asarray(got_i)[:, causal],
+                                onp.asarray(want_i)[:, causal], atol=1e-5,
                                 rtol=1e-5)
-    onp.testing.assert_array_equal(got_sel.asnumpy(), want_sel.asnumpy())
-    assert got_sel.asnumpy().sum() == 2 * sum(min(t + 1, 6)
-                                              for t in range(48))
+    onp.testing.assert_array_equal(got_sel, want_sel)
+    assert onp.asarray(got_sel).sum() == 2 * sum(min(t + 1, 6)
+                                                 for t in range(48))
 
 
 def test_sparse_indexer_learns_the_same_either_way(monkeypatch):
@@ -405,11 +379,11 @@ def test_sparse_indexer_learns_the_same_either_way(monkeypatch):
             net, {**p, **aux}, x, train=True)
         return jnp.sum(scores * ct)
 
+    want, g_want = H.traced(jax.value_and_grad(loss), params)
+    _on_the_kernels(monkeypatch, 16)
     with jax.default_matmul_precision("highest"):
-        want, g_want = jax.value_and_grad(loss)(params)
-        _on_the_kernels(monkeypatch, 16)
         names = _names(jax.grad(loss), params)
-        got, g_got = jax.value_and_grad(loss)(params)
+    got, g_got = H.traced(jax.value_and_grad(loss), params)
     assert names == ["mx_dsa_scores", "mx_dsa_scores_bwd"]
     onp.testing.assert_allclose(got, want, rtol=1e-5)
     assert set(g_got) == set(g_want) and len(g_want) >= 4

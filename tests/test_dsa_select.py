@@ -6,6 +6,8 @@ bit, rows that tie at their threshold included, the dispatch in
 traced under, the panel counter, and ``nn.SparseIndexer`` end to end.
 Interpret mode on the CPU.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as onp
@@ -18,7 +20,8 @@ from mxnet_tpu.ops import sparse_index
 from mxnet_tpu.ops.pallas import dsa_scores, dsa_select
 from mxnet_tpu.parallel import MeshConfig
 from mxnet_tpu.parallel.mesh import activation_sharding
-from test_dsa_scores import _counted, _eqns, _names, _pallas_calls
+from family_harness import eqns as _eqns, kernel_tiles as _counted, \
+    pallas_calls as _pallas_calls, pallas_names as _names
 
 
 def _scores(b, s, kind, seed=0):
@@ -43,13 +46,25 @@ def _scores(b, s, kind, seed=0):
     return jnp.asarray(x)
 
 
+@functools.lru_cache(maxsize=None)
+def _kernel_program(topk, block):
+    return jax.jit(lambda i: jnp.swapaxes(dsa_select.select_pass(
+        jnp.swapaxes(i, 1, 2), topk, interpret=True, block=block), 1, 2))
+
+
 def _kernel(scores, topk, block):
-    """``select_pass`` on ``select_topk``'s own layouts."""
-    return jnp.swapaxes(dsa_select.select_pass(
-        jnp.swapaxes(scores, 1, 2), topk, interpret=True, block=block), 1, 2)
+    """``select_pass`` on ``select_topk``'s own layouts: one program a
+    shape, whatever the kind of scores."""
+    return _kernel_program(topk, block)(scores)
 
 
-_composed = sparse_index._composed_select
+@functools.lru_cache(maxsize=None)
+def _composed_program(topk):
+    return jax.jit(lambda i: sparse_index._composed_select(i, topk))
+
+
+def _composed(scores, topk):
+    return _composed_program(topk)(scores)
 
 
 # -- the kernel against the composition -------------------------------------
@@ -99,9 +114,9 @@ def test_without_ties_the_mask_is_the_selection(b, s, topk, block, kind):
     rows = onp.minimum(onp.arange(s) + 1, topk)
     for t in range(min(topk, s)):
         assert got[:, t, :t + 1].all()
-    neg = jnp.where(jnp.tril(jnp.ones((s, s), bool)), scores, -jnp.inf)
-    _, best = jax.lax.top_k(neg, min(topk, s))
-    best = onp.asarray(best)
+    best = onp.asarray(jax.jit(lambda i: jax.lax.top_k(jnp.where(
+        jnp.tril(jnp.ones((s, s), bool)), i, -jnp.inf), min(topk, s))[1])(
+            scores))
     for t in range(s):
         for i in range(b):
             assert set(got[i, t].nonzero()[0]) == set(best[i, t, :rows[t]])
@@ -157,7 +172,7 @@ def _on_the_kernel(monkeypatch, block):
 
 
 def _select(topk):
-    return lambda scores: sparse_index.select_topk(scores, topk)
+    return jax.jit(lambda scores: sparse_index.select_topk(scores, topk))
 
 
 @pytest.mark.parametrize("s", [16, 64, 512])
@@ -248,7 +263,7 @@ def test_ties_at_the_threshold_go_to_the_lower_index(monkeypatch):
     def select():
         assert _names(_select(topk), jnp.asarray(scores)) == [
             "mx_dsa_select"]
-        sel = onp.asarray(sparse_index.select_topk(jnp.asarray(scores), topk))
+        sel = onp.asarray(_select(topk)(jnp.asarray(scores)))
         onp.testing.assert_array_equal(
             sel, _composed(jnp.asarray(scores), topk))
         return sel
@@ -309,7 +324,7 @@ def test_under_a_mesh_the_kernel_sits_in_a_shard_map(monkeypatch):
     want = _composed(scores, topk)
     mesh = MeshConfig(dp=2, tp=2).build(jax.devices()[:4])
     with activation_sharding(mesh):
-        got = jax.jit(_select(topk))(scores)
+        got = _select(topk)(scores)
     assert seen[-1] == (2, s, s)
     onp.testing.assert_array_equal(got, want)
     # for Mosaic: panels of 128 lanes, nothing interpreted
@@ -317,7 +332,7 @@ def test_under_a_mesh_the_kernel_sits_in_a_shard_map(monkeypatch):
     monkeypatch.setattr(dsa_select, "BLOCKS", (128,))
     s = 256
     with activation_sharding(mesh):
-        text = jax.jit(_select(64)).trace(jax.ShapeDtypeStruct(
+        text = _select(64).trace(jax.ShapeDtypeStruct(
             (b, s, s), jnp.float32)).lower(
                 lowering_platforms=("tpu",)).as_text()
     assert seen[-1] == (2, s, s)
@@ -343,16 +358,17 @@ def test_sparse_indexer_selects_and_counts_the_same_either_way(monkeypatch,
     composition's either way: the TPU's route for the top-k picks the
     same keys and reports the same ``selected_pairs`` and
     ``select_grid``."""
-    from mxnet_tpu import autograd
+    from mxnet_tpu import functional
     net = _indexer(seed)
     x = mx.np.array(onp.random.RandomState(seed).randn(2, 48, 32).astype(
         "float32"))
+    net.infer_shape(x[:1, :1])
 
     def run():
-        with autograd.record(train_mode=True):
-            _, sel = net(x)
-        return (sel.asnumpy(), net.selected_pairs.data().asnumpy(),
-                net.select_grid.data().asnumpy())
+        (_, sel), counts = jax.jit(lambda p, x_: functional.functional_call(
+            net, p, x_, train=True))(functional.param_arrays(net), x._data)
+        return (onp.asarray(sel), onp.asarray(counts["selected_pairs"]),
+                onp.asarray(counts["select_grid"]))
 
     want = run()
     _on_the_kernel(monkeypatch, 16)

@@ -10,12 +10,12 @@ import jax.numpy as jnp
 import numpy as onp
 import pytest
 
+import family_harness as H
 import mxnet_tpu as mx
+from family_harness import pallas_calls as _pallas_calls
 from mxnet_tpu import telemetry
 from mxnet_tpu.ops.attention import _reference_attention
 from mxnet_tpu.ops.pallas import flash_attention as F
-
-from test_flash_tiles import _pallas_calls
 
 
 def _operands(batch, heads, kv_heads, seq, dim, dtype, seed=0):
@@ -66,9 +66,9 @@ def test_kernels_against_the_xla_composition(case, dtype):
             block_q=fwd[0], block_k=fwd[1], bwd_block_q=bwd[0],
             bwd_block_k=bwd[1])
 
-    out, vjp = jax.vjp(flash, q, k, v)
-    ref, ref_vjp = jax.vjp(lambda *a: _reference(*a, window), q, k, v)
-    grads, ref_grads = vjp(w.astype(out.dtype)), ref_vjp(w)
+    out, grads = H.out_and_vjp(flash, w, q, k, v)
+    ref, ref_grads = H.out_and_vjp(lambda *a: _reference(*a, window), w,
+                                   q, k, v)
     tol = 2e-5 if dtype == jnp.float32 else 3e-2
     assert out.dtype == dtype and out.shape == q.shape
     onp.testing.assert_allclose(out.astype(jnp.float32), ref, atol=tol,

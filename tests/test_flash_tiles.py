@@ -6,6 +6,7 @@ counter, and the layouts — no statistic with a minor dimension of 1, no
 fp32 gradient leaving a kernel.  Interpret mode on the CPU; the last test
 compiles for a described v5e and skips where none can be described.
 """
+import functools
 import re
 
 import jax
@@ -13,6 +14,8 @@ import jax.numpy as jnp
 import numpy as onp
 import pytest
 
+import family_harness as H
+from family_harness import one_v5e, pallas_calls as _pallas_calls  # noqa: F401
 from mxnet_tpu import telemetry
 from mxnet_tpu.autotune import kernels as K
 from mxnet_tpu.ops.attention import _reference_attention
@@ -43,6 +46,14 @@ def _reference(q, k, v, causal):
     return out.reshape(b, sq, h, d).transpose(0, 2, 1, 3)
 
 
+@functools.lru_cache(maxsize=None)
+def _oracle(sq, sk, head, dtype, causal):
+    """The reference's output and gradients, once a shape for the block
+    pairs compared with it."""
+    q, k, v, w = _qkv(sq, sk, head, dtype)
+    return H.out_and_vjp(lambda *a: _reference(*a, causal), w, q, k, v)
+
+
 def _check(sq, sk, head, dtype, causal, fwd, bwd):
     """Output and dq, dk, dv of the kernels against the reference's."""
     q, k, v, w = _qkv(sq, sk, head, dtype)
@@ -53,9 +64,8 @@ def _check(sq, sk, head, dtype, causal, fwd, bwd):
             block_q=fwd["block_q"], block_k=fwd["block_k"],
             bwd_block_q=bwd["block_q"], bwd_block_k=bwd["block_k"])
 
-    out, vjp = jax.vjp(flash, q, k, v)
-    ref, ref_vjp = jax.vjp(lambda *a: _reference(*a, causal), q, k, v)
-    grads, ref_grads = vjp(w.astype(out.dtype)), ref_vjp(w)
+    out, grads = H.out_and_vjp(flash, w, q, k, v)
+    ref, ref_grads = _oracle(sq, sk, head, str(jnp.dtype(dtype)), causal)
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
     assert out.dtype == dtype
     onp.testing.assert_allclose(onp.asarray(out, "float32"),
@@ -165,16 +175,6 @@ def test_tile_counts_at_the_cells_shape():
         "computed": 1, "masked": 2, "skipped": 1}
     assert F.tile_counts(1024, 1024, 512, 512, False) == {
         "computed": 4, "masked": 0, "skipped": 0}
-
-
-def _pallas_calls(jaxpr, out=None):
-    out = [] if out is None else out
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            out.append(eqn)
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            _pallas_calls(sub, out)
-    return out
 
 
 def _grad_jaxpr(blocks, causal=True, shape=(8, 16, 1024, 64)):
@@ -288,18 +288,6 @@ def test_no_minor_dimension_of_one_and_gradients_in_the_operands_dtype():
             assert var.aval.dtype == jnp.bfloat16, (name, var.aval)
             assert var.aval.shape == (128, 1024, 128)
     assert all(v.aval.dtype == jnp.bfloat16 for v in closed.jaxpr.outvars)
-
-
-@pytest.fixture(scope="module")
-def one_v5e():
-    from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
 
 
 def test_compiled_for_a_v5e_no_sparse_statistic_and_no_fp32_gradient(

@@ -4,11 +4,10 @@ on the three flash kernels at a head width of 256, sigmoid-routed
 ``nn.RoutedExperts`` with a shared expert, the multi-token-prediction
 depth — against the benchmark's plain reference
 (chipbench/reference/glm4_moe_lite.py, importing nothing of the program),
-on seeded random weights at small sizes on the CPU.
+on seeded random weights at small sizes on the CPU.  What the families'
+tests share is ``tests/family_harness.py``.
 """
-import importlib.util
-import json
-import os
+import functools
 import re
 
 import jax
@@ -16,31 +15,16 @@ import jax.numpy as jnp
 import numpy as onp
 import pytest
 
+import family_harness as H
 import mxnet_tpu as mx
 from mxnet_tpu import functional
 from mxnet_tpu.gluon import nn
 from mxnet_tpu.gluon.model_zoo import glm4_moe_lite as zoo
 from mxnet_tpu.ops.attention import _reference_attention
 from mxnet_tpu.ops.pallas import flash_attention as F
-from mxnet_tpu.parallel import MeshConfig, ShardedTrainStep
 
-from test_nemotron_h import _AS_BEFORE, _jaxpr_text
-
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-_DATA = os.path.join(_REPO, "tests", "data")
-
-
-def _chipbench(kind):
-    path = os.path.join(_REPO, "chipbench", kind, "glm4_moe_lite.py")
-    spec = importlib.util.spec_from_file_location(
-        f"chipbench_{kind}_glm4_moe_lite", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-REF, FAMILY, FLOPS = (_chipbench(k) for k in ("reference", "families",
-                                              "flops"))
+REF, FAMILY, FLOPS = H.load("glm4_moe_lite")
+_weights = functools.partial(H.weights, "glm4_moe_lite")
 
 CFG = {
     "hidden_size": 32, "intermediate_size": 48, "moe_intermediate_size": 16,
@@ -66,14 +50,7 @@ WIDE = dict(CFG, num_attention_heads=2, qk_nope_head_dim=192,
             qk_rope_head_dim=64, v_head_dim=256)
 
 
-def _tokens(cfg, batch=2, seq=20, seed=0):
-    t = onp.random.default_rng(seed).integers(
-        0, cfg["vocab_size"], (batch, seq + 1), dtype=onp.int32)
-    return t[:, :-1], t[:, 1:]
-
-
-def _put(p, a):
-    p.set_data(mx.np.array(onp.asarray(a, onp.float32)))
+_tokens = functools.partial(H.tokens, seq=20)
 
 
 # ---- nn.LatentAttention against the reference's equations ----------------
@@ -93,7 +70,7 @@ def _latent_attention(cfg, seed=2, scale=4.0):
     """The layer and the reference's leaves of layer 0, the same values
     (matrices times ``scale``: scores that are not flat)."""
     one = dict(cfg, num_hidden_layers=1, first_k_dense_replace=1)
-    w = FAMILY.make_weights(one, seed)
+    w = _weights(one, seed)
     p = {n: onp.asarray(w[n][0]) * (scale if n.endswith(".w") else 1.0)
          for n in _ATTN_PARAMS}
     layer = nn.LatentAttention(
@@ -103,7 +80,7 @@ def _latent_attention(cfg, seed=2, scale=4.0):
         rope_theta=cfg["rope_theta"], epsilon=cfg["rms_norm_eps"])
     layer.initialize()
     for n, pname in _ATTN_PARAMS.items():
-        _put(_param(layer, pname), p[n])
+        H.put(_param(layer, pname), p[n])
     return layer, {n: jnp.asarray(a, jnp.float32) for n, a in p.items()}
 
 
@@ -145,27 +122,27 @@ def test_latent_attention_and_every_gradient_against_the_reference(
         out = jnp.stack([REF._attention(xi, p_, cfg) for xi in x])
         return jnp.sum(out * ct), out
 
+    params = functional.param_arrays(layer)
+
+    def program(x, p_):
+        out = functional.functional_call(layer, p_, x, train=True)[0]
+        return jnp.sum(out * ct), out
+
     seen = _on_the_kernels(monkeypatch, 16) if route == "kernels" else None
-    with jax.default_matmul_precision("highest"):
-        (_, want), (dx, dp) = jax.value_and_grad(
-            plain, argnums=(0, 1), has_aux=True)(jnp.asarray(u), p)
-        x = mx.np.array(u)
-        x.attach_grad()
-        with mx.autograd.record(train_mode=True):
-            out = layer(x)
-            loss = (out * mx.np.array(ct)).sum()
-        loss.backward()
+    (_, want), (dx, dp) = H.traced(jax.value_and_grad(
+        plain, argnums=(0, 1), has_aux=True), jnp.asarray(u), p)
+    (_, out), (dx_got, dp_got) = H.traced(jax.value_and_grad(
+        program, argnums=(0, 1), has_aux=True), jnp.asarray(u), params)
     if seen is not None:
         heads = cfg["num_attention_heads"]
         width = cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"]
         # one key and one value head a query head, all of one width
         assert seen and set(seen[0]) == {(2, heads, seq, width)}
     close = dict(atol=3e-5, rtol=3e-4)
-    onp.testing.assert_allclose(out.asnumpy(), want, **close)
-    onp.testing.assert_allclose(x.grad.asnumpy(), dx, **close)
+    onp.testing.assert_allclose(out, want, **close)
+    onp.testing.assert_allclose(dx_got, dx, **close)
     for n, pname in _ATTN_PARAMS.items():
-        onp.testing.assert_allclose(_param(layer, pname).grad().asnumpy(),
-                                    dp[n], err_msg=n, **close)
+        onp.testing.assert_allclose(dp_got[pname], dp[n], err_msg=n, **close)
 
 
 def test_latent_attention_has_the_latent_leaves_and_no_other():
@@ -208,12 +185,12 @@ def test_flash_kernels_at_head_width_256(dtype, tol):
                                    causal=True)
         return out.reshape(b, s, h, d).transpose(0, 2, 1, 3)
 
-    out, vjp = jax.vjp(flash, q, k, v)
-    ref, ref_vjp = jax.vjp(reference, q, k, v)
+    out, grads = H.out_and_vjp(flash, w, q, k, v)
+    ref, ref_grads = H.out_and_vjp(reference, w, q, k, v)
     assert out.dtype == dtype and out.shape == q.shape
     onp.testing.assert_allclose(out.astype(jnp.float32), ref, atol=tol,
                                 rtol=tol)
-    for g, r in zip(vjp(w.astype(out.dtype)), ref_vjp(w)):
+    for g, r in zip(grads, ref_grads):
         scale = float(jnp.max(jnp.abs(r)))
         onp.testing.assert_allclose(g.astype(jnp.float32) / scale,
                                     r / scale, atol=tol)
@@ -236,123 +213,65 @@ def test_unequal_query_and_value_widths_are_refused_by_name(who):
 
 # ---- the expert layer's shares -------------------------------------------
 
-def _whole_experts(cfg, seed=3):
-    """All of one expert layer's weights (every published expert)."""
-    rs = onp.random.RandomState(seed)
-    e, f, n = (cfg["hidden_size"], cfg["moe_intermediate_size"],
-               cfg["n_routed_experts"])
-    return {"router": rs.randn(n, e) * 0.3, "bias": rs.randn(n) * 0.05,
-            "gate": rs.randn(n, e, f) * 0.2, "up": rs.randn(n, e, f) * 0.2,
-            "down": rs.randn(n, f, e) * 0.2, "sg": rs.randn(f, e) * 0.2,
-            "su": rs.randn(f, e) * 0.2, "sd": rs.randn(e, f) * 0.2}
+@pytest.fixture(scope="module")
+def uncut():
+    """All of one expert layer's weights (every published expert) and 24
+    tokens through the whole layer by the reference, once for the three
+    cuts."""
+    cfg = CFG
+    w = H.whole_experts(cfg["hidden_size"], cfg["moe_intermediate_size"],
+                        cfg["n_routed_experts"],
+                        shared=cfg["moe_intermediate_size"])
+    u = H.rows(24, cfg["hidden_size"])
+    return (w, u) + tuple(H.uncut("glm4_moe_lite", cfg, w, u))
 
 
 @pytest.mark.parametrize("shares", [8, 2, 1])
-def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer(shares):
+def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer(shares,
+                                                                 uncut):
     """Eight chips share a layer in the deployment the cell stands for:
     each routes over all 64 (here 16) experts and computes its own; the
     shared expert is what every chip computes alike, so it is counted
     once; the sum is what the uncut reference gives."""
-    cfg = CFG
-    w = _whole_experts(cfg)
-    u = jnp.asarray(onp.random.RandomState(5).randn(24, cfg["hidden_size"]),
-                    jnp.float32)
-    whole = dict(cfg, num_experts_held=cfg["n_routed_experts"],
-                 experts_held_from=0)
-    p = {"moe.router.w": w["router"], "moe.shared.gate.w": w["sg"],
-         "moe.shared.up.w": w["su"], "moe.shared.down.w": w["sd"],
-         "moe.gate.w": w["gate"], "moe.up.w": w["up"],
-         "moe.down.w": w["down"]}
-    p = {n: jnp.asarray(a, jnp.float32) for n, a in p.items()}
-    per = cfg["n_routed_experts"] // shares
-    total = 0.0
-    with jax.default_matmul_precision("highest"):
-        want, load = REF._experts(u, p, jnp.asarray(w["bias"], jnp.float32),
-                                  whole)
-        for s in range(shares):
-            lo, hi = s * per, (s + 1) * per
-            layer = nn.RoutedExperts(
-                cfg["hidden_size"], cfg["moe_intermediate_size"],
-                cfg["n_routed_experts"], cfg["num_experts_per_tok"],
-                held=(lo, hi), rows_bound=24 * 4,
-                shared_hidden_size=cfg["moe_intermediate_size"]
-                if s == 0 else 0,
-                route_scale=cfg["routed_scaling_factor"])
-            layer.initialize()
-            _put(layer.router, w["router"])
-            _put(layer.expert_bias, w["bias"])
-            for param, name in ((layer.w_gate, "gate"), (layer.w_up, "up"),
-                                (layer.w_down, "down")):
-                _put(param, w[name][lo:hi])
-            if s == 0:
-                _put(layer.shared_gate, w["sg"])
-                _put(layer.shared_up, w["su"])
-                _put(layer.shared_down, w["sd"])
-            with mx.autograd.record(train_mode=True):
-                total = total + layer(mx.np.array(u)[None])._data[0]
-            onp.testing.assert_array_equal(
-                layer.expert_load.data().asnumpy(), load)
-            assert int(layer.rows_over.data().asnumpy()[0]) == 0
-    onp.testing.assert_allclose(total, want, atol=2e-5, rtol=2e-5)
+    H.assert_shares_add_up(uncut, shares, CFG["num_experts_per_tok"],
+                           route_scale=CFG["routed_scaling_factor"])
 
 
 # ---- the blocks that were there trace as they did ------------------------
 
-@pytest.mark.parametrize("name", list(_AS_BEFORE))
+@pytest.mark.parametrize("name", list(H.AS_BEFORE))
 def test_the_blocks_that_were_there_trace_what_they_traced(name):
     """``nn.LatentAttention`` and the family change nothing of
     ``RoutedExperts`` and ``GroupedQueryAttention``: forward and backward
     they trace to the jaxpr files of ``tests/data``, so the four other
     one-chip cells' step programs are the parent's."""
-    with open(os.path.join(_DATA, name + ".jaxpr.txt")) as f:
-        assert _jaxpr_text(_AS_BEFORE[name](), (2, 8, 32)) == f.read()
+    got, want = H.as_before(name)
+    assert got == want
 
 
 # ---- the zoo model -------------------------------------------------------
 
-def _reference_loss(cfg, weights, x, y):
-    params, bias, mtp_bias = REF.split_biases(weights)
-
-    def loss(p):
-        total, loads = 0.0, 0
-        for xs, ys in zip(x, y):
-            one, load = REF.sequence_loss_sum(
-                p, bias, jnp.asarray(xs), jnp.asarray(ys), cfg, mtp_bias)
-            total, loads = total + one, loads + load
-        return total / x.size, loads
-
-    return jax.jit(jax.value_and_grad(loss, has_aux=True))(params)
-
-
-def _program_loss_and_grads(cfg, net, x, y, mtp_weight=0.3):
-    trainable, aux = functional.split_params(net)
-
-    def loss(tr):
-        out, mutated = functional.functional_call(
-            net, {**tr, **aux}, x, train=True)
-        return zoo.next_token_loss(out, y, mtp_weight), mutated
-
-    with jax.default_matmul_precision("highest"):
-        return jax.value_and_grad(loss, has_aux=True)(trainable), aux
+def _program_loss_and_grads(net, x, y, mtp_weight=0.3):
+    return H.program_loss_and_grads(
+        net, lambda out, y_: zoo.next_token_loss(out, y_, mtp_weight), x, y)
 
 
 @pytest.mark.parametrize("size", list(SIZES))
 def test_zoo_model_loss_gradients_and_counts_against_the_reference(size):
     cfg = SIZES[size]
-    weights = FAMILY.make_weights(cfg, 7)
+    weights = _weights(cfg, 7)
     net = FAMILY.build_net(cfg, weights)
     x, y = _tokens(cfg)
-    ((got, mutated), grads), aux = _program_loss_and_grads(cfg, net, x, y)
     assert all(n.endswith((".expert_bias", ".expert_load", ".rows_over"))
-               for n in aux)
-    (want, loads), ref_grads = _reference_loss(cfg, weights, x, y)
-    assert abs(float(got) - float(want)) < 2e-5
+               for n in functional.split_params(net)[1])
+    params, bias, mtp_bias = REF.split_biases(weights)
     n_layer = cfg["num_hidden_layers"]
-    stacked = FAMILY.stack_program_tree(grads, n_layer)
-    assert set(stacked) == set(ref_grads)
-    for name, ref in ref_grads.items():
-        onp.testing.assert_allclose(stacked[name], ref, atol=3e-6,
-                                    rtol=2e-3, err_msg=name)
+    mutated, loads, _ = H.against_the_reference(
+        "glm4_moe_lite", net,
+        lambda out, y_: zoo.next_token_loss(out, y_, 0.3),
+        lambda p, xs, ys: REF.sequence_loss_sum(p, bias, xs, ys, cfg,
+                                                mtp_bias), params, x, y,
+        n_layer)
     counts = FAMILY.stack_program_tree(mutated, n_layer)
     onp.testing.assert_array_equal(counts[FAMILY.LOAD], loads)
     assert counts[FAMILY.LOAD].sum() \
@@ -362,9 +281,9 @@ def test_zoo_model_loss_gradients_and_counts_against_the_reference(size):
 
 
 def test_without_the_second_depth_no_leaf_of_it_exists():
-    net = FAMILY.build_net(CFG, FAMILY.make_weights(CFG, 1))
+    net = FAMILY.build_net(CFG, _weights(CFG, 1))
     x, _ = _tokens(CFG)
-    out = net(mx.np.array(x))
+    out = H.forward(net, x)
     assert out.shape == (2, 20, CFG["vocab_size"])      # logits alone
     assert not any("mtp" in n for n in net.collect_params())
     assert not any(n.startswith(FAMILY.MTP)
@@ -380,7 +299,7 @@ def test_the_second_depth_shares_the_embedding_and_the_head():
     too, through ``h``; the last position, which has no next token,
     touches nothing."""
     cfg = SIZES["mtp"]
-    weights = FAMILY.make_weights(cfg, 3)
+    weights = _weights(cfg, 3)
     net = FAMILY.build_net(cfg, weights)
     names = list(net.collect_params())
     assert sum(n.endswith("word_embed.weight") for n in names) == 1
@@ -390,10 +309,10 @@ def test_the_second_depth_shares_the_embedding_and_the_head():
             "mtp.eh_proj.weight", "mtp.final_norm.gamma"}
     assert net.mtp.eh_proj.weight.shape == (32, 64)
     x, y = _tokens(cfg)
-    logits, second = net(mx.np.array(x))
+    logits, second = H.forward(net, x)
     assert logits.shape == second.shape == (2, 20, cfg["vocab_size"])
-    ((both, _), g_both), _ = _program_loss_and_grads(cfg, net, x, y)
-    ((main, _), g_main), _ = _program_loss_and_grads(cfg, net, x, y, 0.0)
+    (both, _), g_both = _program_loss_and_grads(net, x, y)
+    (main, _), g_main = _program_loss_and_grads(net, x, y, 0.0)
     assert float(both) > float(main)
     for name in ("backbone.word_embed.weight", "lm_head.weight",
                  "backbone.layer0.attention.q_a_proj.weight"):
@@ -402,101 +321,82 @@ def test_the_second_depth_shares_the_embedding_and_the_head():
     assert float(jnp.max(jnp.abs(g_main["mtp.eh_proj.weight"]))) == 0.0
     # the last position has no next token and no target: whatever the
     # second depth says there, the loss does not hear it
-    heard = float(zoo.next_token_loss((logits._data, second._data), y))
-    loud = (logits._data, second._data.at[:, -1, 0].add(50.0))
-    assert float(zoo.next_token_loss(loud, y)) == heard
-    early = (logits._data, second._data.at[:, 0, 0].add(50.0))
-    assert float(zoo.next_token_loss(early, y)) > heard + 0.1
+    loss = jax.jit(lambda *out: zoo.next_token_loss(out, y))
+    heard = float(loss(logits, second))
+    assert float(loss(logits, second.at[:, -1, 0].add(50.0))) == heard
+    assert float(loss(logits, second.at[:, 0, 0].add(50.0))) > heard + 0.1
+
+
+@functools.lru_cache(maxsize=None)
+def _updates(size):
+    """Three updates by the step and by the reference, once a size."""
+    cfg = SIZES[size]
+    return H.three_updates(
+        "glm4_moe_lite", cfg, 11, [_tokens(cfg, seed=s) for s in (4, 5, 6)],
+        cfg["num_hidden_layers"])
 
 
 @pytest.mark.parametrize("size", ["small", "mtp"])
-def test_eager_hybridized_and_sharded_step_agree_and_follow_the_reference(
-        size):
-    """The same seeded net three ways — eager under autograd, hybridized,
-    and through ``ShardedTrainStep`` — and the step's three updates of
-    loss, first gradient and Adam against the reference's, without and
-    with the second prediction depth."""
-    cfg = SIZES[size]
-    weights = FAMILY.make_weights(cfg, 11)
-    x, y = _tokens(cfg, seed=4)
-    xs = mx.np.array(x)
-
-    def outputs(net):
-        out = net(xs)
-        return tuple(o._data for o in out) if isinstance(out, tuple) \
-            else out._data
-
-    with jax.default_matmul_precision("highest"):
-        net = FAMILY.build_net(cfg, weights)
-        with mx.autograd.record(train_mode=True):
-            eager = zoo.next_token_loss(outputs(net), y)
-        net.hybridize()
-        with mx.autograd.record(train_mode=True):
-            hybrid = zoo.next_token_loss(outputs(net), y)
-        assert abs(float(eager) - float(hybrid)) < 1e-6
-
-        net = FAMILY.build_net(cfg, weights)
-        mesh = MeshConfig(dp=1)
-        opt = {"lr": 1e-3, "beta1": 0.9, "beta2": 0.999, "epsilon": 1e-8}
-        step = ShardedTrainStep(
-            net, FAMILY.loss_fn, mx.optimizer.create(
-                "adam", learning_rate=opt["lr"], beta1=opt["beta1"],
-                beta2=opt["beta2"], epsilon=opt["epsilon"]), mesh,
-            batch_specs=mesh.batch_specs(2, 2), n_labels=1)
-        batches = [_tokens(cfg, seed=s) for s in (4, 5, 6)]
-        losses, first = [], None
-        for bx, by in batches:
-            losses.append(float(step(bx, by).asnumpy()))
-            if first is None:
-                first = {n: onp.sqrt(onp.sum(onp.square(s[0]))) / 0.1
-                         for n, s in jax.device_get(step.states).items()}
-        change = jax.device_get(FAMILY.change_norms(cfg, 11, step.trainable))
-    assert abs(losses[0] - float(eager)) < 1e-5
-    ref = REF.train_reference(lambda: FAMILY.make_weights(cfg, 11), batches,
-                              cfg, opt)
-    onp.testing.assert_allclose(losses, ref["losses"], atol=2e-5)
-    n_layer = cfg["num_hidden_layers"]
-    stacked = FAMILY.stack_program_tree(first, n_layer)
+def test_the_sharded_step_has_the_second_depths_leaves(size):
+    """The step's side of the three updates: the first gradient reaches
+    the second depth's leaves where there is one, and every assignment
+    is counted."""
+    cfg, run = SIZES[size], _updates(size)
+    stacked = FAMILY.stack_program_tree(run.first, cfg["num_hidden_layers"])
     assert ("mtp.eh.w" in stacked) == bool(cfg["num_nextn_predict_layers"])
-    g_gaps = REF.leaf_gaps(stacked, ref["grad_norms"])
-    c_gaps = REF.leaf_gaps(FAMILY.stack_program_tree(change, n_layer),
-                           ref["change_norms"])
-    assert REF.worst_leaf(g_gaps)[0] < 2e-3, REF.worst_leaf(g_gaps)
-    dead = REF.dead_leaves(ref["grad_norms"])
-    assert REF.worst_leaf(c_gaps, skip=dead)[0] < 2e-3, \
-        REF.worst_leaf(c_gaps, skip=dead)
-    assert all(v == 0 for n, v in c_gaps.items() if "moe." in n
+    assert not run.last_counts[FAMILY.ROWS_OVER].any()
+
+
+def test_eager_hybridized_and_sharded_step_agree():
+    """The same seeded net three ways — eager under autograd (the
+    family's one eager case), hybridized, and the first loss of its
+    ``ShardedTrainStep``."""
+    eager, hybrid = _updates("small").eager_and_hybridized
+    assert abs(eager - hybrid) < 1e-6
+    assert abs(_updates("small").losses[0] - eager) < 1e-5
+
+
+@pytest.mark.parametrize("size", ["small", "mtp"])
+def test_hybridized_and_sharded_step_agree(size):
+    """The seeded net hybridized and the first loss of its
+    ``ShardedTrainStep``, without and with the second prediction depth."""
+    run = _updates(size)
+    if size == "small":     # the eager case's net, hybridized
+        hybrid = run.eager_and_hybridized[1]
+    else:
+        cfg = SIZES[size]
+        net = FAMILY.build_net(cfg, _weights(cfg, 11))
+        net.hybridize()
+        x, y = _tokens(cfg, seed=4)
+        with jax.default_matmul_precision("highest"), \
+                mx.autograd.record(train_mode=True):
+            out = net(mx.np.array(x))
+        hybrid = float(zoo.next_token_loss(tuple(o._data for o in out), y))
+    assert abs(run.losses[0] - hybrid) < 1e-5
+
+
+@pytest.mark.parametrize("size", ["small", "mtp"])
+def test_the_sharded_steps_three_updates_follow_the_reference(size):
+    """The step's three updates of loss, first gradient and Adam against
+    the reference's, without and with the second prediction depth."""
+    run = _updates(size)
+    onp.testing.assert_allclose(run.losses, run.ref["losses"], atol=2e-5)
+    assert REF.worst_leaf(run.g_gaps)[0] < 2e-3, REF.worst_leaf(run.g_gaps)
+    assert REF.worst_leaf(run.c_gaps, skip=run.dead)[0] < 2e-3, \
+        REF.worst_leaf(run.c_gaps, skip=run.dead)
+    assert all(v == 0 for n, v in run.c_gaps.items() if "moe." in n
                and ("load" in n or "rows_over" in n))
 
 
-def test_scopes_once_a_layer_and_on_the_backward_pass(monkeypatch):
+def test_scopes_once_a_layer_and_on_the_backward_pass():
     """``mx.mla`` and ``mx.mla.assemble`` once a latent attention,
     ``mx.attn`` inside ``mx.mla``, ``mx.mtp`` once round the second
     depth (whose layer enters the others once more); all of them carried
     by the backward pass."""
-    import collections
-    from jax._src import source_info_util
-    entered = collections.Counter()
-    real = source_info_util.ExtendNameStackContextManager.__enter__
-
-    def counting(self):
-        if self.name.startswith("mx"):
-            entered[self.name] += 1
-        return real(self)
-
-    monkeypatch.setattr(source_info_util.ExtendNameStackContextManager,
-                        "__enter__", counting)
     for cfg in (SIZES["small"], SIZES["mtp"]):
         mtp = cfg["num_nextn_predict_layers"]
-        net = FAMILY.build_net(cfg, FAMILY.make_weights(cfg, 1))
-        mesh = MeshConfig(dp=1)
-        step = ShardedTrainStep(
-            net, FAMILY.loss_fn,
-            mx.optimizer.create("adam", learning_rate=1e-3), mesh,
-            batch_specs=mesh.batch_specs(2, 2), n_labels=1)
-        x, y = _tokens(cfg)
-        entered.clear()
-        text = step.lower(x, y).as_text(debug_info=True)
+        net = FAMILY.build_net(cfg, _weights(cfg, 1))
+        text, entered = H.lowered_scopes(net, FAMILY.loss_fn, *_tokens(cfg))
         layers = cfg["num_hidden_layers"]
         assert entered["mx.mla"] == entered["mx.mla.assemble"] \
             == entered["mx.attn"] == layers + mtp
@@ -506,9 +406,7 @@ def test_scopes_once_a_layer_and_on_the_backward_pass(monkeypatch):
         scopes = ["mx.mla/mx.mla.assemble", "mx.mla/mx.attn"] \
             + (["mx.mtp/mx.mla", "mx.mtp/mx.moe"] if mtp else [])
         for scope in scopes:
-            assert re.search(r'jvp\(mx\.fwd\)/' + re.escape(scope), text)
-            assert re.search(r'transpose\(jvp\(mx\.fwd\)\)/'
-                             + re.escape(scope), text), scope
+            assert H.on_the_backward_pass(text, scope), scope
         assert ("mx.mtp" in text) == bool(mtp)
 
 
@@ -541,8 +439,7 @@ def test_the_configuration_file_of_the_cell():
     """Every width as published, the four reduced keys and no other, the
     catalog's numbers under their keys, the count from the family's
     shapes, ISSUE 40's FLOPs."""
-    cfg = json.load(open(os.path.join(
-        _REPO, "chipbench", "configs", "glm-4.7-flash.json")))
+    cfg = H.config("glm-4.7-flash")
     assert cfg["reduced"] == ["num_hidden_layers", "num_experts_held",
                               "vocab_size", "num_nextn_predict_layers"]
     published = {

@@ -4,40 +4,27 @@ scores, top-k selection and alignment loss (ops/sparse_index.py),
 ``nn.SparseIndexer`` / ``nn.IndexedAttention``, softmax routing in
 ``nn.RoutedExperts`` — against the benchmark's plain reference
 (chipbench/reference/keye.py, which imports nothing of the program), on
-seeded random weights at small sizes on the CPU.
+seeded random weights at small sizes on the CPU.  What the families'
+tests share is ``tests/family_harness.py``.
 """
-import collections
-import importlib.util
-import json
-import os
+import functools
+import gc
 
 import jax
 import jax.numpy as jnp
 import numpy as onp
 import pytest
 
+import family_harness as H
 import mxnet_tpu as mx
-from mxnet_tpu import functional
 from mxnet_tpu.gluon import nn
 from mxnet_tpu.ops import sparse_index
 from mxnet_tpu.ops.attention import _reference_attention, multi_head_attention
 from mxnet_tpu.ops.pallas.flash_attention import flash_attention
-from mxnet_tpu.parallel import MeshConfig, ShardedTrainStep
+from mxnet_tpu.parallel import MeshConfig
 
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _chipbench(kind):
-    path = os.path.join(_REPO, "chipbench", kind, "keye.py")
-    spec = importlib.util.spec_from_file_location(
-        f"chipbench_{kind}_keye", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-REF, FAMILY, FLOPS = (_chipbench(k) for k in ("reference", "families",
-                                              "flops"))
+REF, FAMILY, FLOPS = H.load("keye")
+_weights = functools.partial(H.weights, "keye")
 
 CFG = {
     "hidden_size": 32, "moe_intermediate_size": 16, "head_dim": 8,
@@ -56,54 +43,18 @@ SIZES = {
 }
 
 
-def _tokens(cfg, batch=2, seq=16, seed=0):
-    t = onp.random.default_rng(seed).integers(
-        0, cfg["vocab_size"], (batch, seq + 1), dtype=onp.int32)
-    return t[:, :-1], t[:, 1:]
-
-
-def _reference_loss(cfg, weights, x, y):
-    """L_lm + L_I, its gradients, and the counts, by the reference."""
-    def loss(p):
-        total, counts = 0.0, None
-        for xs, ys in zip(x, y):
-            one, c = REF.sequence_loss_sum(p, jnp.asarray(xs),
-                                           jnp.asarray(ys), cfg)
-            total = total + one
-            counts = c if counts is None else jax.tree_util.tree_map(
-                jnp.add, counts, c)
-        return total / x.size, counts
-
-    return jax.jit(jax.value_and_grad(loss, has_aux=True))(dict(weights))
-
-
-def _program_loss(net, x, y, loss_fn=None):
-    trainable, aux = functional.split_params(net)
-
-    def loss(tr):
-        out, mutated = functional.functional_call(
-            net, {**tr, **aux}, x, train=True)
-        return (loss_fn or FAMILY.loss_fn)(out, y), mutated
-
-    with jax.default_matmul_precision("highest"):
-        return jax.jit(jax.value_and_grad(loss, has_aux=True))(trainable)
-
-
 @pytest.mark.parametrize("size", list(SIZES))
 def test_zoo_model_loss_gradients_and_counts_against_the_reference(size):
     cfg = SIZES[size]
-    weights = FAMILY.make_weights(cfg, 7)
+    weights = _weights(cfg, 7)
     net = FAMILY.build_net(cfg, weights)
-    x, y = _tokens(cfg)
-    (got, mutated), grads = _program_loss(net, x, y)
-    (want, (loads, grids)), ref_grads = _reference_loss(cfg, weights, x, y)
-    assert abs(float(got) - float(want)) < 2e-5
+    x, y = H.tokens(cfg)
     n_layer = cfg["num_hidden_layers"]
-    stacked = FAMILY.stack_program_tree(grads, n_layer)
-    assert set(stacked) == set(ref_grads)
-    for name, ref in ref_grads.items():
-        onp.testing.assert_allclose(stacked[name], ref, atol=3e-6,
-                                    rtol=2e-3, err_msg=name)
+    # L_lm + L_I, its gradients, and the counts, by the reference
+    mutated, (loads, grids), _ = H.against_the_reference(
+        "keye", net, FAMILY.loss_fn,
+        lambda p, xs, ys: REF.sequence_loss_sum(p, xs, ys, cfg), weights,
+        x, y, n_layer)
     counts = FAMILY.stack_program_tree(mutated, n_layer)
     onp.testing.assert_array_equal(counts[FAMILY.LOAD], loads)
     onp.testing.assert_array_equal(counts[FAMILY.GRID], grids)
@@ -114,46 +65,45 @@ def test_zoo_model_loss_gradients_and_counts_against_the_reference(size):
     assert not counts[FAMILY.ROWS_OVER].any()
 
 
-def _loss(out, labels):
-    return FAMILY.loss_fn(out, labels)
+@pytest.fixture(scope="module")
+def updates():
+    """Three updates by the step and by the reference, once a file."""
+    return H.three_updates("keye", CFG, 3, [H.tokens(CFG, seed=s)
+                                            for s in (0, 1, 2)],
+                           CFG["num_hidden_layers"])
 
 
-def _step(cfg, seed=1, lr=1e-3):
-    net = FAMILY.build_net(cfg, FAMILY.make_weights(cfg, seed))
-    mesh = MeshConfig(dp=1)
-    return net, ShardedTrainStep(
-        net, _loss, mx.optimizer.create("adam", learning_rate=lr), mesh,
-        batch_specs=mesh.batch_specs(2, 2), n_labels=1)
+def test_the_sharded_step_carries_the_last_updates_pairs(updates):
+    """The step's side of the three updates: the counters hold the last
+    update's values, not a sum over three."""
+    pairs = 2 * REF.selected_pairs(16, CFG["sa_config"]["topk"])
+    assert (updates.last_counts[FAMILY.PAIRS] == pairs).all()
+    assert len(updates.losses) == 3
 
 
-def test_three_adam_updates_follow_the_reference():
+def test_eager_and_hybridized_agree_with_the_sharded_step(updates):
+    """The family's eager case: the seeded net op by op under
+    ``mx.autograd.record``, hybridized, and the first loss of its
+    ``ShardedTrainStep``."""
+    eager, hybrid = updates.eager_and_hybridized
+    assert abs(eager - hybrid) < 1e-6
+    assert abs(updates.losses[0] - eager) < 1e-5
+
+
+def test_three_adam_updates_follow_the_reference(updates):
     """What the chip check compares, at a small size in float32: losses,
     first-gradient norms (from Adam's first moment), the parameters'
     change and the counts after three updates through
     ``ShardedTrainStep``."""
-    cfg, seed = CFG, 3
-    opt = {"lr": 1e-3, "beta1": 0.9, "beta2": 0.999, "epsilon": 1e-8}
-    with jax.default_matmul_precision("highest"):
-        net, step = _step(cfg, seed, opt["lr"])
-        batches = [_tokens(cfg, seed=s) for s in (0, 1, 2)]
-        losses = [float(step(x, y).asnumpy()) for x, y in batches]
-        change = jax.device_get(FAMILY.change_norms(cfg, seed,
-                                                   step.trainable))
-        ref = REF.train_reference(lambda: FAMILY.make_weights(cfg, seed),
-                                  batches, cfg, opt)
-    onp.testing.assert_allclose(losses, ref["losses"], atol=2e-5)
-    gaps = REF.leaf_gaps(
-        FAMILY.stack_program_tree(change, cfg["num_hidden_layers"]),
-        ref["change_norms"])
-    worst, leaf = REF.worst_leaf(gaps, skip=REF.dead_leaves(
-        ref["grad_norms"]))
+    run, cfg = updates, CFG
+    onp.testing.assert_allclose(run.losses, run.ref["losses"], atol=2e-5)
+    worst, leaf = REF.worst_leaf(run.c_gaps, skip=run.dead)
     assert worst < 2e-3, leaf
-    assert {n.split("[")[0] for n in gaps} >= {
+    assert {n.split("[")[0] for n in run.c_gaps} >= {
         REF.LOAD, REF.ROWS_OVER, REF.PAIRS, REF.GRID}
     # the counters hold the last update's values, not a sum over three
     pairs = 2 * REF.selected_pairs(16, cfg["sa_config"]["topk"])
-    assert (ref["change_norms"][REF.PAIRS] == pairs).all()
-    assert (FAMILY.last_counts[FAMILY.PAIRS] == pairs).all()
+    assert (run.ref["change_norms"][REF.PAIRS] == pairs).all()
 
 
 # ---- the flash kernels with a selection ---------------------------------
@@ -201,11 +151,10 @@ def test_flash_kernels_with_a_selection_match_the_composition(
         out = out.reshape(b, s, h, d).transpose(0, 2, 1, 3)
         return jnp.sum(out * jnp.cos(out)), out
 
-    with jax.default_matmul_precision("highest"):
-        (_, got), g_got = jax.value_and_grad(kernels, (0, 1, 2),
-                                             has_aux=True)(q, k, v)
-        (_, want), g_want = jax.value_and_grad(composed, (0, 1, 2),
-                                               has_aux=True)(q, k, v)
+    (_, got), g_got = H.traced(jax.value_and_grad(
+        kernels, (0, 1, 2), has_aux=True), q, k, v)
+    (_, want), g_want = H.traced(jax.value_and_grad(
+        composed, (0, 1, 2), has_aux=True), q, k, v)
     onp.testing.assert_allclose(got, want, atol=2e-5)
     for a, r in zip(g_got, g_want):
         onp.testing.assert_allclose(a, r, atol=5e-5)
@@ -291,23 +240,34 @@ def test_index_scores_in_blocks_are_the_plain_sum(dtype, block, atol,
     q, k, w = _index_operands(onp.random.RandomState(1))
 
     def plain(q, k, w):
-        with jax.default_matmul_precision("highest"):
-            per_head = jnp.einsum("bqhd,bkd->bqhk", q, k)
+        per_head = jnp.einsum("bqhd,bkd->bqhk", q, k)
         return jnp.sum(jax.nn.relu(per_head) * w[..., None], 2) / 8 ** 0.5
 
     def got(q, k, w):
-        with jax.default_matmul_precision("highest"):
-            return sparse_index.index_scores(q.astype(dtype),
-                                             k.astype(dtype), w)
+        return sparse_index.index_scores(q.astype(dtype), k.astype(dtype), w)
 
-    assert got(q, k, w).dtype == jnp.float32
-    onp.testing.assert_allclose(got(q, k, w), plain(q, k, w), atol=atol)
     ct = jnp.asarray(onp.random.RandomState(2).randn(2, 24, 24),
                      jnp.float32)
-    g_got = jax.grad(lambda *a: jnp.sum(got(*a) * ct), (0, 1, 2))(q, k, w)
-    g_want = jax.grad(lambda *a: jnp.sum(plain(*a) * ct), (0, 1, 2))(q, k, w)
+    (scores, g_got), (want, g_want) = (H.value_and_grads(f, (q, k, w), ct)
+                                       for f in (got, plain))
+    assert scores.dtype == jnp.float32
+    onp.testing.assert_allclose(scores, want, atol=atol)
     for a, r in zip(g_got, g_want):
         onp.testing.assert_allclose(a, r, atol=30 * atol)
+
+
+@functools.lru_cache(maxsize=None)
+def _select_program(topk):
+    return jax.jit(lambda i: sparse_index.select_topk(i, topk))
+
+
+def _select(scores, topk):
+    return _select_program(topk)(jnp.asarray(scores))
+
+
+def _reference_select(scores, topk):
+    return H.traced(lambda i: jnp.stack([
+        REF.select(row, 0, topk) for row in i]), jnp.asarray(scores))
 
 
 @pytest.mark.parametrize("s,topk", [(40, 8), (40, 40), (12, 50), (33, 1)])
@@ -317,17 +277,16 @@ def test_select_topk_takes_exactly_the_best_of_every_row(s, topk):
     reference's ``lax.top_k``."""
     scores = jnp.asarray(onp.random.RandomState(s + topk).randn(2, s, s),
                          jnp.float32)
-    sel = onp.asarray(sparse_index.select_topk(scores, topk))
+    sel = onp.asarray(_select(scores, topk))
     assert sel.dtype == onp.int8 and set(onp.unique(sel)) <= {0, 1}
     want_rows = onp.minimum(onp.arange(s) + 1, topk)
     onp.testing.assert_array_equal(sel.sum(-1), want_rows[None].repeat(2, 0))
     assert not onp.triu(sel, 1).any()
     for t in range(min(topk, s)):
         assert sel[:, t, :t + 1].all()
-    ref = onp.stack([onp.asarray(REF.select(scores[i], 0, topk))
-                     for i in range(2)])
+    ref = _reference_select(scores, topk)
     onp.testing.assert_array_equal(sel != 0, ref)
-    pairs, grid = sparse_index.selection_counts(jnp.asarray(sel))
+    pairs, grid = H.traced(sparse_index.selection_counts, jnp.asarray(sel))
     assert int(pairs[0]) == 2 * REF.selected_pairs(s, topk) \
         == int(grid.sum())
 
@@ -341,18 +300,18 @@ def test_select_topk_gives_ties_to_the_lower_index():
     scores[0, :, 3] = 2.0           # one clear winner, the rest tie at 0
     scores[0, 10, 12:] = 5.0        # above the diagonal: never taken
     scores[0, 12] = -1.0            # a whole row of negative ties
-    sel = onp.asarray(sparse_index.select_topk(jnp.asarray(scores), topk))
+    sel = onp.asarray(_select(scores, topk))
     assert sel[0, 10].nonzero()[0].tolist() == [0, 1, 2, 3]
     assert sel[0, 12].nonzero()[0].tolist() == [0, 1, 2, 3]
     scores[0, 10, 3] = 2.0
     scores[0, 10, 7] = 1.0
-    sel = onp.asarray(sparse_index.select_topk(jnp.asarray(scores), topk))
+    sel = onp.asarray(_select(scores, topk))
     assert sel[0, 10].nonzero()[0].tolist() == [0, 1, 3, 7]
-    ref = onp.asarray(REF.select(jnp.asarray(scores[0]), 0, topk))
-    onp.testing.assert_array_equal(sel[0] != 0, ref)
+    onp.testing.assert_array_equal(sel != 0,
+                                   _reference_select(scores, topk))
     # signs and zeros order as floats do: -0.0 ties with 0.0
     scores[0, 15, :] = onp.linspace(-3, 3, s)
-    sel = onp.asarray(sparse_index.select_topk(jnp.asarray(scores), topk))
+    sel = onp.asarray(_select(scores, topk))
     assert sel[0, 15].nonzero()[0].tolist() == [12, 13, 14, 15]
 
 
@@ -364,7 +323,7 @@ def test_align_loss_is_the_kl_and_its_gradient_the_closed_form():
     q = jnp.asarray(rs.randn(b, s, h * d), jnp.float32)
     k = jnp.asarray(rs.randn(b, s, hk * d), jnp.float32)
     scores = jnp.asarray(rs.randn(b, s, s), jnp.float32)
-    sel = sparse_index.select_topk(scores, topk)
+    sel = _select(scores, topk)
 
     def plain(scores):
         chosen = sel != 0
@@ -379,11 +338,10 @@ def test_align_loss_is_the_kl_and_its_gradient_the_closed_form():
                                     - jnp.where(chosen, logq, 0.0)), 0.0)
         return jnp.sum(kl) / (b * s)
 
-    with jax.default_matmul_precision("highest"):
-        want, g_want = jax.value_and_grad(plain)(scores)
-        got, (g_got, g_q, g_k) = jax.value_and_grad(
-            lambda i, q, k: sparse_index.align_loss(i, sel, q, k, h, hk),
-            (0, 1, 2))(scores, q, k)
+    want, g_want = H.traced(jax.value_and_grad(plain), scores)
+    got, (g_got, g_q, g_k) = H.traced(jax.value_and_grad(
+        lambda i, q, k: sparse_index.align_loss(i, sel, q, k, h, hk),
+        (0, 1, 2)), scores, q, k)
     assert float(got) > 0
     onp.testing.assert_allclose(got, want, rtol=1e-5)
     onp.testing.assert_allclose(g_got, g_want, atol=1e-7)
@@ -395,8 +353,8 @@ def test_each_loss_trains_only_its_own_leaves():
     """The indexer's leaves get their gradient from L_I only; every
     other leaf from L_lm only."""
     cfg = CFG
-    net = FAMILY.build_net(cfg, FAMILY.make_weights(cfg, 5))
-    x, y = _tokens(cfg)
+    net = FAMILY.build_net(cfg, _weights(cfg, 5))
+    x, y = H.tokens(cfg)
     from mxnet_tpu.ops.xent import sparse_softmax_xent
 
     def lm_only(out, labels):
@@ -405,8 +363,8 @@ def test_each_loss_trains_only_its_own_leaves():
     def index_only(out, labels):
         return out[1]
 
-    _, g_lm = _program_loss(net, x, y, lm_only)
-    _, g_index = _program_loss(net, x, y, index_only)
+    _, g_lm = H.program_loss_and_grads(net, lm_only, x, y)
+    _, g_index = H.program_loss_and_grads(net, index_only, x, y)
     assert any(".indexer." in n for n in g_lm)
     for name in g_lm:
         lm, index = (float(jnp.abs(g[name]).max()) for g in (g_lm, g_index))
@@ -418,67 +376,26 @@ def test_each_loss_trains_only_its_own_leaves():
 
 # ---- the expert layer under softmax routing ------------------------------
 
-def _layer(cfg, held, rows_bound):
-    layer = nn.RoutedExperts(
-        cfg["hidden_size"], cfg["moe_intermediate_size"],
-        cfg["num_experts"], cfg["num_experts_per_tok"], held=held,
-        rows_bound=rows_bound, score_func="softmax")
-    layer.initialize()
-    return layer
-
-
 def _whole_layer(cfg, seed=3):
-    """All of one expert layer's weights (every published expert)."""
-    rs = onp.random.RandomState(seed)
-    e, f, n = (cfg["hidden_size"], cfg["moe_intermediate_size"],
-               cfg["num_experts"])
-    return {"router": rs.randn(n, e) * 0.3, "gate": rs.randn(n, e, f) * 0.2,
-            "up": rs.randn(n, e, f) * 0.2, "down": rs.randn(n, f, e) * 0.2}
+    return H.whole_experts(cfg["hidden_size"], cfg["moe_intermediate_size"],
+                           cfg["num_experts"], seed, bias=False)
 
 
-def _load(layer, w, lo, hi):
-    def put(p, a):
-        p.set_data(mx.np.array(onp.asarray(a, onp.float32)))
-    put(layer.router, w["router"])
-    put(layer.w_gate, w["gate"][lo:hi])
-    put(layer.w_up, w["up"][lo:hi])
-    put(layer.w_down, w["down"][lo:hi])
-
-
-def _uncut(cfg, w, u):
-    """The whole layer by the reference: every published expert held."""
-    whole = dict(cfg, num_experts_held=cfg["num_experts"],
-                 experts_held_from=0)
-    p = {"moe.router.w": w["router"], "moe.gate.w": w["gate"],
-         "moe.up.w": w["up"], "moe.down.w": w["down"]}
-    p = {n: jnp.asarray(a, jnp.float32) for n, a in p.items()}
-    with jax.default_matmul_precision("highest"):
-        return REF._experts(u, p, whole)
+@pytest.fixture(scope="module")
+def uncut():
+    """24 tokens through the whole layer, once for the three cuts."""
+    w, u = _whole_layer(CFG), H.rows(24, CFG["hidden_size"])
+    return (w, u) + tuple(H.uncut("keye", CFG, w, u))
 
 
 @pytest.mark.parametrize("shares", [8, 4, 1])
-def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer(shares):
+def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer(shares,
+                                                                 uncut):
     """Under softmax routing, weights normalised over all the selected
     experts, held or not: the parts the shares compute add up to what
     the reference gives for the whole layer.  No shared expert."""
-    cfg = CFG
-    w = _whole_layer(cfg)
-    u = jnp.asarray(onp.random.RandomState(5).randn(24, cfg["hidden_size"]),
-                    jnp.float32)
-    want, load = _uncut(cfg, w, u)
-    per = cfg["num_experts"] // shares
-    total = 0.0
-    with jax.default_matmul_precision("highest"):
-        for s in range(shares):
-            lo, hi = s * per, (s + 1) * per
-            layer = _layer(cfg, (lo, hi), rows_bound=24 * 4)
-            _load(layer, w, lo, hi)
-            with mx.autograd.record(train_mode=True):
-                total = total + layer(mx.np.array(u)[None])._data[0]
-            onp.testing.assert_array_equal(
-                layer.expert_load.data().asnumpy(), load)
-            assert int(layer.rows_over.data().asnumpy()[0]) == 0
-    onp.testing.assert_allclose(total, want, atol=2e-5, rtol=2e-5)
+    H.assert_shares_add_up(uncut, shares, CFG["num_experts_per_tok"],
+                           score_func="softmax")
 
 
 def test_score_func_is_checked_and_sigmoid_stays_the_default():
@@ -501,11 +418,10 @@ def test_amp_keeps_norms_router_and_types_the_indexer_products(
     and the selection is the best ``topk`` of them."""
     cfg = CFG
     w = _whole_layer(cfg)
-    rs = onp.random.RandomState(5)
-    u = jnp.asarray(rs.randn(32, cfg["hidden_size"]), jnp.float32)
-    _, load = _uncut(cfg, w, u)
-    layer = _layer(cfg, (0, 4), rows_bound=128)
-    _load(layer, w, 0, 4)
+    u = H.rows(32, cfg["hidden_size"])
+    _, load = H.uncut("keye", cfg, w, u)
+    layer = H.routed_experts(w, 0, 4, cfg["num_experts_per_tok"], 128,
+                             score_func="softmax")
     indexer = nn.SparseIndexer(cfg["hidden_size"], 4, 8, topk=6)
     indexer.initialize()
     seen = {}
@@ -533,12 +449,11 @@ def test_amp_keeps_norms_router_and_types_the_indexer_products(
     want = jnp.bfloat16 if amp else jnp.float32
     assert seen["q"].dtype == seen["k"].dtype == want
     q, k, wt = (seen[n].astype(jnp.float32) for n in "qkw")
-    with jax.default_matmul_precision("highest"):
-        plain = jnp.sum(jax.nn.relu(jnp.einsum("bqhd,bkd->bqhk", q, k))
-                        * wt[..., None], 2) / 8 ** 0.5
+    plain = H.traced(lambda q, k, wt: jnp.sum(
+        jax.nn.relu(jnp.einsum("bqhd,bkd->bqhk", q, k)) * wt[..., None], 2)
+        / 8 ** 0.5, q, k, wt)
     onp.testing.assert_allclose(index._data, plain, atol=1e-4)
-    onp.testing.assert_array_equal(
-        chosen._data, sparse_index.select_topk(index._data, 6))
+    onp.testing.assert_array_equal(chosen._data, _select(index._data, 6))
 
 
 def test_sharded_train_step_carries_the_selection_counts_in_aux():
@@ -547,8 +462,9 @@ def test_sharded_train_step_carries_the_selection_counts_in_aux():
     layers' counts accumulate beside them, and the family finds them
     all."""
     cfg = CFG
-    net, step = _step(cfg)
-    x, y = _tokens(cfg)
+    net = FAMILY.build_net(cfg, _weights(cfg, 1))
+    step = H.sharded_step(net, FAMILY.loss_fn)
+    x, y = H.tokens(cfg)
     pairs = x.shape[0] * REF.selected_pairs(x.shape[1],
                                             cfg["sa_config"]["topk"])
     for updates in (1, 2):
@@ -565,32 +481,18 @@ def test_sharded_train_step_carries_the_selection_counts_in_aux():
     assert set(found) <= set(norms)
     assert FAMILY.last_counts[FAMILY.GRID].shape == (2, 16, 16)
     del step, net
-    import gc
     gc.collect()
     assert FAMILY.step_counts() == {}
 
 
-def test_scopes_of_the_keye_block_do_not_grow_with_depth(monkeypatch):
+def test_scopes_of_the_keye_block_do_not_grow_with_depth():
     """``mx.attn``, ``mx.dsa.index``, ``mx.dsa.select``, ``mx.dsa.align``,
     ``mx.moe`` / ``mx.moe.route`` / ``mx.moe.experts`` once a layer,
     whatever the depth."""
-    from jax._src import source_info_util
-    entered = collections.Counter()
-    real = source_info_util.ExtendNameStackContextManager.__enter__
-
-    def counting(self):
-        if self.name.startswith("mx"):
-            entered[self.name] += 1
-        return real(self)
-
-    monkeypatch.setattr(source_info_util.ExtendNameStackContextManager,
-                        "__enter__", counting)
     for layers in (1, 3):
         cfg = dict(CFG, num_hidden_layers=layers)
-        net, step = _step(cfg)
-        x, y = _tokens(cfg)
-        entered.clear()
-        text = step.lower(x, y).as_text(debug_info=True)
+        net = FAMILY.build_net(cfg, _weights(cfg, 1))
+        text, entered = H.lowered_scopes(net, FAMILY.loss_fn, *H.tokens(cfg))
         assert dict(entered) == {
             "mx.fwd": 1, "mx.optimizer": 1, "mx.attn": layers,
             "mx.dsa.index": layers, "mx.dsa.select": layers,
@@ -603,8 +505,7 @@ def test_scopes_of_the_keye_block_do_not_grow_with_depth(monkeypatch):
 def test_parameter_count_and_needed_work_of_the_cell():
     """The configuration file's count, from the family's shapes; the
     published widths unchanged."""
-    cfg = json.load(open(os.path.join(
-        _REPO, "chipbench", "configs", "keye-vl2-30b-a3b.json")))
+    cfg = H.config("keye-vl2-30b-a3b")
     assert FAMILY.n_params(cfg) == cfg["parameters"] == 465_391_104
     assert round(FLOPS.forward_flops_per_token(cfg, 8192)) == 437_727_232
     assert FLOPS.keys_per_query(8192, 2048) == 1792.125

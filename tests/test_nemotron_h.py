@@ -5,10 +5,10 @@ convolution and the gated grouped norm of ``ops/ssm.py``,
 ``nn.GroupedQueryAttention`` without q/k norms — against the benchmark's
 plain reference (chipbench/reference/nemotron_h.py: the recurrence token
 by token, importing nothing of the program), on seeded random weights at
-small sizes on the CPU.
+small sizes on the CPU.  What the families' tests share is
+``tests/family_harness.py``.
 """
-import importlib.util
-import json
+import functools
 import os
 import re
 import subprocess
@@ -19,27 +19,14 @@ import jax.numpy as jnp
 import numpy as onp
 import pytest
 
+import family_harness as H
 import mxnet_tpu as mx
 from mxnet_tpu import functional
 from mxnet_tpu.gluon import nn
 from mxnet_tpu.ops import ssm
-from mxnet_tpu.parallel import MeshConfig, ShardedTrainStep
 
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-_DATA = os.path.join(_REPO, "tests", "data")
-
-
-def _chipbench(kind):
-    path = os.path.join(_REPO, "chipbench", kind, "nemotron_h.py")
-    spec = importlib.util.spec_from_file_location(
-        f"chipbench_{kind}_nemotron_h", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-REF, FAMILY, FLOPS = (_chipbench(k) for k in ("reference", "families",
-                                              "flops"))
+REF, FAMILY, FLOPS = H.load("nemotron_h")
+_weights = functools.partial(H.weights, "nemotron_h")
 
 CFG = {
     "hidden_size": 32, "head_dim": 8, "num_attention_heads": 4,
@@ -61,10 +48,7 @@ SIZES = {
 }
 
 
-def _tokens(cfg, batch=2, seq=20, seed=0):
-    t = onp.random.default_rng(seed).integers(
-        0, cfg["vocab_size"], (batch, seq + 1), dtype=onp.int32)
-    return t[:, :-1], t[:, 1:]
+_tokens = functools.partial(H.tokens, seq=20)
 
 
 # ---- ops/ssm.py against the recurrence, token by token ------------------
@@ -96,14 +80,10 @@ def test_ssd_scan_values_and_every_gradient(batch, seq, chunk):
     args = _scan_inputs(batch, seq)
     ct = jnp.asarray(onp.random.RandomState(1).randn(*args[0].shape),
                      jnp.float32)
-    with jax.default_matmul_precision("highest"):
-        got = ssm.ssd_scan(*args, chunk=chunk)
-        want = _recurrence(*args)
-        onp.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
-        g_got = jax.grad(lambda *a: jnp.sum(
-            ssm.ssd_scan(*a, chunk=chunk) * ct), argnums=range(6))(*args)
-        g_want = jax.grad(lambda *a: jnp.sum(_recurrence(*a) * ct),
-                          argnums=range(6))(*args)
+    got, g_got = H.value_and_grads(
+        lambda *a: ssm.ssd_scan(*a, chunk=chunk), args, ct)
+    want, g_want = H.value_and_grads(_recurrence, args, ct)
+    onp.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
     for name, a, b in zip(("x", "dt", "A", "B", "C", "D"), g_got, g_want):
         onp.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-4,
                                     err_msg=name)
@@ -114,11 +94,11 @@ def test_the_pad_of_a_ragged_sequence_contributes_nothing():
     no chunk size changes a value."""
     long = _scan_inputs(2, 32, seed=3)
     short = tuple(t[:, :29] if t.ndim > 1 else t for t in long)
-    with jax.default_matmul_precision("highest"):
-        want = ssm.ssd_scan(*long, chunk=8)[:, :29]
-        for chunk in (8, 16, 5, 128):
-            onp.testing.assert_allclose(ssm.ssd_scan(*short, chunk=chunk),
-                                        want, atol=2e-5, rtol=2e-5)
+    want = H.traced(lambda *a: ssm.ssd_scan(*a, chunk=8), *long)[:, :29]
+    for chunk in (8, 16, 5, 128):
+        onp.testing.assert_allclose(
+            H.traced(lambda *a: ssm.ssd_scan(*a, chunk=chunk), *short),
+            want, atol=2e-5, rtol=2e-5)
 
 
 @pytest.mark.parametrize("batch,seq", [(1, 9), (2, 3)])
@@ -133,16 +113,15 @@ def test_causal_conv1d_values_and_every_gradient(batch, seq, activation):
     def plain(x_, w_, b_):
         return jnp.stack([act(REF._conv(xi, w_, b_)) for xi in x_])
 
-    onp.testing.assert_allclose(ssm.causal_conv1d(x, w, b, activation),
-                                plain(x, w, b), atol=1e-6)
-    # causal: an output sees its own input through the last tap only
-    assert float(ssm.causal_conv1d(x.at[:, -1].add(1.0), w, b)[0, 0, 0]) \
-        == float(ssm.causal_conv1d(x, w, b)[0, 0, 0])
     ct = jnp.asarray(rs.randn(batch, seq, 6), jnp.float32)
-    g_got = jax.grad(lambda *a: jnp.sum(
-        ssm.causal_conv1d(*a, activation) * ct), argnums=(0, 1, 2))(x, w, b)
-    g_want = jax.grad(lambda *a: jnp.sum(plain(*a) * ct),
-                      argnums=(0, 1, 2))(x, w, b)
+    got, g_got = H.value_and_grads(
+        lambda *a: ssm.causal_conv1d(*a, activation), (x, w, b), ct)
+    want, g_want = H.value_and_grads(plain, (x, w, b), ct)
+    onp.testing.assert_allclose(got, want, atol=1e-6)
+    # causal: an output sees its own input through the last tap only
+    conv = jax.jit(ssm.causal_conv1d)
+    assert float(conv(x.at[:, -1].add(1.0), w, b)[0, 0, 0]) \
+        == float(conv(x, w, b)[0, 0, 0])
     for a, c in zip(g_got, g_want):
         onp.testing.assert_allclose(a, c, atol=1e-5, rtol=1e-5)
 
@@ -154,8 +133,9 @@ def test_gated_rms_norm_gates_first_and_norms_by_group():
     gated = onp.asarray(y * jax.nn.silu(z)).reshape(2, 5, 3, 4)
     want = (gated / onp.sqrt((gated ** 2).mean(-1, keepdims=True) + 1e-5)
             ).reshape(2, 5, 12) * onp.asarray(w)
-    onp.testing.assert_allclose(ssm.gated_rms_norm(y, z, w, 3, 1e-5), want,
-                                atol=1e-6)
+    onp.testing.assert_allclose(
+        jax.jit(lambda *a: ssm.gated_rms_norm(*a, 3, 1e-5))(y, z, w), want,
+        atol=1e-6)
 
 
 def test_an_unknown_activation_or_grouping_is_refused():
@@ -175,15 +155,11 @@ def test_an_unknown_activation_or_grouping_is_refused():
 
 # ---- the three sublayers against plain jax.numpy -------------------------
 
-def _put(p, a):
-    p.set_data(mx.np.array(onp.asarray(a, onp.float32)))
-
-
 def _layer_leaves(cfg, kind, seed=2):
     """One sublayer's leaves as the generator makes them (layer 0 of its
     kind), as float32 host arrays under the reference's names."""
     one = dict(cfg, hybrid_override_pattern=kind)
-    w = FAMILY.make_weights(one, seed)
+    w = _weights(one, seed)
     return {n: onp.asarray(w[n][0]) for n in REF.KIND_LEAVES[kind]}
 
 
@@ -201,15 +177,14 @@ def test_mamba2_mixer_against_the_reference():
             param = layer
             for part in pname.split("mixer.")[1].split("."):
                 param = getattr(param, part)
-            _put(param, p[n])
+            H.put(param, p[n])
     u = onp.random.RandomState(5).randn(2, 21, cfg["hidden_size"]) \
         .astype(onp.float32)
-    with jax.default_matmul_precision("highest"):
-        got = layer(mx.np.array(u)).asnumpy()
-        want = onp.stack([onp.asarray(REF._mixer(
-            jnp.asarray(ui), {n: jnp.asarray(a, jnp.float32)
-                              for n, a in p.items()}, cfg)) for ui in u])
-    onp.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-4)
+    p = {n: jnp.asarray(a, jnp.float32) for n, a in p.items()}
+    want = H.traced(lambda u_: jnp.stack([REF._mixer(ui, p, cfg)
+                                          for ui in u_]), jnp.asarray(u))
+    onp.testing.assert_allclose(H.forward(layer, u), want, atol=2e-5,
+                                rtol=2e-4)
 
 
 def test_the_mixer_starts_as_mamba2_does():
@@ -227,71 +202,31 @@ def test_the_mixer_starts_as_mamba2_does():
 
 
 def _whole_experts(cfg, seed=3):
-    """All of one expert layer's weights (every published expert)."""
-    rs = onp.random.RandomState(seed)
-    e, f, fs, n = (cfg["hidden_size"], cfg["moe_intermediate_size"],
-                   cfg["moe_shared_expert_intermediate_size"],
-                   cfg["n_routed_experts"])
-    return {"router": rs.randn(n, e) * 0.3, "bias": rs.randn(n) * 0.05,
-            "up": rs.randn(n, e, f) * 0.2, "down": rs.randn(n, f, e) * 0.2,
-            "su": rs.randn(fs, e) * 0.2, "sd": rs.randn(e, fs) * 0.2}
-
-
-def _experts_layer(cfg, w, lo, hi, rows_bound, shared=True):
-    layer = nn.RoutedExperts(
+    return H.whole_experts(
         cfg["hidden_size"], cfg["moe_intermediate_size"],
-        cfg["n_routed_experts"], cfg["num_experts_per_tok"], held=(lo, hi),
-        rows_bound=rows_bound,
-        shared_hidden_size=cfg["moe_shared_expert_intermediate_size"]
-        if shared else 0,
-        route_scale=cfg["routed_scaling_factor"], activation="relu2")
-    layer.initialize()
-    _put(layer.router, w["router"])
-    _put(layer.expert_bias, w["bias"])
-    _put(layer.w_up, w["up"][lo:hi])
-    _put(layer.w_down, w["down"][lo:hi])
-    if shared:
-        _put(layer.shared_up, w["su"])
-        _put(layer.shared_down, w["sd"])
-    return layer
+        cfg["n_routed_experts"], seed, gate=False,
+        shared=cfg["moe_shared_expert_intermediate_size"])
 
 
-def _uncut_experts(cfg, w, u):
-    """The whole layer by the reference: every published expert held."""
-    whole = dict(cfg, num_experts_held=cfg["n_routed_experts"],
-                 experts_held_from=0)
-    p = {"moe.router.w": w["router"], "moe.shared.up.w": w["su"],
-         "moe.shared.down.w": w["sd"], "moe.up.w": w["up"],
-         "moe.down.w": w["down"]}
-    p = {n: jnp.asarray(a, jnp.float32) for n, a in p.items()}
-    with jax.default_matmul_precision("highest"):
-        return REF._experts(u, p, jnp.asarray(w["bias"], jnp.float32), whole)
+_RELU2 = dict(route_scale=CFG["routed_scaling_factor"], activation="relu2")
+
+
+@pytest.fixture(scope="module")
+def uncut():
+    """24 tokens through the whole layer, once for the three cuts."""
+    w, u = _whole_experts(CFG), H.rows(24, CFG["hidden_size"])
+    return (w, u) + tuple(H.uncut("nemotron_h", CFG, w, u))
 
 
 @pytest.mark.parametrize("shares", [16, 4, 1])
 def test_the_shares_of_a_relu2_expert_layer_add_up_to_the_uncut_layer(
-        shares):
+        shares, uncut):
     """Each share routes over all the experts and computes its own; the
     shared expert is what every chip computes alike, so it is counted
     once.  A relu^2 layer has no gate matrix, routed or shared."""
-    cfg = CFG
-    w = _whole_experts(cfg)
-    u = jnp.asarray(onp.random.RandomState(5).randn(24, cfg["hidden_size"]),
-                    jnp.float32)
-    want, load = _uncut_experts(cfg, w, u)
-    per = cfg["n_routed_experts"] // shares
-    total = 0.0
-    with jax.default_matmul_precision("highest"):
-        for s in range(shares):
-            layer = _experts_layer(cfg, w, s * per, (s + 1) * per,
-                                   rows_bound=24 * 4, shared=(s == 0))
-            assert not any("gate" in n for n in layer.collect_params())
-            with mx.autograd.record(train_mode=True):
-                total = total + layer(mx.np.array(u)[None])._data[0]
-            onp.testing.assert_array_equal(
-                layer.expert_load.data().asnumpy(), load)
-            assert int(layer.rows_over.data().asnumpy()[0]) == 0
-    onp.testing.assert_allclose(total, want, atol=2e-5, rtol=2e-5)
+    for layer in H.assert_shares_add_up(
+            uncut, shares, CFG["num_experts_per_tok"], **_RELU2):
+        assert not any("gate" in n for n in layer.collect_params())
 
 
 @pytest.mark.parametrize("held,rows_bound,widths", [
@@ -318,43 +253,40 @@ def test_a_relu2_share_and_every_gradient_against_the_reference(
                                                     cfg["hidden_size"]),
                     jnp.float32)
     part = dict(cfg, num_experts_held=hi - lo, experts_held_from=lo)
-    leaves = {"moe.router.w": w["router"], "moe.shared.up.w": w["su"],
-              "moe.shared.down.w": w["sd"], "moe.up.w": w["up"][lo:hi],
-              "moe.down.w": w["down"][lo:hi]}
-    leaves = {n: jnp.asarray(a, jnp.float32) for n, a in leaves.items()}
-    bias = jnp.asarray(w["bias"], jnp.float32)
+    leaves, bias = H.reference_leaves(w, lo, hi)
 
     def plain(x, p):
         out, load = REF._experts(x.reshape(-1, x.shape[-1]), p, bias, part)
         return jnp.sum(out * out), (out, load)
 
-    with jax.default_matmul_precision("highest"):
-        (_, (want, load)), (dx, dp) = jax.value_and_grad(
-            plain, argnums=(0, 1), has_aux=True)(u, leaves)
-        layer = _experts_layer(cfg, w, lo, hi, rows_bound)
-        x = mx.np.array(u)
-        x.attach_grad()
-        with mx.autograd.record(train_mode=True):
-            out = layer(x)
-            loss = (out * out).sum()
-        loss.backward()
-    onp.testing.assert_array_equal(layer.expert_load.data().asnumpy(), load)
+    layer = H.routed_experts(w, lo, hi, cfg["num_experts_per_tok"],
+                             rows_bound, **_RELU2)
+    params, aux = functional.split_params(layer)
+
+    def program(x, p):
+        out, mutated = functional.functional_call(layer, {**p, **aux}, x,
+                                                  train=True)
+        return jnp.sum(out * out), (out, mutated)
+
+    (_, (want, load)), (dx, dp) = H.traced(jax.value_and_grad(
+        plain, argnums=(0, 1), has_aux=True), u, leaves)
+    (_, (out, mutated)), (dx_got, dp_got) = H.traced(jax.value_and_grad(
+        program, argnums=(0, 1), has_aux=True), u, params)
+    onp.testing.assert_array_equal(mutated["expert_load"], load)
     assigned = int(load[lo:hi].sum())
-    assert int(layer.rows_over.data().asnumpy()[0]) \
-        == max(assigned - rows_bound, 0)
+    assert int(mutated["rows_over"][0]) == max(assigned - rows_bound, 0)
     if assigned > rows_bound:
-        assert onp.isfinite(out.asnumpy()).all()
+        assert onp.isfinite(out).all()
         return
     close = dict(atol=3e-5, rtol=3e-5)
-    onp.testing.assert_allclose(out.asnumpy().reshape(want.shape), want,
-                                **close)
-    onp.testing.assert_allclose(x.grad.asnumpy(), dx, **close)
-    for got, name in ((layer.router, "moe.router.w"),
-                      (layer.w_up, "moe.up.w"), (layer.w_down, "moe.down.w"),
-                      (layer.shared_up, "moe.shared.up.w"),
-                      (layer.shared_down, "moe.shared.down.w")):
-        onp.testing.assert_allclose(got.grad().asnumpy(), dp[name],
-                                    err_msg=name, **close)
+    onp.testing.assert_allclose(out.reshape(want.shape), want, **close)
+    onp.testing.assert_allclose(dx_got, dx, **close)
+    for got, name in (("router", "moe.router.w"), ("w_up", "moe.up.w"),
+                      ("w_down", "moe.down.w"),
+                      ("shared_up", "moe.shared.up.w"),
+                      ("shared_down", "moe.shared.down.w")):
+        onp.testing.assert_allclose(dp_got[got], dp[name], err_msg=name,
+                                    **close)
 
 
 def test_attention_without_qk_norm_against_the_reference():
@@ -372,111 +304,48 @@ def test_attention_without_qk_norm_against_the_reference():
                     ("attn.k.w", layer.key_proj),
                     ("attn.v.w", layer.value_proj),
                     ("attn.o.w", layer.out_proj)):
-        _put(proj.weight, p[n] * 5)        # scores that are not flat
+        H.put(proj.weight, p[n] * 5)        # scores that are not flat
         p[n] = p[n] * 5
     u = onp.random.RandomState(5).randn(2, 12, cfg["hidden_size"]) \
         .astype(onp.float32)
-    with jax.default_matmul_precision("highest"):
-        got = layer(mx.np.array(u)).asnumpy()
-        want = onp.stack([onp.asarray(REF._attention(
-            jnp.asarray(ui), {n: jnp.asarray(a) for n, a in p.items()}, cfg))
-            for ui in u])
-    onp.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-4)
+    p = {n: jnp.asarray(a) for n, a in p.items()}
+    want = H.traced(lambda u_: jnp.stack([REF._attention(ui, p, cfg)
+                                          for ui in u_]), jnp.asarray(u))
+    onp.testing.assert_allclose(H.forward(layer, u), want, atol=2e-5,
+                                rtol=2e-4)
 
 
 # ---- the default layers trace as they did --------------------------------
 
-def _jaxpr_text(layer, shape):
-    """The jaxpr of a layer's training call and its gradients, with what
-    differs between processes taken out."""
-    layer.initialize()
-    x = onp.zeros(shape, onp.float32)
-    layer(mx.np.array(x))
-    tr, aux = functional.split_params(layer)
-
-    def loss(p, x_):
-        out, _ = functional.functional_call(layer, {**p, **aux}, x_,
-                                            train=True)
-        return jnp.sum(out)
-
-    # as a program traces it: without the test suite's matmul precision
-    with jax.default_matmul_precision(None):
-        text = str(jax.make_jaxpr(jax.value_and_grad(loss))(
-            tr, jnp.asarray(x)))
-    # addresses, and the count of functional calls the process has made
-    text = re.sub(r" at 0x[0-9a-f]+", "", text)
-    return re.sub(r"(fold_in \w+) \d+:u32\[\]", r"\1 n:u32[]", text)
-
-
-_AS_BEFORE = {
-    "routed_experts": lambda: nn.RoutedExperts(
-        32, 16, 16, 4, held=(4, 8), rows_bound=48, shared_hidden_size=16,
-        route_scale=2.5),
-    "routed_experts_softmax": lambda: nn.RoutedExperts(
-        32, 16, 16, 4, held=(0, 4), rows_bound=48, score_func="softmax"),
-    "grouped_query_attention": lambda: nn.GroupedQueryAttention(
-        32, 4, 2, 8, window=4, rotary=True),
-    "grouped_query_attention_plain": lambda: nn.GroupedQueryAttention(
-        32, 4, 2, 8, gate=False),
-}
-
-
-@pytest.mark.parametrize("name", list(_AS_BEFORE))
+@pytest.mark.parametrize("name", list(H.AS_BEFORE))
 def test_a_call_without_the_new_arguments_traces_what_it_traced(name):
     """``RoutedExperts()`` without ``activation`` and
     ``GroupedQueryAttention()`` without ``qk_norm`` trace, forward and
     backward, to the jaxpr they traced to at the parent of the PR that
     added the arguments (``tests/data/<name>.jaxpr.txt``, written there
-    by this function): the two expert cells' step programs are then the
-    parent's and are found in its compile cache."""
-    with open(os.path.join(_DATA, name + ".jaxpr.txt")) as f:
-        want = f.read()
-    assert _jaxpr_text(_AS_BEFORE[name](), (2, 8, 32)) == want
+    by ``family_harness.jaxpr_text``): the two expert cells' step
+    programs are then the parent's and are found in its compile cache."""
+    got, want = H.as_before(name)
+    assert got == want
 
 
 # ---- the zoo model -------------------------------------------------------
 
-def _reference_loss(cfg, weights, x, y):
-    params = dict(weights)
-    bias = params.pop(REF.BIAS)
-
-    def loss(p):
-        total, loads = 0.0, 0
-        for xs, ys in zip(x, y):
-            one, load = REF.sequence_loss_sum(p, bias, jnp.asarray(xs),
-                                              jnp.asarray(ys), cfg)
-            total, loads = total + one, loads + load
-        return total / x.size, loads
-
-    return jax.jit(jax.value_and_grad(loss, has_aux=True))(params)
-
-
 @pytest.mark.parametrize("size", list(SIZES))
 def test_zoo_model_loss_gradients_and_counts_against_the_reference(size):
     cfg = SIZES[size]
-    weights = FAMILY.make_weights(cfg, 7)
+    weights = _weights(cfg, 7)
     net = FAMILY.build_net(cfg, weights)
     x, y = _tokens(cfg)
-    trainable, aux = functional.split_params(net)
     assert all(n.endswith((".expert_bias", ".expert_load", ".rows_over"))
-               for n in aux)
-
-    def loss(tr):
-        logits, mutated = functional.functional_call(
-            net, {**tr, **aux}, x, train=True)
-        return FAMILY.loss_fn(logits, y), mutated
-
-    with jax.default_matmul_precision("highest"):
-        (got, mutated), grads = jax.value_and_grad(loss, has_aux=True)(
-            trainable)
-    (want, loads), ref_grads = _reference_loss(cfg, weights, x, y)
-    assert abs(float(got) - float(want)) < 2e-5
+               for n in functional.split_params(net)[1])
+    params = dict(weights)
+    bias = params.pop(REF.BIAS)
     n_layer = len(cfg["hybrid_override_pattern"])
-    stacked = FAMILY.stack_program_tree(grads, n_layer)
-    assert set(stacked) == set(ref_grads)
-    for name, ref in ref_grads.items():
-        onp.testing.assert_allclose(stacked[name], ref, atol=3e-6,
-                                    rtol=2e-3, err_msg=name)
+    mutated, loads, _ = H.against_the_reference(
+        "nemotron_h", net, FAMILY.loss_fn,
+        lambda p, xs, ys: REF.sequence_loss_sum(p, bias, xs, ys, cfg),
+        params, x, y, n_layer)
     counts = FAMILY.stack_program_tree(mutated, n_layer)
     onp.testing.assert_array_equal(counts[FAMILY.LOAD], loads)
     assert counts[FAMILY.LOAD].sum() \
@@ -485,58 +354,41 @@ def test_zoo_model_loss_gradients_and_counts_against_the_reference(size):
     assert not counts[FAMILY.ROWS_OVER].any()
 
 
-def _loss(logits, labels):
-    from mxnet_tpu.ops.xent import sparse_softmax_xent
-    return jnp.mean(sparse_softmax_xent(logits, labels))
+@pytest.fixture(scope="module")
+def updates():
+    """Three updates by the step and by the reference, once a file."""
+    return H.three_updates(
+        "nemotron_h", CFG, 11, [_tokens(CFG, seed=s) for s in (4, 5, 6)],
+        len(CFG["hybrid_override_pattern"]))
 
 
-def test_eager_hybridized_and_sharded_step_agree_and_follow_the_reference():
-    """The same seeded net three ways — eager under autograd, hybridized,
-    and through ``ShardedTrainStep`` — and the step's three updates of
-    loss, first gradient and Adam against the reference's."""
-    cfg = CFG
-    weights = FAMILY.make_weights(cfg, 11)
-    x, y = _tokens(cfg, seed=4)
-    xs, ys = mx.np.array(x), mx.np.array(y)
-    with jax.default_matmul_precision("highest"):
-        net = FAMILY.build_net(cfg, weights)
-        with mx.autograd.record(train_mode=True):
-            eager = _loss(net(xs)._data, y)
-        net.hybridize()
-        with mx.autograd.record(train_mode=True):
-            hybrid = _loss(net(xs)._data, y)
-        assert abs(float(eager) - float(hybrid)) < 1e-6
+def test_the_sharded_step_counts_every_assignment(updates):
+    """The step's side of the three updates: its expert layers' counts
+    after them, as ``change_norms`` reads them."""
+    counts = updates.last_counts
+    assert (counts[FAMILY.LOAD].sum(axis=1)
+            == 3 * 2 * 20 * CFG["num_experts_per_tok"]).all()
+    assert not counts[FAMILY.ROWS_OVER].any()
 
-        net = FAMILY.build_net(cfg, weights)
-        mesh = MeshConfig(dp=1)
-        opt = {"lr": 1e-3, "beta1": 0.9, "beta2": 0.999, "epsilon": 1e-8}
-        step = ShardedTrainStep(
-            net, _loss, mx.optimizer.create(
-                "adam", learning_rate=opt["lr"], beta1=opt["beta1"],
-                beta2=opt["beta2"], epsilon=opt["epsilon"]), mesh,
-            batch_specs=mesh.batch_specs(2, 2), n_labels=1)
-        batches = [_tokens(cfg, seed=s) for s in (4, 5, 6)]
-        losses, first = [], None
-        for bx, by in batches:
-            losses.append(float(step(bx, by).asnumpy()))
-            if first is None:
-                first = {n: onp.sqrt(onp.sum(onp.square(s[0]))) / 0.1
-                         for n, s in jax.device_get(step.states).items()}
-        change = jax.device_get(FAMILY.change_norms(cfg, 11, step.trainable))
-    assert abs(losses[0] - float(eager)) < 1e-5
-    ref = REF.train_reference(lambda: FAMILY.make_weights(cfg, 11), batches,
-                              cfg, opt)
-    onp.testing.assert_allclose(losses, ref["losses"], atol=2e-5)
-    n_layer = len(cfg["hybrid_override_pattern"])
-    g_gaps = REF.leaf_gaps(FAMILY.stack_program_tree(first, n_layer),
-                           ref["grad_norms"])
-    c_gaps = REF.leaf_gaps(FAMILY.stack_program_tree(change, n_layer),
-                           ref["change_norms"])
-    assert REF.worst_leaf(g_gaps)[0] < 2e-3, REF.worst_leaf(g_gaps)
-    dead = REF.dead_leaves(ref["grad_norms"])
-    assert REF.worst_leaf(c_gaps, skip=dead)[0] < 2e-3, \
-        REF.worst_leaf(c_gaps, skip=dead)
-    assert all(v == 0 for n, v in c_gaps.items() if "moe." in n
+
+def test_eager_hybridized_and_sharded_step_agree(updates):
+    """The same seeded net three ways — eager under autograd (the
+    family's one eager case), hybridized, and the first loss of its
+    ``ShardedTrainStep``."""
+    eager, hybrid = updates.eager_and_hybridized
+    assert abs(eager - hybrid) < 1e-6
+    assert abs(updates.losses[0] - eager) < 1e-5
+
+
+def test_the_sharded_steps_three_updates_follow_the_reference(updates):
+    """The step's three updates of loss, first gradient and Adam against
+    the reference's."""
+    run = updates
+    onp.testing.assert_allclose(run.losses, run.ref["losses"], atol=2e-5)
+    assert REF.worst_leaf(run.g_gaps)[0] < 2e-3, REF.worst_leaf(run.g_gaps)
+    assert REF.worst_leaf(run.c_gaps, skip=run.dead)[0] < 2e-3, \
+        REF.worst_leaf(run.c_gaps, skip=run.dead)
+    assert all(v == 0 for n, v in run.c_gaps.items() if "moe." in n
                and ("load" in n or "rows_over" in n))
 
 
@@ -571,51 +423,26 @@ def test_amp_runs_the_products_in_bf16_and_the_state_in_float32():
     assert re.search(r"scan\[", text)
 
 
-def test_scopes_once_a_mixer_and_on_the_backward_pass(monkeypatch):
+def test_scopes_once_a_mixer_and_on_the_backward_pass():
     """``mx.ssm`` once a mixer layer whatever the depth; the scan and the
     convolution carry their scopes forward and in the backward pass
     (where they are made again)."""
-    import collections
-    from jax._src import source_info_util
-    entered = collections.Counter()
-    real = source_info_util.ExtendNameStackContextManager.__enter__
-
-    def counting(self):
-        if self.name.startswith("mx"):
-            entered[self.name] += 1
-        return real(self)
-
-    monkeypatch.setattr(source_info_util.ExtendNameStackContextManager,
-                        "__enter__", counting)
     for pattern in ("ME", "MEM*M"):
         cfg = dict(CFG, hybrid_override_pattern=pattern)
-        net = FAMILY.build_net(cfg, FAMILY.make_weights(cfg, 1))
-        mesh = MeshConfig(dp=1)
-        step = ShardedTrainStep(
-            net, _loss, mx.optimizer.create("adam", learning_rate=1e-3),
-            mesh, batch_specs=mesh.batch_specs(2, 2), n_labels=1)
-        x, y = _tokens(cfg)
-        entered.clear()
-        text = step.lower(x, y).as_text(debug_info=True)
+        net = FAMILY.build_net(cfg, _weights(cfg, 1))
+        text, entered = H.lowered_scopes(net, FAMILY.loss_fn, *_tokens(cfg))
         assert entered["mx.ssm"] == pattern.count("M")
         assert entered["mx.moe"] == pattern.count("E")
         assert entered["mx.attn"] == pattern.count("*")
         for scope in ("mx.ssm.scan", "mx.ssm.conv"):
-            assert re.search(r'jvp\(mx\.fwd\)[^"]*' + re.escape(scope), text)
-            assert re.search(r'transpose\(jvp\(mx\.fwd\)\)[^"]*'
-                             + re.escape(scope), text), scope
+            assert H.on_the_backward_pass(text, scope, sep='[^"]*'), scope
 
 
 def test_scan_counters_once_a_traced_call():
     from mxnet_tpu import telemetry
     args = _scan_inputs(2, 29)
-    telemetry.enable()
-    telemetry.reset()
-    try:
-        ssm.ssd_scan(*args, chunk=8)
-        got = telemetry.counters("ssm.")
-    finally:
-        telemetry.enable(False)
+    _, got = H.counters("ssm.", jax.jit(
+        lambda *a: ssm.ssd_scan(*a, chunk=8)), *args)
     # 2 x 29 tokens; 2 sequences x 4 chunks (the last one padded) x 4 heads
     assert got == {"ssm.scan_tokens_total": 58, "ssm.scan_chunks_total": 32}
     for name in got:
@@ -631,15 +458,14 @@ def test_no_other_family_imports_the_scan():
             "print('mxnet_tpu.ops.ssm' in sys.modules)")
     out = subprocess.run(
         [sys.executable, "-c", code], capture_output=True, text=True,
-        env=dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=_REPO))
+        env=dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=H.REPO))
     assert out.stdout.strip() == "False", out.stderr[-2000:]
 
 
 def test_the_configuration_file_of_the_cell():
     """Every width as published, the four reduced keys and no other, the
     count from the family's shapes, ISSUE 36's FLOPs."""
-    cfg = json.load(open(os.path.join(
-        _REPO, "chipbench", "configs", "nemotron-twotower-30b-a3b.json")))
+    cfg = H.config("nemotron-twotower-30b-a3b")
     assert cfg["reduced"] == ["num_hidden_layers", "hybrid_override_pattern",
                               "num_experts_held", "vocab_size"]
     published = {

@@ -13,40 +13,28 @@ the program for ``highest`` too, so program and reference differ by the
 order of float32 sums only: losses to 2e-5 of ~4.2, gradients to rtol
 2e-3 / atol 3e-6 (the zoo tests' own bounds).  A bf16 product anywhere
 moves a loss by 1e-3 and a gradient leaf by percents: neither passes.
+What the families' tests share is ``tests/family_harness.py``.
 """
+import functools
 import gc
-import importlib.util
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as onp
 import pytest
 
+import family_harness as H
 import mxnet_tpu as mx
-from mxnet_tpu import functional, telemetry
+from mxnet_tpu import functional
 from mxnet_tpu.gluon import block as gluon_block
 from mxnet_tpu.gluon.block import save_these
 from mxnet_tpu.gluon.model_zoo import ouro as zoo
 from mxnet_tpu.ops.xent import sparse_softmax_xent
 from mxnet_tpu.parallel import MeshConfig, ShardedTrainStep
+from family_harness import one_v5e  # noqa: F401  (a fixture)
 
-from test_nemotron_h import _AS_BEFORE, _jaxpr_text
-
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _chipbench(kind):
-    path = os.path.join(_REPO, "chipbench", kind, "ouro.py")
-    spec = importlib.util.spec_from_file_location(
-        f"chipbench_{kind}_ouro", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-REF, FAMILY, FLOPS = (_chipbench(k) for k in ("reference", "families",
-                                              "flops"))
+REF, FAMILY, FLOPS = H.load("ouro")
+_weights = functools.partial(H.weights, "ouro")
 
 CFG = {
     "hidden_size": 64, "intermediate_size": 96, "num_attention_heads": 4,
@@ -62,35 +50,21 @@ SIZES = {
                     total_ut_steps=4),
     "boundaries": dict(CFG, layer_remat=["attn.qkv", "attn.proj"]),
 }
-OPT = {"lr": 1e-3, "beta1": 0.9, "beta2": 0.999, "epsilon": 1e-8}
-
-
-def _tokens(cfg, batch=2, seq=24, seed=0):
-    t = onp.random.default_rng(seed).integers(
-        0, cfg["vocab_size"], (batch, seq + 1), dtype=onp.int32)
-    return t[:, :-1], t[:, 1:]
+_tokens = functools.partial(H.tokens, seq=24)
 
 
 def _reference_loss(cfg, weights, x, y):
-    def loss(p):
-        total, pdf = REF.batch_loss(p, jnp.asarray(x), jnp.asarray(y), cfg)
-        return total / x.size, pdf / x.size
-
-    return jax.jit(jax.value_and_grad(loss, has_aux=True))(dict(weights))
+    """The reference takes the batch whole: one 'sequence' of it."""
+    (loss, pdf), grads = H.reference_loss_and_grads(
+        lambda p, xs, ys: REF.batch_loss(p, xs, ys, cfg), weights,
+        x[None], y[None])
+    return (loss, pdf / x.size), grads
 
 
 def _program_loss(cfg, weights, x, y):
     net = FAMILY.build_net(cfg, weights)
-    trainable, aux = functional.split_params(net)
-    assert list(aux) == ["exit.pdf"]
-
-    def loss(tr):
-        out, mutated = functional.functional_call(
-            net, {**tr, **aux}, x, train=True)
-        return FAMILY.loss_fn(out, y), mutated
-
-    with jax.default_matmul_precision("highest"):
-        return jax.value_and_grad(loss, has_aux=True)(trainable)
+    assert list(functional.split_params(net)[1]) == ["exit.pdf"]
+    return H.program_loss_and_grads(net, FAMILY.loss_fn, x, y)
 
 
 # ---- the zoo model against the reference ---------------------------------
@@ -98,16 +72,13 @@ def _program_loss(cfg, weights, x, y):
 @pytest.mark.parametrize("size", list(SIZES))
 def test_zoo_model_loss_gradients_and_exit_distribution(size):
     cfg = SIZES[size]
-    weights = FAMILY.make_weights(cfg, 7)
+    weights = _weights(cfg, 7)
     x, y = _tokens(cfg)
     (got, mutated), grads = _program_loss(cfg, weights, x, y)
     (want, pdf), ref_grads = _reference_loss(cfg, weights, x, y)
     assert abs(float(got) - float(want)) < 2e-5
-    stacked = FAMILY.stack_program_tree(grads, cfg["num_hidden_layers"])
-    assert set(stacked) == set(ref_grads)
-    for name, ref in ref_grads.items():
-        onp.testing.assert_allclose(stacked[name], ref, atol=3e-6,
-                                    rtol=2e-3, err_msg=name)
+    H.assert_leaves_close(FAMILY.stack_program_tree(
+        grads, cfg["num_hidden_layers"]), ref_grads)
     # the gate learns (from both terms), and every exit has its share
     assert float(jnp.linalg.norm(ref_grads["gate.w"])) > 1e-4
     onp.testing.assert_allclose(mutated["exit.pdf"], pdf, atol=1e-6)
@@ -121,7 +92,7 @@ def test_a_shared_leafs_gradient_is_the_sum_over_its_uses():
     autodiff of the plain loop, in the program through the parameter
     swap."""
     cfg = CFG
-    weights = dict(FAMILY.make_weights(cfg, 3))
+    weights = dict(_weights(cfg, 3))
     x, y = _tokens(cfg, batch=1)
     steps, n = cfg["total_ut_steps"], cfg["num_hidden_layers"]
 
@@ -145,8 +116,7 @@ def test_a_shared_leafs_gradient_is_the_sum_over_its_uses():
 
     copies = {k: jnp.concatenate([weights[k]] * steps)
               for k in REF.LAYER_LEAVES}
-    with jax.default_matmul_precision("highest"):
-        per_use = jax.grad(unshared)(copies)
+    per_use = H.traced(jax.grad(unshared), copies)
     (_, _), ref_grads = _reference_loss(cfg, weights, x, y)
     (_, _), grads = _program_loss(cfg, weights, x, y)
     stacked = FAMILY.stack_program_tree(grads, n)
@@ -167,7 +137,7 @@ def test_one_pass_is_the_same_stack_unlooped_and_has_no_loop_residue():
     entropy), so the loss is the plain stack's mean cross-entropy, and
     the trace holds no loop."""
     cfg = dict(CFG, total_ut_steps=1)
-    weights = FAMILY.make_weights(cfg, 5)
+    weights = _weights(cfg, 5)
     x, y = _tokens(cfg)
     net = FAMILY.build_net(cfg, weights)
     params = functional.param_arrays(net)
@@ -175,15 +145,17 @@ def test_one_pass_is_the_same_stack_unlooped_and_has_no_loop_residue():
     def states(x_):
         return functional.functional_call(net, params, x_, train=True)[0]
 
+    def losses(x_):
+        h, w, log_p = states(x_)
+        return (h, w, log_p), jnp.mean(sparse_softmax_xent(
+            jnp.einsum("bsd,vd->bsv", h[0], w), y)), \
+            FAMILY.loss_fn((h, w, log_p), y)
+
+    (h, w, log_p), plain, got = H.traced(losses, x)
     with jax.default_matmul_precision("highest"):
-        h, w, log_p = states(x)
         text = str(jax.make_jaxpr(states)(x))
     assert h.shape == (1, 2, 24, 64) and not float(jnp.abs(log_p).max())
     assert "scan" not in text and "while" not in text
-    with jax.default_matmul_precision("highest"):
-        plain = jnp.mean(sparse_softmax_xent(
-            jnp.einsum("bsd,vd->bsv", h[0], w), y))
-        got = FAMILY.loss_fn((h, w, log_p), y)
     (want, _), _ = _reference_loss(cfg, weights, x, y)
     assert abs(float(got) - float(plain)) < 1e-6
     assert abs(float(got) - float(want)) < 2e-5
@@ -191,27 +163,29 @@ def test_one_pass_is_the_same_stack_unlooped_and_has_no_loop_residue():
 
 def test_the_exit_distribution_sums_to_one_and_beta_zero_leaves_the_expected_xent():
     cfg = dict(CFG, total_ut_steps=4)
-    weights = dict(FAMILY.make_weights(cfg, 9))
+    weights = dict(_weights(cfg, 9))
     # a gate far from its seeded 0.5, saturating on some tokens
     weights["gate.w"] = weights["gate.w"] * 400.0
     net = FAMILY.build_net(cfg, weights)
     x, y = _tokens(cfg)
-    with jax.default_matmul_precision("highest"):
-        out, mutated = functional.functional_call(
-            net, functional.param_arrays(net), x, train=True)
+    def run(params):
+        out, mutated = functional.functional_call(net, params, x, train=True)
         h, w, log_p = out
         p = jnp.exp(log_p)
-        onp.testing.assert_allclose(p.sum(0), 1.0, atol=2e-6)
-        assert float(p.max()) > 0.99 and float(p.min()) < 1e-3
-        onp.testing.assert_allclose(mutated["exit.pdf"], p.mean((1, 2)),
-                                    atol=1e-6)
         ce = jnp.stack([sparse_softmax_xent(
             jnp.einsum("bsd,vd->bsv", h[t], w), y) for t in range(4)])
-        expected = jnp.mean(jnp.sum(p * ce, axis=0))
-        got0 = zoo.looped_lm_loss(out, y, beta=0.0)
-        got = zoo.looped_lm_loss(out, y, beta=0.1)
+        return (p, log_p, mutated, jnp.mean(jnp.sum(p * ce, axis=0)),
+                zoo.looped_lm_loss(out, y, beta=0.0),
+                zoo.looped_lm_loss(out, y, beta=0.1),
+                -jnp.mean(jnp.sum(p * log_p, axis=0)))
+
+    p, log_p, mutated, expected, got0, got, entropy = H.traced(
+        run, functional.param_arrays(net))
+    onp.testing.assert_allclose(p.sum(0), 1.0, atol=2e-6)
+    assert float(p.max()) > 0.99 and float(p.min()) < 1e-3
+    onp.testing.assert_allclose(mutated["exit.pdf"], p.mean((1, 2)),
+                                atol=1e-6)
     assert abs(float(got0) - float(expected)) < 2e-6
-    entropy = -jnp.mean(jnp.sum(p * log_p, axis=0))
     assert float(entropy) > 5e-3
     assert abs(float(got) - float(expected - 0.1 * entropy)) < 2e-6
 
@@ -244,9 +218,8 @@ def test_the_stacked_chunked_head_against_four_dense_heads(dtype, tol):
             for t in range(steps)])
         return jnp.mean(jnp.sum(jnp.exp(log_p) * (ce + 0.1 * log_p), axis=0))
 
-    with jax.default_matmul_precision("highest"):
-        got, g_got = jax.value_and_grad(stacked, (0, 1, 2))(h, w, log_p)
-        want, g_want = jax.value_and_grad(dense, (0, 1, 2))(h, w, log_p)
+    got, g_got = H.traced(jax.value_and_grad(stacked, (0, 1, 2)), h, w, log_p)
+    want, g_want = H.traced(jax.value_and_grad(dense, (0, 1, 2)), h, w, log_p)
     assert abs(float(got) - float(want)) <= (tol or 1e-6)
     # bf16: dz and dW are bf16 sums of float32-accumulated products on
     # both sides, a unit in the last place of bf16 (0.8 %) apart at most
@@ -257,48 +230,54 @@ def test_the_stacked_chunked_head_against_four_dense_heads(dtype, tol):
                                     rtol=rtol, atol=atol)
 
 
-def test_the_sharded_step_follows_the_reference_and_keeps_the_exit_pdf():
+@pytest.fixture(scope="module")
+def updates():
+    """Three updates by the step, its layers flagged as the cell flags
+    them, and by the reference: once a file."""
+    cfg = dict(CFG, layer_remat=["attn.qkv"])
+    return H.three_updates("ouro", cfg, 11, [_tokens(cfg, seed=s)
+                                             for s in (4, 5, 6)],
+                           cfg["num_hidden_layers"],
+                           look=lambda step: step._remat_on)
+
+
+def test_the_sharded_steps_flag_is_the_layers_alone(updates):
+    """The step's side of the three updates: the cell's flag is on the
+    layers, not on the step, and the step keeps the exit distribution."""
+    assert not updates.seen                # the flag is the layers' alone
+    assert updates.last_pdf and abs(sum(updates.last_pdf) - 1) < 1e-5
+
+
+def test_eager_and_hybridized_agree_with_the_sharded_step(updates):
+    """The family's eager case: the seeded net op by op under
+    ``mx.autograd.record``, hybridized, and the first loss of its
+    ``ShardedTrainStep`` (whose layers are boundaries)."""
+    eager, hybrid = updates.eager_and_hybridized
+    assert abs(eager - hybrid) < 1e-6
+    assert abs(updates.losses[0] - eager) < 1e-5
+
+
+def test_the_sharded_step_follows_the_reference_and_keeps_the_exit_pdf(
+        updates):
     """Three updates through ``ShardedTrainStep``, the layers flagged as
     the cell flags them: losses, the first gradient (from Adam's first
     moment), the parameters' change and ``exit.pdf`` in the step's
     ``aux`` against the reference's."""
-    cfg = dict(CFG, layer_remat=["attn.qkv"])
-    weights = FAMILY.make_weights(cfg, 11)
-    batches = [_tokens(cfg, seed=s) for s in (4, 5, 6)]
-    with jax.default_matmul_precision("highest"):
-        net = FAMILY.build_net(cfg, weights)
-        mesh = MeshConfig(dp=1)
-        step = ShardedTrainStep(
-            net, FAMILY.loss_fn, mx.optimizer.create(
-                "adam", learning_rate=OPT["lr"], beta1=OPT["beta1"],
-                beta2=OPT["beta2"], epsilon=OPT["epsilon"]), mesh,
-            batch_specs=mesh.batch_specs(2, 2), n_labels=1)
-        assert not step._remat_on          # the flag is the layers' alone
-        losses, first = [], None
-        for bx, by in batches:
-            losses.append(float(step(bx, by).asnumpy()))
-            if first is None:
-                first = {n: onp.asarray(s[0]) / (1 - OPT["beta1"])
-                         for n, s in step.states.items()}
-        change = jax.device_get(FAMILY.change_norms(cfg, 11, step.trainable))
-        ref = REF.train_reference(lambda: FAMILY.make_weights(cfg, 11),
-                                  batches, cfg, OPT)
-    onp.testing.assert_allclose(losses, ref["losses"], atol=2e-5)
-    n = cfg["num_hidden_layers"]
+    run, ref = updates, updates.ref
+    onp.testing.assert_allclose(run.losses, ref["losses"], atol=2e-5)
+    n = CFG["num_hidden_layers"]
     norms = {k: onp.sqrt((v.astype(onp.float64) ** 2).sum(
         axis=tuple(range(1, v.ndim)) if k in REF.STACKED else None))
-        for k, v in FAMILY.stack_program_tree(first, n).items()}
+        for k, v in FAMILY.stack_program_tree(run.first, n).items()}
     g_gaps = REF.leaf_gaps({k: onp.atleast_1d(v) for k, v in norms.items()},
                            ref["grad_norms"])
     assert max(g_gaps.values()) < 1e-3, REF.worst_leaf(g_gaps)
-    c_gaps = REF.leaf_gaps(FAMILY.stack_program_tree(change, n),
-                           ref["change_norms"])
+    c_gaps = run.c_gaps
     # Adam divides by sqrt(v): a float32 rounding of a small gradient
     # entry moves its step by more than it moves the gradient's norm
     assert max(c_gaps.values()) < 5e-3, REF.worst_leaf(c_gaps)
     assert c_gaps["exit.pdf"] < 1e-5
-    assert FAMILY.last_pdf and abs(sum(FAMILY.last_pdf) - 1) < 1e-5
-    onp.testing.assert_allclose(step.aux["exit.pdf"],
+    onp.testing.assert_allclose(run.aux["exit.pdf"],
                                 ref["change_norms"]["exit.pdf"], atol=1e-5)
 
 
@@ -325,26 +304,14 @@ def test_needed_flops_against_hand_numbers():
 
 # ---- the recomputation boundary at a child block -------------------------
 
-def _step_of(cfg, seed=13):
+def _step_of(cfg, seed=13, **kw):
     with jax.default_matmul_precision("highest"):
-        net = FAMILY.build_net(cfg, FAMILY.make_weights(cfg, seed))
-        mesh = MeshConfig(dp=1)
-        return ShardedTrainStep(
-            net, FAMILY.loss_fn,
-            mx.optimizer.create("adam", learning_rate=1e-3), mesh,
-            batch_specs=mesh.batch_specs(2, 2), n_labels=1)
+        return H.sharded_step(FAMILY.build_net(cfg, _weights(cfg, seed)),
+                              FAMILY.loss_fn, **kw)
 
 
-def _counted(f, *args):
-    """``f(*args)`` and the ``block.boundary_*`` counters of what it
-    traced."""
-    telemetry.reset()
-    telemetry.enable()
-    try:
-        return f(*args), telemetry.counters("block.boundary")
-    finally:
-        telemetry.enable(False)
-        telemetry.reset()
+#: ``f(*args)`` and the ``block.boundary_*`` counters of what it traced
+_counted = functools.partial(H.counters, "block.boundary")
 
 
 def _flagged_below(block, depth=float("inf")):
@@ -374,6 +341,20 @@ def _regions(jaxpr, inside=False):
 CELL_NAMES = ["pallas_call", "attn.qkv", "attn.proj", "ffn.down"]
 
 
+@functools.lru_cache(maxsize=None)
+def _three_plain_updates():
+    """Three updates without a boundary, once for the five flags: (losses,
+    Adam's first moments after the first, parameters and pdf at the end)."""
+    x, y = _tokens(CFG, seed=2)
+    plain = _step_of(CFG)
+    losses = [plain(x, y).asnumpy()]
+    moments = {n: onp.asarray(s[0]) for n, s in plain.states.items()}
+    losses += [plain(x, y).asnumpy() for _ in range(2)]
+    return (losses, moments,
+            {n: onp.asarray(w) for n, w in plain.trainable.items()},
+            onp.asarray(plain.aux["exit.pdf"]))
+
+
 @pytest.mark.parametrize("remat", [True, "dots", ["attn.qkv", "ffn.inner"],
                                    ["dot_general"], CELL_NAMES])
 def test_a_flagged_child_inside_the_step_changes_no_value(remat):
@@ -394,24 +375,21 @@ def test_a_flagged_child_inside_the_step_changes_no_value(remat):
     3.7)."""
     x, y = _tokens(CFG, seed=2)
     names = isinstance(remat, list)
-    plain, flagged = _step_of(CFG), _step_of(dict(CFG, layer_remat=remat))
-    for i in range(3):
-        a = plain(x, y).asnumpy()
+    losses, moments, trainable, pdf = _three_plain_updates()
+    flagged = _step_of(dict(CFG, layer_remat=remat))
+    for i, a in enumerate(losses):
         b, counts = _counted(lambda: flagged(x, y).asnumpy())
         assert abs(a - b) <= (4 * onp.spacing(a) if names else 0)
         if i == 0:
             assert (counts.get("block.boundary_nested_total", 0) > 0) == names
-            for n, s in plain.states.items():
-                m = onp.asarray(s[0])
+            for n, m in moments.items():
                 onp.testing.assert_allclose(
                     m, onp.asarray(flagged.states[n][0]), rtol=0,
                     atol=1e-5 * onp.abs(m).max(), err_msg=n)
-    for n, w in plain.trainable.items():
-        onp.testing.assert_allclose(onp.asarray(w),
-                                    onp.asarray(flagged.trainable[n]),
+    for n, w in trainable.items():
+        onp.testing.assert_allclose(w, onp.asarray(flagged.trainable[n]),
                                     rtol=0, atol=1e-5, err_msg=n)
-    onp.testing.assert_allclose(plain.aux["exit.pdf"],
-                                flagged.aux["exit.pdf"], atol=1e-7)
+    onp.testing.assert_allclose(pdf, flagged.aux["exit.pdf"], atol=1e-7)
 
 
 @pytest.mark.parametrize("remat,alone", [
@@ -472,13 +450,11 @@ def test_an_unflagged_block_traces_what_it_traced():
     jaxpr the pinned files hold (written at the parents of the PRs that
     pinned them)."""
     name = "grouped_query_attention_plain"
-    with open(os.path.join(_REPO, "tests", "data",
-                           name + ".jaxpr.txt")) as f:
-        want = f.read()
-    assert _jaxpr_text(_AS_BEFORE[name](), (2, 8, 32)) == want
-    layer = _AS_BEFORE[name]()
+    got, want = H.as_before(name)
+    assert got == want
+    layer = H.AS_BEFORE[name]()
     layer.hybridize()
-    nested = _jaxpr_text(layer, (2, 8, 32))
+    nested = H.jaxpr_text(layer, (2, 8, 32))
     assert "remat2" not in nested and "name=_pure" in nested
 
 
@@ -727,27 +703,18 @@ def test_which_flagged_blocks_open_a_region(outer, inner, want):
     assert _regions(fwd.jaxpr) == want
     assert (counts.get("block.boundary_regions_total", 0),
             counts.get("block.boundary_nested_total", 0)) == want
-    policies = [e.params["policy"] for e in _region_eqns(fwd.jaxpr)]
+    # the ``remat2`` equations, outermost first
+    policies = [e.params["policy"] for e in H.eqns(fwd.jaxpr)
+                if e.primitive.name == "remat2"]
     if outer == ["kept"]:
         # the outer region first, then b's, then the Dense's
         assert policies[0] is not None and policies[2] is not None
         assert (policies[1] is None) == (inner is True)
     g0, params0 = grad_of(build(False))
-    want_g, got_g = g0(params0, x), g(params, x)
+    want_g, got_g = jax.jit(g0)(params0, x), jax.jit(g)(params, x)
     for n in want_g:
         onp.testing.assert_allclose(got_g[n], want_g[n], rtol=1e-5,
                                     atol=1e-6, err_msg=n)
-
-
-def _region_eqns(jaxpr):
-    """The ``remat2`` equations of a jaxpr, outermost first."""
-    out = []
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "remat2":
-            out.append(eqn)
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            out += _region_eqns(sub)
-    return out
 
 
 class _CountedCell(mx.gluon.nn.HybridBlock):
@@ -820,16 +787,6 @@ def test_a_looped_block_is_traced_once_and_reads_what_was_rebound(remat):
                                     err_msg=n)
 
 
-def _primitives(jaxpr):
-    """The names of every primitive in a jaxpr and its sub-jaxprs."""
-    names = set()
-    for eqn in jaxpr.eqns:
-        names.add(eqn.primitive.name)
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            names |= _primitives(sub)
-    return names
-
-
 def test_a_dropout_two_regions_deep_draws_from_a_key_handed_down():
     """A flagged pair of cells, each ``x + dropout(dense(x))``, inside
     ``ShardedTrainStep`` under a policy of names: the pair is a region,
@@ -863,8 +820,8 @@ def test_a_dropout_two_regions_deep_draws_from_a_key_handed_down():
             return functional.functional_call(
                 net, functional.param_arrays(net), x, train=True)[0]
 
-    everything = sorted(_primitives(
-        jax.make_jaxpr(traced)(jax.random.PRNGKey(0)).jaxpr))
+    everything = sorted({e.primitive.name for e in H.eqns(
+        jax.make_jaxpr(traced)(jax.random.PRNGKey(0)).jaxpr)})
     assert "dot_general" in everything and any(
         "random" in n or "threefry" in n for n in everything)
 
@@ -890,6 +847,26 @@ def test_a_dropout_two_regions_deep_draws_from_a_key_handed_down():
     assert mx.np.random.uniform(size=(2,)).asnumpy().shape == (2,)
 
 
+def _fp8_histories(remat):
+    """Two fp8 updates with the layers flagged ``remat``: (losses, each
+    site's largest amaxes, the boundary counters of the first)."""
+    x, y = _tokens(CFG, seed=2)
+    with jax.default_matmul_precision("highest"):
+        step = H.sharded_step(
+            FAMILY.build_net(dict(CFG, layer_remat=remat),
+                             _weights(CFG, 13)),
+            FAMILY.loss_fn, precision="fp8")
+        first, counts = _counted(lambda: float(step(x, y).asnumpy()))
+        losses = [first, float(step(x, y).asnumpy())]
+    return losses, {s: {k: float(v.max()) for k, v in h.items()}
+                    for s, h in step.extra["fp8"].items()}, counts
+
+
+#: without a flag, once for both flags it is compared with
+_unflagged_fp8_histories = functools.lru_cache(maxsize=None)(
+    lambda: _fp8_histories(None))
+
+
 @pytest.mark.parametrize("remat", [["attn.qkv"], True],
                          ids=["names", "true"])
 def test_an_fp8_step_sees_through_a_boundary(remat):
@@ -901,25 +878,8 @@ def test_an_fp8_step_sees_through_a_boundary(remat):
     keeps one region an application under a list of names too (regions
     inside it cost it 2.7 GB at the cell's size: the control would not
     load)."""
-    x, y = _tokens(CFG, seed=2)
-
-    def histories(remat):
-        with jax.default_matmul_precision("highest"):
-            net = FAMILY.build_net(dict(CFG, layer_remat=remat),
-                                   FAMILY.make_weights(CFG, 13))
-            mesh = MeshConfig(dp=1)
-            step = ShardedTrainStep(
-                net, FAMILY.loss_fn,
-                mx.optimizer.create("adam", learning_rate=1e-3), mesh,
-                batch_specs=mesh.batch_specs(2, 2), n_labels=1,
-                precision="fp8")
-            first, counts = _counted(lambda: float(step(x, y).asnumpy()))
-            losses = [first, float(step(x, y).asnumpy())]
-        return losses, {s: {k: float(v.max()) for k, v in h.items()}
-                        for s, h in step.extra["fp8"].items()}, counts
-
-    plain_losses, plain, _ = histories(None)
-    flagged_losses, flagged, counts = histories(remat)
+    plain_losses, plain, _ = _unflagged_fp8_histories()
+    flagged_losses, flagged, counts = _fp8_histories(remat)
     apps = CFG["num_hidden_layers"] * CFG["total_ut_steps"]
     assert counts == {"block.boundary_regions_total": apps}
     # seven products a layer; the embedding and the head are sites by
@@ -934,9 +894,6 @@ def test_an_fp8_step_sees_through_a_boundary(remat):
 
 
 # ---- what regions inside a region cost in memory ---------------------------
-
-from test_flash_tiles import one_v5e  # noqa: E402,F401  (a fixture)
-
 
 def _kernel_product(x, w):
     """``x @ w`` by a Pallas kernel, in ``x``'s type."""

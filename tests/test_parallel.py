@@ -461,10 +461,11 @@ def test_weak_scaling_table():
     relative to n=1."""
     from mxnet_tpu.parallel.scaling import weak_scaling_table
     rows = weak_scaling_table(ns=[1, 2], per_device_batch=1, image=16,
-                              iters=2, warmup=1)
+                              iters=1, warmup=0)
     assert [r["n"] for r in rows] == [1, 2]
     assert rows[0]["efficiency"] == 1.0
+    # rows and batches only: a CPU step time is a count or a check, never
+    # a speed (PERF.md), so no bound is put on the ratio of two of them
     for r in rows:
         assert r["ms_per_step"] > 0
         assert r["global_batch"] == r["n"]
-        assert 0 < r["efficiency"] <= 1.5

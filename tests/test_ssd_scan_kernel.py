@@ -4,8 +4,7 @@ XLA composition ``_ssd_chunked`` and against the benchmark's reference
 recurrence, token by token (chipbench/reference/nemotron_h.py) — values
 and every gradient —, and ``ssd_scan``'s dispatch between the two.
 """
-import importlib.util
-import os
+import functools
 import re
 
 import jax
@@ -13,27 +12,17 @@ import jax.numpy as jnp
 import numpy as onp
 import pytest
 
+import family_harness as H
 import mxnet_tpu as mx
+from family_harness import pallas_scopes as _pallas_names
 from mxnet_tpu import runtime, telemetry
 from mxnet_tpu.gluon import nn
 from mxnet_tpu.ops import ssm
 from mxnet_tpu.ops.pallas import ssd_scan
 from mxnet_tpu.parallel import MeshConfig, ShardedTrainStep
 
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NAMES = ("x", "dt", "A", "B", "C", "D")
-
-
-def _reference():
-    path = os.path.join(_REPO, "chipbench", "reference", "nemotron_h.py")
-    spec = importlib.util.spec_from_file_location(
-        "chipbench_reference_nemotron_h_for_the_kernels", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-REF = _reference()
+REF = H.load("nemotron_h")[0]
 
 
 def _operands(batch, seq, heads, dim, groups, state, dtype="float32", seed=0,
@@ -132,11 +121,11 @@ def test_chunk_changes_no_value_through_the_kernels():
     of 8 or of 16; without a gradient no entering state is kept."""
     long = _operands(2, 32, 4, 4, 2, 8, seed=3)
     short = tuple(t[:, :29] if t.ndim > 1 else t for t in long)
-    with jax.default_matmul_precision("highest"):
-        want = ssm._ssd_chunked(*long, 8)[:, :29]
-        for chunk in (8, 16):
-            onp.testing.assert_allclose(ssm._ssd_kernels(*short, chunk),
-                                        want, atol=2e-5, rtol=2e-5)
+    want = H.traced(lambda *a: ssm._ssd_chunked(*a, 8), *long)[:, :29]
+    for chunk in (8, 16):
+        onp.testing.assert_allclose(
+            H.traced(lambda *a: ssm._ssd_kernels(*a, chunk), *short),
+            want, atol=2e-5, rtol=2e-5)
     calls = [e for e in jax.make_jaxpr(
         lambda *a: ssm._ssd_kernels(*a, 8))(*short).jaxpr.eqns
         if e.primitive.name == "custom_vjp_call"]
@@ -160,14 +149,7 @@ def test_fits_takes_whole_registers_only(what, shape, fits):
     assert ssd_scan.fits(*shape) is fits, what
 
 
-def _counted(f, *args):
-    telemetry.reset()
-    telemetry.enable()
-    try:
-        return f(*args), telemetry.counters("ssm.")
-    finally:
-        telemetry.enable(False)
-        telemetry.reset()
+_counted = functools.partial(H.counters, "ssm.")
 
 
 def _aligned(seed=0):
@@ -176,31 +158,13 @@ def _aligned(seed=0):
     return _operands(1, 256, 2, 64, 1, 128, seed=seed)
 
 
-def _pallas_names(f, *args):
-    found = []
-
-    def walk(jaxpr):
-        for e in jaxpr.eqns:
-            if e.primitive.name == "pallas_call":
-                found.append((e.params["name"],
-                              str(e.source_info.name_stack)))
-            for v in e.params.values():
-                for sub in (v if isinstance(v, (list, tuple)) else (v,)):
-                    inner = getattr(sub, "jaxpr", sub)
-                    if hasattr(inner, "eqns"):
-                        walk(inner)
-
-    walk(jax.make_jaxpr(f)(*args).jaxpr)
-    return found
-
-
 def test_off_the_tpu_ssd_scan_is_the_composition():
     """On a CPU no kernel is traced, forward or backward, whatever the
     shapes, and the counter of kernel calls stays where it was."""
     args = _aligned()
     assert ssd_scan.fits(256, 2, 64, 1, 128, 128, 4)
-    _, counts = _counted(jax.grad(
-        lambda *a: jnp.sum(ssm.ssd_scan(*a, chunk=128))), *args)
+    _, counts = _counted(jax.jit(jax.grad(
+        lambda *a: jnp.sum(ssm.ssd_scan(*a, chunk=128)))), *args)
     assert counts == {"ssm.scan_tokens_total": 256,
                       "ssm.scan_chunks_total": 4}
     assert _pallas_names(jax.grad(
@@ -245,7 +209,8 @@ def test_on_the_tpus_route_ssd_scan_takes_the_kernels(monkeypatch):
     assert "transpose(jvp(mx.ssm))/mx.ssm.scan" in calls["mx_ssd_bwd"]
     # shapes the tiles do not fill: the composition, nothing counted
     small = _operands(2, 29, 4, 3, 2, 5)
-    _, counts = _counted(lambda *a: ssm.ssd_scan(*a, chunk=8), *small)
+    _, counts = _counted(jax.jit(lambda *a: ssm.ssd_scan(*a, chunk=8)),
+                         *small)
     assert "ssm.scan_kernel_calls_total" not in counts
     assert _pallas_names(lambda *a: ssm.ssd_scan(*a, chunk=8), *small) == []
 

@@ -5,16 +5,19 @@ and the gradients of ``x``, ``weight`` and ``bias`` — and against zeros
 before the sequence by hand, ``causal_conv1d``'s dispatch between the two
 with its counters, and a mixer's kernels in a lowered step.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as onp
 import pytest
 
+import family_harness as H
+from family_harness import pallas_scopes as _pallas_names
 from mxnet_tpu import functional, runtime, telemetry
 from mxnet_tpu.gluon import nn
 from mxnet_tpu.ops import ssm
 from mxnet_tpu.ops.pallas import ssm_conv
-from test_ssd_scan_kernel import _pallas_names
 
 NAMES = ("x", "weight", "bias")
 
@@ -36,14 +39,7 @@ def _value_and_grads(f, args, ct):
     return y, grads
 
 
-def _counted(f, *args):
-    telemetry.reset()
-    telemetry.enable()
-    try:
-        return f(*args), telemetry.counters("ssm.conv")
-    finally:
-        telemetry.enable(False)
-        telemetry.reset()
+_counted = functools.partial(H.counters, "ssm.conv")
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
@@ -82,7 +78,7 @@ def test_the_first_tokens_read_zeros_and_a_block_its_neighbours():
     chunk's edge; and a token's gradient reaches the three tokens before
     it across that edge."""
     x, w, bias = _operands(1, 256, 8, 4, seed=2)
-    y = ssm._conv_by_kernels(x, w, bias, False)
+    y = jax.jit(lambda *a: ssm._conv_by_kernels(*a, False))(x, w, bias)
     xs, ws, bs = (onp.asarray(t, onp.float64) for t in (x, w, bias))
     for t in (0, 1, 2, 3, 126, 127, 128, 129, 130, 131, 255):
         want = bs.copy()
@@ -90,8 +86,8 @@ def test_the_first_tokens_read_zeros_and_a_block_its_neighbours():
             if t - 3 + k >= 0:
                 want += ws[:, k] * xs[0, t - 3 + k]
         onp.testing.assert_allclose(y[0, t], want, atol=1e-5, err_msg=str(t))
-    dx = jax.grad(lambda x_: ssm._conv_by_kernels(x_, w, bias, False)
-                  [0, 129, 0])(x)
+    dx = jax.jit(jax.grad(lambda x_: ssm._conv_by_kernels(
+        x_, w, bias, False)[0, 129, 0]))(x)
     want = onp.zeros((256, 8))
     want[126:130, 0] = ws[0]
     onp.testing.assert_allclose(dx[0], want, atol=1e-6)
@@ -119,7 +115,7 @@ def test_off_the_tpu_causal_conv1d_is_the_composition():
     args = _operands(2, 256, 16, 4)
     assert ssm_conv.fits(256, 16, 4, 4)
     grad = jax.grad(lambda *a: jnp.sum(ssm.causal_conv1d(*a, "silu")))
-    _, counts = _counted(grad, *args)
+    _, counts = _counted(jax.jit(grad), *args)
     assert counts == {"ssm.conv_tokens_total": 512}
     assert _pallas_names(grad, *args) == []
     for name in ("ssm.conv_tokens_total", "ssm.conv_kernel_calls_total"):
@@ -159,7 +155,7 @@ def test_on_the_tpus_route_causal_conv1d_takes_the_kernels(monkeypatch):
     assert "transpose(jvp(mx.ssm))/mx.ssm.conv" in calls["mx_ssm_conv_bwd"]
     # shapes the tiles do not fill: the composition, no kernel call counted
     small = _operands(2, 29, 6, 4)
-    _, counts = _counted(conv, *small)
+    _, counts = _counted(jax.jit(conv), *small)
     assert counts == {"ssm.conv_tokens_total": 58}
     assert _pallas_names(conv, *small) == []
 
@@ -180,9 +176,8 @@ def test_a_mixer_by_the_kernels_is_the_mixer_by_the_composition(monkeypatch):
         return jnp.sum(out * ct), out
 
     def run():
-        with jax.default_matmul_precision("highest"):
-            return _counted(jax.value_and_grad(loss, (0, 1), has_aux=True),
-                            params, x)
+        return _counted(H.traced, jax.value_and_grad(
+            loss, (0, 1), has_aux=True), params, x)
 
     ((_, want), (gp_want, gx_want)), counts = run()
     assert counts == {"ssm.conv_tokens_total": 512}
