@@ -56,7 +56,7 @@ class GPTModel(HybridBlock):
 
     def init_cache(self, max_slots, max_seq=None, dtype="float32"):
         """Fixed-footprint decode cache: per layer one
-        (max_slots, max_seq, heads, head_dim) K and V pair."""
+        (max_slots, max_seq, units) K and V pair."""
         max_seq = self._max_length if max_seq is None else max_seq
         if max_seq > self._max_length:
             raise ValueError(
@@ -73,13 +73,13 @@ class GPTModel(HybridBlock):
         x, caches = self.decoder.prefill(x, caches, slot)
         return self.final_ln(x), caches
 
-    def decode_step(self, tokens, caches, positions):
+    def decode_step(self, tokens, caches, positions, live=None):
         """Advance every slot one token: tokens (slots, 1) int32,
-        positions (slots,) int32 cache rows. Returns
-        (hidden (slots, 1, units), caches)."""
+        positions (slots,) int32 cache rows, live (slots,) bool the slots
+        whose output is read. Returns (hidden (slots, 1, units), caches)."""
         x = self.word_embed(tokens) \
             + self.position_embed(positions.reshape(-1, 1))
-        x, caches = self.decoder.decode_step(x, caches, positions)
+        x, caches = self.decoder.decode_step(x, caches, positions, live)
         return self.final_ln(x), caches
 
     def prefill_suffix(self, inputs, caches, slot, start):
@@ -146,8 +146,9 @@ class GPTForCausalLM(HybridBlock):
         w = self.backbone.word_embed.weight.data()
         return np.dot(h, w.T), caches
 
-    def decode_step(self, tokens, caches, positions):
-        h, caches = self.backbone.decode_step(tokens, caches, positions)
+    def decode_step(self, tokens, caches, positions, live=None):
+        h, caches = self.backbone.decode_step(tokens, caches, positions,
+                                              live)
         w = self.backbone.word_embed.weight.data()
         return np.dot(h[:, 0], w.T), caches
 

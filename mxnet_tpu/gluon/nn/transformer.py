@@ -61,16 +61,16 @@ class MultiHeadAttention(HybridBlock):
 
     def init_cache(self, max_slots, max_seq, dtype="float32"):
         """Preallocate one (k, v) cache pair:
-        (max_slots, max_seq, heads, head_dim) each.
+        (max_slots, max_seq, units) each, a row as the projections give it
+        (the heads side by side: what the decode step's kernel reads).
 
         ``dtype="int8"`` selects quantized storage: each of k/v becomes a
         (values int8, scales float32) pair with one symmetric scale per
         (slot, row, head) — same fixed footprint at a quarter of the
         fp32 bytes (docs/SERVING.md "Low-bit weights and KV cache")."""
-        d = self._units // self._heads
-        shape = (max_slots, max_seq, self._heads, d)
+        shape = (max_slots, max_seq, self._units)
         if str(dtype) == "int8":
-            sshape = (max_slots, max_seq, self._heads, 1)
+            sshape = (max_slots, max_seq, self._heads)
             return ((np.zeros(shape, dtype="int8"),
                      np.ones(sshape, dtype="float32")),
                     (np.zeros(shape, dtype="int8"),
@@ -101,9 +101,9 @@ class MultiHeadAttention(HybridBlock):
         out = multi_head_attention(q, k, v, self._heads, causal=True)
         return self.out_proj(out), new_kv
 
-    def decode_step(self, x, kv, positions):
+    def decode_step(self, x, kv, positions, live=None):
         """One cached decode step: x is (slots, 1, units), ``positions``
-        (slots,) the cache row each slot's token occupies."""
+        (slots,) each slot's cache row, ``live`` the slots that are read."""
         from ...ops.attention import decode_attention, decode_attention_q8
         q = self.query_proj(x)
         k = self.key_proj(x)
@@ -114,7 +114,7 @@ class MultiHeadAttention(HybridBlock):
                 q, k, v, kc, ks, vc, vs, positions, self._heads)
             return self.out_proj(out), ((kc, ks), (vc, vs))
         out, k_cache, v_cache = decode_attention(
-            q, k, v, kv[0], kv[1], positions, self._heads)
+            q, k, v, kv[0], kv[1], positions, self._heads, live)
         return self.out_proj(out), (k_cache, v_cache)
 
     def prefill_suffix(self, x, kv, slot, start):
@@ -525,13 +525,13 @@ class TransformerEncoderCell(HybridBlock):
         x = self.attn_ln(x + h)
         return self.ffn_ln(x + self.ffn(x)), kv
 
-    def decode_step(self, x, kv, positions):
+    def decode_step(self, x, kv, positions, live=None):
         if self._pre_norm:
             h, kv = self.attention.decode_step(self.attn_ln(x), kv,
-                                               positions)
+                                               positions, live)
             x = x + h
             return x + self.ffn(self.ffn_ln(x)), kv
-        h, kv = self.attention.decode_step(x, kv, positions)
+        h, kv = self.attention.decode_step(x, kv, positions, live)
         x = self.attn_ln(x + h)
         return self.ffn_ln(x + self.ffn(x)), kv
 
@@ -620,10 +620,10 @@ class TransformerEncoder(HybridBlock):
             out.append(kv)
         return x, out
 
-    def decode_step(self, x, caches, positions):
+    def decode_step(self, x, caches, positions, live=None):
         out = []
         for cell, kv in zip(self._layers, caches):
-            x, kv = cell.decode_step(x, kv, positions)
+            x, kv = cell.decode_step(x, kv, positions, live)
             out.append(kv)
         return x, out
 

@@ -14,7 +14,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .. import runtime as _runtime
+from .. import runtime as _runtime, telemetry as _telemetry
 from ..numpy.multiarray import _invoke
 
 
@@ -146,54 +146,120 @@ def _flash(qh, kh, vh, causal, window=None, selection=None,
                      check_vma=False)(*operands)
 
 
+# -- The KV cache (mx.serve) --------------------------------------------
+# A cache leaf is (max_slots, max_seq, heads*dim): a row is the K or V
+# projection's row, the heads side by side along the lanes.  The decode
+# step's kernel reads it where it lies (a Mosaic operand's layout is fixed,
+# so XLA writes the step's row in place and copies nothing); the
+# compositions view a row as (heads, dim) for their einsums.  An int8 cache
+# is a (values, scales) pair a leaf, scales (max_slots, max_seq, heads)
+# float32, one a written row and head.  Rows past a slot's position hold
+# whatever was there: nothing reads them before they are written again.
+
+
+def _int32(x):
+    """A slot or row operand, traced or a Python number, as int32."""
+    return x.astype(jnp.int32) if hasattr(x, "astype") else jnp.int32(x)
+
+
+def _heads_apart(rows, heads):
+    """(..., heads*dim) cache rows -> (..., heads, dim): the view the
+    compositions' einsums take (the cache itself keeps a row whole)."""
+    return rows.reshape(rows.shape[:-1] + (heads, rows.shape[-1] // heads))
+
+
+def _dequantized(values, scales, dtype):
+    """int8 (..., heads*dim) rows with their (..., heads) scales ->
+    (..., heads, dim) in ``dtype``; fuses into the einsum that reads it."""
+    return _heads_apart(values, scales.shape[-1]).astype(dtype) \
+        * scales.astype(dtype)[..., None]
+
+
+def _slot_rows(leaf, slot):
+    """One slot of a cache leaf: (max_seq, ...)."""
+    return jax.lax.dynamic_index_in_dim(leaf, slot, 0, keepdims=False)
+
+
+def _step_rows(pos, max_seq):
+    """``(lane, row)`` of the decode step's scatter: each slot's own row,
+    clipped into the cache."""
+    return (jnp.arange(pos.shape[0]),
+            jnp.clip(pos.astype(jnp.int32), 0, max_seq - 1))
+
+
+def _multi_rows(pos, t, max_seq):
+    """``(lane, rows)`` of the verify step's scatter: t rows a slot from
+    its position on, clipped into the cache."""
+    rows = jnp.clip(pos.astype(jnp.int32)[:, None] + jnp.arange(t),
+                    0, max_seq - 1)
+    return jnp.arange(pos.shape[0])[:, None], rows
+
+
+def decode_read_block(k_cache):
+    """Cache rows a grid step of ``decode_attention``'s kernel reads, where
+    the kernel takes a cache leaf like ``k_cache`` — a TPU, one array a
+    leaf (no int8 pair), shapes ``ops/pallas/decode_attention.py::fits``
+    holds for; None where the composition runs.  ``ServeEngine`` reads
+    its ``decode_rows_read_share`` off this."""
+    if isinstance(k_cache, (tuple, list)) or len(k_cache.shape) != 3 \
+            or not _runtime.on_tpu():
+        return None
+    from .pallas import decode_attention as kernel
+    shape = k_cache.shape[1:] + (jnp.dtype(k_cache.dtype).itemsize,)
+    if not kernel.fits(*shape):
+        return None
+    return kernel._block(*shape)
+
+
+def _softmax_over_rows(scores, visible, dtype):
+    """The compositions' softmax: masked, float32, back in ``dtype``."""
+    scores = jnp.where(visible, scores, -1e30)
+    return jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(dtype)
+
+
 def write_prefill_kv(k_cache, v_cache, key, value, slot, heads):
     """Write a whole prompt's projected K/V into one cache slot.
 
     ``key``/``value`` are (1, L, heads*dim) projections; the caches are
-    (max_slots, max_seq, heads, dim). Rows [slot, :L] are overwritten (rows
+    (max_slots, max_seq, heads*dim): a row is the projection's row, the
+    heads side by side (what ``decode_attention``'s kernel reads where it
+    lies). Rows [slot, :L] are overwritten (rows
     beyond L keep stale values — they are never attended because the decode
     mask is bounded by the slot's position counter and every row below it
     is rewritten in order before it becomes visible). ``slot`` may be a
     traced scalar, so one compiled prefill serves every slot.
     """
     def fn(kc, vc, k, v, s):
-        _, seq_len, hd = k.shape
-        d = hd // heads
-        kh = k.reshape(1, seq_len, heads, d).astype(kc.dtype)
-        vh = v.reshape(1, seq_len, heads, d).astype(vc.dtype)
-        start = (s.astype(jnp.int32) if hasattr(s, "astype") else
-                 jnp.int32(s), 0, 0, 0)
-        return (jax.lax.dynamic_update_slice(kc, kh, start),
-                jax.lax.dynamic_update_slice(vc, vh, start))
+        start = (_int32(s), 0, 0)
+        return (jax.lax.dynamic_update_slice(kc, k.astype(kc.dtype), start),
+                jax.lax.dynamic_update_slice(vc, v.astype(vc.dtype), start))
 
     return _invoke(fn, (k_cache, v_cache, key, value, slot),
                    name="write_prefill_kv")
 
 
-def _quantize_kv_rows(x, int8_max=127.0):
-    """Symmetric int8 over the last (head_dim) axis: one scale per
-    (slot, row, head) — each written row computes its own scale, so the
-    fixed-footprint cache never needs requantization."""
-    xf = x.astype(jnp.float32)
+def _quantize_kv_rows(x, heads, int8_max=127.0):
+    """Symmetric int8 over each head's ``dim`` of (..., heads*dim) rows:
+    one scale per (slot, row, head), (..., heads) float32 — each written
+    row computes its own scale, so the fixed-footprint cache never needs
+    requantization."""
+    xf = _heads_apart(x, heads).astype(jnp.float32)
     scale = jnp.max(jnp.abs(xf), axis=-1, keepdims=True) / int8_max
     scale = jnp.where(scale == 0, 1.0, scale)
     q = jnp.clip(jnp.round(xf / scale), -int8_max, int8_max)
-    return q.astype(jnp.int8), scale
+    return q.astype(jnp.int8).reshape(x.shape), scale[..., 0]
 
 
 def write_prefill_kv_q8(k_cache, k_scale, v_cache, v_scale, key, value,
                         slot, heads):
     """int8-cache variant of :func:`write_prefill_kv`: quantizes the
     prompt's projected K/V per (row, head) and writes values + scales.
-    Caches are (max_slots, max_seq, heads, dim) int8; scales
-    (max_slots, max_seq, heads, 1) float32."""
+    Caches are (max_slots, max_seq, heads*dim) int8; scales
+    (max_slots, max_seq, heads) float32."""
     def fn(kc, ks, vc, vs, k, v, s):
-        _, seq_len, hd = k.shape
-        d = hd // heads
-        kq, ksc = _quantize_kv_rows(k.reshape(1, seq_len, heads, d))
-        vq, vsc = _quantize_kv_rows(v.reshape(1, seq_len, heads, d))
-        start = (s.astype(jnp.int32) if hasattr(s, "astype") else
-                 jnp.int32(s), 0, 0, 0)
+        kq, ksc = _quantize_kv_rows(k, heads)
+        vq, vsc = _quantize_kv_rows(v, heads)
+        start = (_int32(s), 0, 0)
         return (jax.lax.dynamic_update_slice(kc, kq, start),
                 jax.lax.dynamic_update_slice(ks, ksc, start),
                 jax.lax.dynamic_update_slice(vc, vq, start),
@@ -254,32 +320,14 @@ def suffix_prefill_attention(q, k, v, k_cache, v_cache, slot, start, heads):
     start + Ls <= max_seq (the engine falls back to full prefill
     otherwise)."""
     def fn(q, k, v, kc, vc, s, st):
-        _, ls, hd = q.shape
-        d = hd // heads
-        max_seq = kc.shape[1]
-        s32 = jnp.int32(s) if not hasattr(s, "astype") else \
-            s.astype(jnp.int32)
-        st32 = jnp.int32(st) if not hasattr(st, "astype") else \
-            st.astype(jnp.int32)
-        kh = k.reshape(1, ls, heads, d).astype(kc.dtype)
-        vh = v.reshape(1, ls, heads, d).astype(vc.dtype)
-        kc = jax.lax.dynamic_update_slice(kc, kh, (s32, st32, 0, 0))
-        vc = jax.lax.dynamic_update_slice(vc, vh, (s32, st32, 0, 0))
-        kslot = jax.lax.dynamic_slice(
-            kc, (s32, 0, 0, 0), (1, max_seq, heads, d))[0]
-        vslot = jax.lax.dynamic_slice(
-            vc, (s32, 0, 0, 0), (1, max_seq, heads, d))[0]
-        qh = q.reshape(ls, heads, d)
-        scale = 1.0 / (d ** 0.5)
-        scores = jnp.einsum("qhd,shd->hqs", qh,
-                            kslot.astype(q.dtype)) * scale
-        visible = (jnp.arange(max_seq)[None, :]
-                   <= (st32 + jnp.arange(ls))[:, None])
-        scores = jnp.where(visible[None, :, :], scores, -1e30)
-        att = jax.nn.softmax(scores.astype(jnp.float32),
-                             axis=-1).astype(q.dtype)
-        out = jnp.einsum("hqs,shd->qhd", att, vslot.astype(q.dtype))
-        return out.reshape(1, ls, hd), kc, vc
+        s32, st32 = _int32(s), _int32(st)
+        kc = jax.lax.dynamic_update_slice(kc, k.astype(kc.dtype),
+                                          (s32, st32, 0))
+        vc = jax.lax.dynamic_update_slice(vc, v.astype(vc.dtype),
+                                          (s32, st32, 0))
+        kslot = _heads_apart(_slot_rows(kc, s32), heads).astype(q.dtype)
+        vslot = _heads_apart(_slot_rows(vc, s32), heads).astype(q.dtype)
+        return _suffix_attend(q, kslot, vslot, st32, heads), kc, vc
 
     return _invoke(fn, (q, k, v, k_cache, v_cache, slot, start),
                    name="suffix_prefill_attention")
@@ -292,37 +340,19 @@ def suffix_prefill_attention_q8(q, k, v, k_cache, k_scale, v_cache,
     the write (scales land beside the copied prefix's scales), and the
     slot's cached K/V dequantizes into the score/value einsums."""
     def fn(q, k, v, kc, ks, vc, vs, s, st):
-        _, ls, hd = q.shape
-        d = hd // heads
-        max_seq = kc.shape[1]
-        s32 = jnp.int32(s) if not hasattr(s, "astype") else \
-            s.astype(jnp.int32)
-        st32 = jnp.int32(st) if not hasattr(st, "astype") else \
-            st.astype(jnp.int32)
-        kq, ksc = _quantize_kv_rows(k.reshape(1, ls, heads, d))
-        vq, vsc = _quantize_kv_rows(v.reshape(1, ls, heads, d))
-        kc = jax.lax.dynamic_update_slice(kc, kq, (s32, st32, 0, 0))
-        ks = jax.lax.dynamic_update_slice(ks, ksc, (s32, st32, 0, 0))
-        vc = jax.lax.dynamic_update_slice(vc, vq, (s32, st32, 0, 0))
-        vs = jax.lax.dynamic_update_slice(vs, vsc, (s32, st32, 0, 0))
-        kslot = jax.lax.dynamic_slice(
-            kc, (s32, 0, 0, 0), (1, max_seq, heads, d))[0].astype(q.dtype)
-        kssl = jax.lax.dynamic_slice(
-            ks, (s32, 0, 0, 0), (1, max_seq, heads, 1))[0].astype(q.dtype)
-        vslot = jax.lax.dynamic_slice(
-            vc, (s32, 0, 0, 0), (1, max_seq, heads, d))[0].astype(q.dtype)
-        vssl = jax.lax.dynamic_slice(
-            vs, (s32, 0, 0, 0), (1, max_seq, heads, 1))[0].astype(q.dtype)
-        qh = q.reshape(ls, heads, d)
-        scale = 1.0 / (d ** 0.5)
-        scores = jnp.einsum("qhd,shd->hqs", qh, kslot * kssl) * scale
-        visible = (jnp.arange(max_seq)[None, :]
-                   <= (st32 + jnp.arange(ls))[:, None])
-        scores = jnp.where(visible[None, :, :], scores, -1e30)
-        att = jax.nn.softmax(scores.astype(jnp.float32),
-                             axis=-1).astype(q.dtype)
-        out = jnp.einsum("hqs,shd->qhd", att, vslot * vssl)
-        return out.reshape(1, ls, hd), kc, ks, vc, vs
+        s32, st32 = _int32(s), _int32(st)
+        kq, ksc = _quantize_kv_rows(k, heads)
+        vq, vsc = _quantize_kv_rows(v, heads)
+        kc = jax.lax.dynamic_update_slice(kc, kq, (s32, st32, 0))
+        ks = jax.lax.dynamic_update_slice(ks, ksc, (s32, st32, 0))
+        vc = jax.lax.dynamic_update_slice(vc, vq, (s32, st32, 0))
+        vs = jax.lax.dynamic_update_slice(vs, vsc, (s32, st32, 0))
+        kslot = _dequantized(_slot_rows(kc, s32), _slot_rows(ks, s32),
+                             q.dtype)
+        vslot = _dequantized(_slot_rows(vc, s32), _slot_rows(vs, s32),
+                             q.dtype)
+        return (_suffix_attend(q, kslot, vslot, st32, heads),
+                kc, ks, vc, vs)
 
     return _invoke(fn, (q, k, v, k_cache, k_scale, v_cache, v_scale,
                         slot, start), name="suffix_prefill_attention_q8")
@@ -338,30 +368,16 @@ def decode_multi_attention(query, key, value, k_cache, v_cache, positions,
     :func:`decode_attention` — clipped writes only ever touch rows above
     the slot's position counter, which are rewritten before becoming
     visible).  Query j attends rows <= positions + j, so the t tokens
-    verify causally in ONE batched call."""
+    verify causally in ONE batched call.  The XLA composition on every
+    backend (``decode_attention``'s kernel takes one query a slot)."""
     def fn(q, k, v, kc, vc, pos):
-        n, t, hd = q.shape
-        d = hd // heads
-        max_seq = kc.shape[1]
-        rows = jnp.clip(pos.astype(jnp.int32)[:, None] + jnp.arange(t),
-                        0, max_seq - 1)
-        lane = jnp.arange(n)[:, None]
-        kc = kc.at[lane, rows].set(k.reshape(n, t, heads, d)
-                                   .astype(kc.dtype))
-        vc = vc.at[lane, rows].set(v.reshape(n, t, heads, d)
-                                   .astype(vc.dtype))
-        qh = q.reshape(n, t, heads, d)
-        scale = 1.0 / (d ** 0.5)
-        scores = jnp.einsum("nqhd,nshd->nhqs", qh,
-                            kc.astype(q.dtype)) * scale
-        limit = pos.astype(jnp.int32)[:, None] + jnp.arange(t)
-        visible = (jnp.arange(max_seq)[None, None, :]
-                   <= limit[:, :, None])[:, None, :, :]
-        scores = jnp.where(visible, scores, -1e30)
-        att = jax.nn.softmax(scores.astype(jnp.float32),
-                             axis=-1).astype(q.dtype)
-        out = jnp.einsum("nhqs,nshd->nqhd", att, vc.astype(q.dtype))
-        return out.reshape(n, t, hd), kc, vc
+        lane, rows = _multi_rows(pos, q.shape[1], kc.shape[1])
+        kc = kc.at[lane, rows].set(k.astype(kc.dtype))
+        vc = vc.at[lane, rows].set(v.astype(vc.dtype))
+        out = _multi_attend(q, _heads_apart(kc, heads).astype(q.dtype),
+                            _heads_apart(vc, heads).astype(q.dtype), pos,
+                            heads)
+        return out, kc, vc
 
     return _invoke(fn, (query, key, value, k_cache, v_cache, positions),
                    name="decode_multi_attention")
@@ -374,31 +390,16 @@ def decode_multi_attention_q8(query, key, value, k_cache, k_scale, v_cache,
     dequant fusing into the einsums exactly like
     :func:`decode_attention_q8`."""
     def fn(q, k, v, kc, ks, vc, vs, pos):
-        n, t, hd = q.shape
-        d = hd // heads
-        max_seq = kc.shape[1]
-        rows = jnp.clip(pos.astype(jnp.int32)[:, None] + jnp.arange(t),
-                        0, max_seq - 1)
-        lane = jnp.arange(n)[:, None]
-        kq, ksc = _quantize_kv_rows(k.reshape(n, t, heads, d))
-        vq, vsc = _quantize_kv_rows(v.reshape(n, t, heads, d))
+        lane, rows = _multi_rows(pos, q.shape[1], kc.shape[1])
+        kq, ksc = _quantize_kv_rows(k, heads)
+        vq, vsc = _quantize_kv_rows(v, heads)
         kc = kc.at[lane, rows].set(kq)
         ks = ks.at[lane, rows].set(ksc)
         vc = vc.at[lane, rows].set(vq)
         vs = vs.at[lane, rows].set(vsc)
-        qh = q.reshape(n, t, heads, d)
-        scale = 1.0 / (d ** 0.5)
-        kf = kc.astype(q.dtype) * ks.astype(q.dtype)
-        scores = jnp.einsum("nqhd,nshd->nhqs", qh, kf) * scale
-        limit = pos.astype(jnp.int32)[:, None] + jnp.arange(t)
-        visible = (jnp.arange(max_seq)[None, None, :]
-                   <= limit[:, :, None])[:, None, :, :]
-        scores = jnp.where(visible, scores, -1e30)
-        att = jax.nn.softmax(scores.astype(jnp.float32),
-                             axis=-1).astype(q.dtype)
-        vf = vc.astype(q.dtype) * vs.astype(q.dtype)
-        out = jnp.einsum("nhqs,nshd->nqhd", att, vf)
-        return out.reshape(n, t, hd), kc, ks, vc, vs
+        out = _multi_attend(q, _dequantized(kc, ks, q.dtype),
+                            _dequantized(vc, vs, q.dtype), pos, heads)
+        return out, kc, ks, vc, vs
 
     return _invoke(fn, (query, key, value, k_cache, k_scale, v_cache,
                         v_scale, positions),
@@ -413,65 +414,64 @@ def decode_attention_q8(query, key, value, k_cache, k_scale, v_cache,
     memory-bound on the cache at long contexts — moves a quarter of the
     fp32 bytes. The current token's K/V is quantized with its own row
     scale before the write; attention math itself stays in the query
-    dtype with an f32 softmax, exactly like the fp path."""
+    dtype with an f32 softmax, exactly like the fp path's composition
+    (which this is on every backend: the kernel reads no scales)."""
     def fn(q, k, v, kc, ks, vc, vs, pos):
-        n, _, hd = q.shape
-        d = hd // heads
-        max_seq = kc.shape[1]
-        row = jnp.clip(pos.astype(jnp.int32), 0, max_seq - 1)
-        lane = jnp.arange(n)
-        kq, ksc = _quantize_kv_rows(k.reshape(n, heads, d))
-        vq, vsc = _quantize_kv_rows(v.reshape(n, heads, d))
+        lane, row = _step_rows(pos, kc.shape[1])
+        kq, ksc = _quantize_kv_rows(k[:, 0], heads)
+        vq, vsc = _quantize_kv_rows(v[:, 0], heads)
         kc = kc.at[lane, row].set(kq)
         ks = ks.at[lane, row].set(ksc)
         vc = vc.at[lane, row].set(vq)
         vs = vs.at[lane, row].set(vsc)
-        qh = q.reshape(n, heads, d)
-        scale = 1.0 / (d ** 0.5)
-        kf = kc.astype(q.dtype) * ks.astype(q.dtype)
-        scores = jnp.einsum("nhd,nshd->nhs", qh, kf) * scale
-        visible = (jnp.arange(max_seq)[None, :] <= row[:, None])[:, None, :]
-        scores = jnp.where(visible, scores, -1e30)
-        att = jax.nn.softmax(scores.astype(jnp.float32),
-                             axis=-1).astype(q.dtype)
-        vf = vc.astype(q.dtype) * vs.astype(q.dtype)
-        out = jnp.einsum("nhs,nshd->nhd", att, vf)
-        return out.reshape(n, 1, hd), kc, ks, vc, vs
+        out = _step_attend(q, _dequantized(kc, ks, q.dtype),
+                           _dequantized(vc, vs, q.dtype), row, heads)
+        return out, kc, ks, vc, vs
 
     return _invoke(fn, (query, key, value, k_cache, k_scale, v_cache,
                         v_scale, positions), name="decode_attention_q8")
 
 
-def decode_attention(query, key, value, k_cache, v_cache, positions, heads):
+def decode_attention(query, key, value, k_cache, v_cache, positions, heads,
+                     live=None):
     """Single-token cached attention for continuous-batching decode.
 
     ``query``/``key``/``value`` are (slots, 1, heads*dim) projections of the
-    current token in every slot; caches are (slots, max_seq, heads, dim);
+    current token in every slot; caches are (slots, max_seq, heads*dim);
     ``positions`` (slots,) is the row each slot's new K/V lands in. Writes
-    the new K/V, attends rows <= positions (static shapes — the mask, not
-    the extent, varies), and returns (out, k_cache, v_cache). Score
-    materialization is (slots, heads, max_seq) — tiny, so no flash path.
-    """
-    def fn(q, k, v, kc, vc, pos):
-        n, _, hd = q.shape
-        d = hd // heads
-        max_seq = kc.shape[1]
-        row = jnp.clip(pos.astype(jnp.int32), 0, max_seq - 1)
-        lane = jnp.arange(n)
-        kc = kc.at[lane, row].set(k.reshape(n, heads, d).astype(kc.dtype))
-        vc = vc.at[lane, row].set(v.reshape(n, heads, d).astype(vc.dtype))
-        qh = q.reshape(n, heads, d)
-        scale = 1.0 / (d ** 0.5)
-        scores = jnp.einsum("nhd,nshd->nhs", qh,
-                            kc.astype(q.dtype)) * scale
-        visible = (jnp.arange(max_seq)[None, :] <= row[:, None])[:, None, :]
-        scores = jnp.where(visible, scores, -1e30)
-        att = jax.nn.softmax(scores.astype(jnp.float32),
-                             axis=-1).astype(q.dtype)
-        out = jnp.einsum("nhs,nshd->nhd", att, vc.astype(q.dtype))
-        return out.reshape(n, 1, hd), kc, vc
+    the new K/V — a scatter XLA applies in place to a donated cache — and
+    attends rows <= positions; returns (out, k_cache, v_cache).  ``live``
+    (slots,) bool, where given, names the slots whose output is read.
 
-    return _invoke(fn, (query, key, value, k_cache, v_cache, positions),
+    The read adapts to what the call can see: on a TPU, at shapes
+    ``ops/pallas/decode_attention.py::fits`` takes, the Pallas kernel
+    ``mx_decode_attn`` reads each live slot's rows in blocks, where the
+    cache lies, and skips the blocks past a position and the idle slots
+    whole (their output is zeros); everywhere else the XLA composition
+    reads all ``max_seq`` rows of every slot behind a mask (static shapes
+    — the mask, not the extent, varies), which is also the kernel's
+    reference.
+    """
+    by_kernel = decode_read_block(k_cache) is not None
+    if by_kernel and _telemetry._active:
+        _telemetry.inc("serve.decode_kernel_calls_total")
+
+    def fn(q, k, v, kc, vc, pos, *read):
+        lane, row = _step_rows(pos, kc.shape[1])
+        kc = kc.at[lane, row].set(k[:, 0].astype(kc.dtype))
+        vc = vc.at[lane, row].set(v[:, 0].astype(vc.dtype))
+        if by_kernel:
+            from .pallas.decode_attention import decode_read
+            rows = jnp.where(read[0], row + 1, 0) if read else row + 1
+            return decode_read(q, kc, vc, rows, heads,
+                               interpret=_runtime.pallas_interpret()), kc, vc
+        out = _step_attend(q, _heads_apart(kc, heads).astype(q.dtype),
+                           _heads_apart(vc, heads).astype(q.dtype), row,
+                           heads)
+        return out, kc, vc
+
+    return _invoke(fn, (query, key, value, k_cache, v_cache, positions)
+                   + (() if live is None else (live,)),
                    name="decode_attention")
 
 
@@ -555,3 +555,44 @@ def multi_head_attention(query, key, value, heads, mask=None, dropout_p=0.0,
     if return_lse and not isinstance(out, tuple):
         return out, None        # a route that keeps no such statistic
     return out
+
+
+def _step_attend(q, kh, vh, row, heads):
+    """The decode step's read as an XLA composition: q (slots, 1,
+    heads*dim) over kh / vh (slots, max_seq, heads, dim) in q's type, rows
+    <= ``row`` visible."""
+    n, _, hd = q.shape
+    d = hd // heads
+    scores = jnp.einsum("nhd,nshd->nhs", q.reshape(n, heads, d),
+                        kh) * (1.0 / (d ** 0.5))
+    visible = (jnp.arange(kh.shape[1])[None, :] <= row[:, None])[:, None, :]
+    att = _softmax_over_rows(scores, visible, q.dtype)
+    return jnp.einsum("nhs,nshd->nhd", att, vh).reshape(n, 1, hd)
+
+
+def _multi_attend(q, kh, vh, pos, heads):
+    """The verify step's read: q (slots, t, heads*dim), query j of a slot
+    over the rows <= its position + j."""
+    n, t, hd = q.shape
+    d = hd // heads
+    scores = jnp.einsum("nqhd,nshd->nhqs", q.reshape(n, t, heads, d),
+                        kh) * (1.0 / (d ** 0.5))
+    limit = pos.astype(jnp.int32)[:, None] + jnp.arange(t)
+    visible = (jnp.arange(kh.shape[1])[None, None, :]
+               <= limit[:, :, None])[:, None, :, :]
+    att = _softmax_over_rows(scores, visible, q.dtype)
+    return jnp.einsum("nhqs,nshd->nqhd", att, vh).reshape(n, t, hd)
+
+
+def _suffix_attend(q, kslot, vslot, start, heads):
+    """The suffix prefill's read: q (1, Ls, heads*dim) over one slot's
+    kslot / vslot (max_seq, heads, dim), query i over the rows <= start
+    + i."""
+    _, ls, hd = q.shape
+    d = hd // heads
+    scores = jnp.einsum("qhd,shd->hqs", q.reshape(ls, heads, d),
+                        kslot) * (1.0 / (d ** 0.5))
+    visible = (jnp.arange(kslot.shape[0])[None, :]
+               <= (start + jnp.arange(ls))[:, None])
+    att = _softmax_over_rows(scores, visible[None, :, :], q.dtype)
+    return jnp.einsum("hqs,shd->qhd", att, vslot).reshape(1, ls, hd)

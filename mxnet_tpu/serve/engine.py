@@ -5,10 +5,13 @@ is the blueprint; "A Learned Performance Model for TPUs" motivates the
 static-shape discipline):
 
 - **Fixed footprint.** The KV cache — per layer one
-  (max_slots, max_seq, heads, head_dim) K and V array — is allocated
-  once at construction and *donated* through every compiled call, so the
-  decode working set never grows, shrinks, or reallocates no matter how
-  requests arrive. Every device shape in the engine is static.
+  (max_slots, max_seq, heads * head_dim) K and V array, a row as the
+  projections give it — is allocated once at construction and *donated*
+  through every compiled call, so the decode working set never grows,
+  shrinks, or reallocates no matter how requests arrive: the decode step
+  writes its row in place and, on a TPU, reads only the rows the live
+  slots hold (``ops/attention.py::decode_attention``). Every device shape
+  in the engine is static.
 - **One decode executable.** All live requests advance together through
   a single AOT-compiled step (batch dim = max_slots); idle slots ride
   along masked. Prefill gets one executable per prompt-length *bucket*
@@ -32,6 +35,7 @@ static-shape discipline):
 from __future__ import annotations
 
 import collections
+import functools
 import time
 
 import jax
@@ -72,6 +76,12 @@ _telemetry.declare_metric(
 _telemetry.declare_metric(
     "serve.steps_total", "counter",
     "continuous-batching decode steps dispatched")
+_telemetry.declare_metric(
+    "serve.decode_kernel_calls_total", "counter",
+    "layers of a traced decode step whose cached attention read took the "
+    "Pallas kernel mx_decode_attn (a TPU, shapes "
+    "ops/pallas/decode_attention.py::fits takes) and not the XLA "
+    "composition, once a traced call; 0 on a CPU")
 _telemetry.declare_metric(
     "serve.step_seconds", "histogram",
     "host wall time to dispatch one decode step (sync-free: excludes "
@@ -319,6 +329,15 @@ def _parse_buckets(spec):
     return vals
 
 
+@functools.partial(jax.jit, static_argnames="size")
+def _cache_rows(cache, slot, size):
+    def one(leaf):
+        return jax.lax.dynamic_index_in_dim(leaf, slot, 0,
+                                            keepdims=False)[:size]
+    return (jnp.stack([one(k) for k, _ in cache]),
+            jnp.stack([one(v) for _, v in cache]))
+
+
 def _sds(tree):
     return jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
@@ -429,6 +448,13 @@ class ServeEngine:
         self.post_warmup_compiles = 0
         self._next_id = 0
         self._steps = 0
+        # cache rows a decode step's read covers: whole blocks up to each
+        # live slot's position where the kernel reads, else every row
+        from ..ops.attention import decode_read_block
+        leaves = jax.tree_util.tree_leaves(self._cache)
+        self._read_block = decode_read_block(leaves[0]) \
+            if leaves and draft is None else None
+        self._rows_read = 0
         self._completed = []
         self._stopping = False
         self._max_queue = int(_config.get("serve.max_queue"))
@@ -615,7 +641,8 @@ class ServeEngine:
         key, kf, ks = jax.random.split(state["key"], 3)
         (logits, cache), _ = _functional.functional_call(
             self.model, full, state["tokens"][:, None], cache,
-            state["positions"], rng_key=kf, method="decode_step")
+            state["positions"], ~state["done"], rng_key=kf,
+            method="decode_step")
         tok = self._sample(logits, ks)
         done0 = state["done"]
         positions = jnp.where(done0, state["positions"],
@@ -1308,6 +1335,7 @@ class ServeEngine:
                 self._params, self._cache, self._state)
         dt = time.perf_counter() - t0
         self._steps += 1
+        self._rows_read += self._rows_covered(live)
         if _servefleet._active:
             _servefleet.note_step(self)
         if _telemetry._active:
@@ -1334,6 +1362,30 @@ class ServeEngine:
             else self._decode_sink(live)
         self._window.push(emit, sink)
         return True
+
+    def _rows_covered(self, live):
+        """Cache rows one decode step's read covers, from the host's own
+        slot table (no device sync; a slot's position as the drained
+        tokens give it, at most ``drain_window`` rows behind): whole
+        blocks up to each live slot's position where the kernel reads,
+        ``max_slots x max_seq`` where the composition does."""
+        if self._read_block is None:
+            return self.max_slots * self.max_seq
+        block = self._read_block
+        return sum(
+            -(-min(len(r.prompt) + max(1, len(r.generated)), self.max_seq)
+              // block) * block for r in live.values())
+
+    def cache_rows(self, slot, size):
+        """Rows 0 .. ``size`` - 1 of cache slot ``slot`` in every layer,
+        as ``(k, v)``, each ``(layers, size, n_embd)`` in the cache's type:
+        what the engine's programs wrote, for a caller that holds them
+        against a reference (one jitted read a ``size``).  An int8 cache
+        has no such rows."""
+        if self.cache_dtype == "int8":
+            raise MXNetError("an int8 KV cache holds (values, scales) "
+                             "pairs: cache_rows() reads float caches")
+        return _cache_rows(self._cache, jnp.int32(slot), int(size))
 
     def _phase_note(self, req, key, val):
         """Per-request phase sample: unbounded while the tracer runs
@@ -1581,6 +1633,10 @@ class ServeEngine:
             "queued": len(self._queue),
             "live": sum(1 for s in self._slots if s is not None),
             "steps": self._steps,
+            # 1.0: the composition read every row of every slot
+            "decode_rows_read_share": self._rows_read / (
+                self._steps * self.max_slots * self.max_seq)
+            if self._steps else None,
             "tokens_out": sum(len(r.generated) for r in done),
             "compiles": self.compiles,
             "post_warmup_compiles": self.post_warmup_compiles,

@@ -411,7 +411,8 @@ def test_int8_kv_cache_arrays_are_int8():
     assert onp.asarray(kq).dtype == onp.int8
     assert onp.asarray(vq).dtype == onp.int8
     assert onp.asarray(ks).dtype == onp.float32
-    assert ks.shape == kq.shape[:3] + (1,)   # per-(slot, row, head) scales
+    heads = 2                                # _tiny()'s
+    assert ks.shape == kq.shape[:2] + (heads,)   # per-(slot, row, head)
 
 
 def test_combined_int4_weights_int8_kv():

@@ -384,6 +384,50 @@ def _ssm_conv_case(B, S, C, K, dtype):
     return f"ssm_conv b{B}s{S}c{C}k{K} {jnp.dtype(dtype).name}", check
 
 
+def _decode_attn_case(slots, max_seq, heads, dim, dtype):
+    """``decode_attention`` as the chip takes it (``mx_decode_attn``: the
+    step's row written in place, each live slot's rows read in blocks)
+    against the XLA composition it replaces (its oracle), at ragged
+    positions with idle slots: the output of the live slots, zeros for
+    the idle ones, and the caches."""
+    def check():
+        from mxnet_tpu.ops import attention
+        width = heads * dim
+        ks = jax.random.split(jax.random.PRNGKey(11), 7)
+        q, k, v = (jax.random.normal(ks[i], (slots, 1, width), dtype)
+                   for i in range(3))
+        kc, vc = (jax.random.normal(ks[i], (slots, max_seq, width), dtype)
+                  for i in (3, 4))
+        pos = jax.random.randint(ks[5], (slots,), 0, max_seq)
+        live = jax.random.uniform(ks[6], (slots,)) < 0.7
+        assert attention.decode_read_block(kc) is not None
+
+        def raw(out):
+            return [getattr(a, "_data", a) for a in out]
+
+        got = raw(attention.decode_attention(q, k, v, kc, vc, pos, heads,
+                                             live))
+        lane, row = attention._step_rows(pos, max_seq)
+        kw = kc.at[lane, row].set(k[:, 0])
+        vw = vc.at[lane, row].set(v[:, 0])
+        want = attention._step_attend(
+            q, attention._heads_apart(kw, heads),
+            attention._heads_apart(vw, heads), row, heads)
+        keep = live[:, None, None]
+        res = {"out_maxerr": _maxerr(jnp.where(keep, got[0], 0),
+                                     jnp.where(keep, want, 0)),
+               "idle_max": float(jnp.max(jnp.abs(
+                   jnp.where(keep, 0, got[0]).astype(jnp.float32)))),
+               "cache_maxerr": max(_maxerr(got[1], kw), _maxerr(got[2], vw))}
+        # float32 scores against the composition's rounded ones
+        tol = 3e-2 if jnp.dtype(dtype) == jnp.bfloat16 else 2e-2
+        assert res["out_maxerr"] < tol and res["idle_max"] == 0 \
+            and res["cache_maxerr"] == 0, res
+        return res
+    return (f"decode_attn n{slots}s{max_seq}h{heads}d{dim} "
+            f"{jnp.dtype(dtype).name}", check)
+
+
 def _conv_case(N, H, W, Cin, Cout):
     def check():
         from mxnet_tpu.ops.pallas_conv_bwd import (conv3x3_bn_relu_ref,
@@ -453,6 +497,10 @@ def cases():
     # batch of float32 rows in three token blocks of 128
     out.append(_ssm_conv_case(1, 8192, 6144, 4, bf16))
     out.append(_ssm_conv_case(2, 384, 264, 4, f32))
+    # the decode step's cached read: GPT-2 medium's heads at the serve
+    # cells' cache length, and float32 rows of GPT-2 small's twelve
+    out.append(_decode_attn_case(16, 1024, 16, 64, bf16))
+    out.append(_decode_attn_case(4, 256, 12, 64, f32))
     for dtype in (bf16, f32):
         out.append(_ln_case(32 * 128, 768, dtype))       # BERT-base bs32
     out.append(_int8_case(1024, 3072, 768, "gelu"))      # GPT-2 FFN up
